@@ -9,8 +9,9 @@ analyzer (``repro.roofline.analysis.analyze_kernel``): bytes moved
 through VMEM, flops, arithmetic intensity and attainable machine-peak
 fraction for the launch geometry. The roofline numbers are geometry-pure
 (no timing involved) so ``benchmarks.compare`` FAIL-gates them like hit
-ratios; wall-clock here is CPU interpret-mode (NOT TPU perf, DESIGN.md
-§11) and only ever WARNs.
+ratios. Kernels run compiled on TPU and interpreted elsewhere
+(``interpret`` resolves from the backend); an interpreter wall-clock is
+not a device time (DESIGN.md §11), and wall-clock only ever WARNs.
 
 Artifacts: ``kernel_micro.csv`` (agreement sweep), ``kernel_roofline.csv``
 (the roofline table), plus the ``"kernels"`` section of
@@ -75,7 +76,7 @@ def bench_record_fused(rows, roofs):
             oracle = jax.vmap(
                 lambda s, bb, ee: record_event(cfg, s, bb, ee))(oracle, b, e)
             t0 = time.time()
-            fused = ops.mithril_record_fused(fused, b, e, interpret=True)
+            fused = ops.mithril_record_fused(fused, b, e)
             jax.block_until_ready(fused)
             t_us += (time.time() - t0) * 1e6
         ok = all(bool(jnp.array_equal(getattr(oracle, f), getattr(fused, f)))

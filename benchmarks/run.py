@@ -58,6 +58,9 @@ def main(argv=None) -> None:
         ap.error(f"--quick contradicts --suite {a.suite}")
     suite = a.suite or ("quick" if a.quick else "mid")
     geo = SUITES[suite]
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     scale, tlen = geo["corpus_scale"], geo["trace_len"]
 
     from repro.traces import resolve_corpus_dir
@@ -122,6 +125,7 @@ def main(argv=None) -> None:
                             "status": f"FAILED:{type(e).__name__}"})
 
     import jax
+    device = jax.devices()[0]
     common.write_bench_json(
         meta={"suite": suite, "quick": suite == "quick",
               "trace_len": tlen,
@@ -129,6 +133,8 @@ def main(argv=None) -> None:
               "corpus": corpus,
               "jax": jax.__version__,
               "backend": jax.default_backend(),
+              "platform": device.platform,
+              "device_kind": device.device_kind,
               "n_devices": jax.local_device_count(),
               "failures": failures},
         jobs=job_log)

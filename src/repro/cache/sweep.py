@@ -194,18 +194,29 @@ def build_batched_step(cfg: SimConfig):
 def _lane_shards(n_lanes: int, shard: Optional[bool]) -> int:
     """Devices to shard the lane axis over (1 = single-device path).
 
-    Auto policy (``shard=None``/``True``): shard over every local device
-    when the lane count divides — the same divisibility contract as
-    ``dist.sharding`` (non-dividing widths silently run single-device
-    rather than erroring). ``shard=False`` forces the single-device path
-    (the bit-exactness reference).
+    ``shard=None`` (auto) shards over every local device when the lane
+    count divides — the divisibility contract of ``dist.sharding`` —
+    and runs single-device otherwise. ``shard=True`` demands the
+    sharded path: a lane count that does not divide over several
+    devices raises instead of quietly running on one. ``shard=False``
+    forces the single-device path (the bit-exactness reference).
     """
     if shard is False:
         return 1
     n_dev = jax.local_device_count()
-    if n_dev > 1 and n_lanes % n_dev == 0:
+    if n_dev == 1 or n_lanes % n_dev == 0:
         return n_dev
+    if shard:
+        raise ValueError(f"shard=True: {n_lanes} lanes do not divide over "
+                         f"{n_dev} devices")
     return 1
+
+
+def lane_mesh(n_shards: int):
+    """1-D ``lanes`` mesh over the first ``n_shards`` local devices."""
+    from repro.launch.mesh import auto_mesh
+    return auto_mesh((n_shards,), (LANE_AXIS,),
+                     devices=jax.local_devices()[:n_shards])
 
 
 @functools.lru_cache(maxsize=None)
@@ -231,11 +242,10 @@ def _runner(cfg: SimConfig, unroll: int, n_shards: int = 1):
 
     # lazy: pulling repro.dist at module import would drag the model
     # stack into every cache-layer import
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.dist import sharding as dist_sharding
 
-    mesh = jax.make_mesh((n_shards,), (LANE_AXIS,))
+    mesh = lane_mesh(n_shards)
 
     def place(carry):
         """Pre-shard the initial carry so the first chunk's input
@@ -266,25 +276,21 @@ def _runner(cfg: SimConfig, unroll: int, n_shards: int = 1):
         # keep their shard across admissions
         bspec, vspec = dist_sharding.ring_specs((blocks, valid), mesh,
                                                 axis=LANE_AXIS)
-        return shard_map(scan_chunk, mesh=mesh,
-                         in_specs=(cspec, bspec, vspec),
-                         out_specs=(cspec, bspec),
-                         check_rep=False)(carry, blocks, valid)
+        return jax.shard_map(scan_chunk, mesh=mesh,
+                             in_specs=(cspec, bspec, vspec),
+                             out_specs=(cspec, bspec),
+                             check_vma=False)(carry, blocks, valid)
 
     return init_batched, run_chunk, place
 
 
 def compile_count(cfg: SimConfig, unroll: int = 1, n_shards: int = 1) -> int:
-    """Compiled-executable count for ``cfg``'s chunk runner (-1 if unknown).
+    """Compiled-executable count for ``cfg``'s chunk runner.
 
     All chunks are padded to one (chunk, B) shape, so a full sweep — and
     every later sweep with the same batch geometry — reports 1.
     """
-    fn = _runner(cfg, unroll, n_shards)[1]
-    try:
-        return int(fn._cache_size())
-    except AttributeError:      # jit internals moved; treat as unknown
-        return -1
+    return int(_runner(cfg, unroll, n_shards)[1]._cache_size())
 
 
 def reset_runners() -> None:
@@ -679,7 +685,7 @@ def sweep_scheduled(cfg: SimConfig,
 
     stats_out = None
     hit = np.zeros((n, t_max), bool)
-    compiles, unknown = 0, False
+    compiles = 0
     for g in plan.groups:
         gb = np.zeros((g.lane_width, g.padded_t), np.int32)
         gl = np.zeros((g.lane_width,), np.int64)
@@ -689,8 +695,7 @@ def sweep_scheduled(cfg: SimConfig,
             gl[j] = ln
         res = sweep(cfg, gb, gl, chunk=g.chunk, unroll=unroll,
                     shard=shard)
-        unknown |= res.compiles < 0
-        compiles += max(res.compiles, 0)
+        compiles += res.compiles
         if stats_out is None:
             stats_out = [np.zeros((n,) + np.asarray(leaf).shape[1:],
                                   np.asarray(leaf).dtype)
@@ -703,7 +708,7 @@ def sweep_scheduled(cfg: SimConfig,
 
     return SweepResult(stats=Stats(*stats_out), hit_curve=hit,
                        lengths=lengths,
-                       compiles=-1 if unknown else compiles,
+                       compiles=compiles,
                        seconds=time.time() - t0)
 
 
@@ -915,6 +920,7 @@ class StreamResult(NamedTuple):
     n_slabs: int
     async_producer: bool = True
     pipeline: Optional[Dict[str, object]] = None
+    n_shards: int = 1           # devices the lane axis was split over
 
     @property
     def lane_steps(self) -> int:
@@ -1047,7 +1053,7 @@ def sweep_streaming(cfg: SimConfig,
     carry = template
     if n_shards > 1:
         from repro.dist import sharding as dist_sharding
-        mesh = jax.make_mesh((n_shards,), (LANE_AXIS,))
+        mesh = lane_mesh(n_shards)
 
         def place_mask(m):
             spec = dist_sharding.occupancy_specs(m, mesh, axis=LANE_AXIS)
@@ -1322,8 +1328,8 @@ def sweep_streaming(cfg: SimConfig,
     }
     after = compile_count(cfg, unroll, n_shards)
     result = SweepResult(stats=stats, hit_curve=hit_curve, lengths=lengths,
-                         compiles=(after - before if before >= 0 else -1),
+                         compiles=after - before,
                          seconds=time.time() - t0)
     return StreamResult(result=result, lane_width=w, chunk=chunk,
                         n_slabs=n_slabs, async_producer=async_producer,
-                        pipeline=pipeline)
+                        pipeline=pipeline, n_shards=n_shards)

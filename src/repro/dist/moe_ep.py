@@ -29,7 +29,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.moe import capacity, group_tokens, moe_ffn, router_topk
@@ -107,12 +106,12 @@ def moe_ffn_tp(p, x: jax.Array, *, n_experts: int, top_k: int,
     tok = P(dpe if ctx.dp_axes else None, None)
     body = functools.partial(_tp_body, tp_name=tp, n_experts=n_experts,
                              top_k=top_k, cap_factor=cap_factor)
-    out, logits, idx = shard_map(
+    out, logits, idx = jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(None, None), P(tp, None, None), P(tp, None, None),
                   P(tp, None, None), tok),
         out_specs=(tok, tok, P(tok[0], None)),
-        check_rep=False,
+        check_vma=False,
     )(*_routed_weights(p), x)
     return out + _shared_expert(p, x), logits, idx
 
@@ -195,11 +194,11 @@ def moe_ffn_ep(p, x: jax.Array, *, n_experts: int, top_k: int,
     body = functools.partial(_ep_body, ep_name=ep, n_shards=n_shards,
                              n_experts=n_experts, top_k=top_k,
                              cap_factor=cap_factor)
-    out, logits, idx = shard_map(
+    out, logits, idx = jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(None, None), P(ep, None, None), P(ep, None, None),
                   P(ep, None, None), tok),
         out_specs=(tok, tok, P(tok[0], None)),
-        check_rep=False,
+        check_vma=False,
     )(*_routed_weights(p), x)
     return out + _shared_expert(p, x), logits, idx

@@ -35,37 +35,56 @@ def _offset_code(ts_i, cnt_i, val_i, live_i, ts_j, cnt_j, val_j,
 
     Shared by the serial row-block kernel below and the lanes-axis
     batched kernel (``mithril_mine_batched``) — same math, same
-    tie-breaking as ``core.mining.pairwise_codes``.
+    tie-breaking as ``core.mining.pairwise_codes``. Every reduction runs
+    over int32 0/1 masks (no bool reductions, no bool<->int casts),
+    which is what Mosaic lowers.
     """
     gap_ok = (ts_j[:, :1] - ts_i[:, :1]) <= delta
     same_cnt = cnt_j == cnt_i
-    diffs = jnp.abs(ts_j - ts_i)
-    weak = jnp.all(jnp.where(live_i, diffs <= delta, True), axis=1,
-                   keepdims=True)
-    strong = weak & jnp.any(jnp.where(live_i, diffs == 1, False), axis=1,
-                            keepdims=True)
+    d = ts_j - ts_i
+    diffs = jnp.where(d < 0, -d, d)
+    too_far = jnp.max(jnp.where(live_i & (diffs > delta), 1, 0), axis=1,
+                      keepdims=True)
+    adjacent = jnp.max(jnp.where(live_i & (diffs == 1), 1, 0), axis=1,
+                       keepdims=True)
+    weak = too_far == 0
+    strong = weak & (adjacent == 1)
     ok = (val_i == 1) & (val_j == 1) & gap_ok & same_cnt
     return jnp.where(ok & strong, 2, jnp.where(ok & weak, 1, 0))
+
+
+def _codes_tile(ts_ref, cnt_ref, valid_ref, r0, *, blk: int, window: int,
+                delta: int, lead: tuple = ()):
+    """(BLK, window) codes of rows ``r0 .. r0+BLK`` against their
+    ``window`` successors. ``lead`` indexes the refs' leading dims (the
+    batched kernel's lane). A ``fori_loop`` over the offsets keeps one
+    shifted slab live at a time; the tile is carried in registers and
+    stored once by the caller."""
+    def rows(ref, start):
+        return ref[(*lead, pl.ds(start, blk), slice(None))]
+
+    ts_i, cnt_i, val_i = (rows(ts_ref, r0), rows(cnt_ref, r0),
+                          rows(valid_ref, r0))      # (BLK, S), (BLK, 1) x2
+    s = ts_i.shape[1]
+    live_i = jax.lax.broadcasted_iota(jnp.int32, (blk, s), 1) < cnt_i
+    col = jax.lax.broadcasted_iota(jnp.int32, (blk, window), 1)
+
+    def body(b, out):
+        j = r0 + 1 + b
+        code = _offset_code(ts_i, cnt_i, val_i, live_i, rows(ts_ref, j),
+                            rows(cnt_ref, j), rows(valid_ref, j), delta)
+        return jnp.where(col == b, code, out)
+
+    return jax.lax.fori_loop(0, window, body,
+                             jnp.zeros((blk, window), jnp.int32))
 
 
 def _mine_kernel(ts_ref, cnt_ref, valid_ref, out_ref, *, delta: int,
                  window: int, blk: int):
     """Grid: (n_row_blocks,). ts_ref: full (N_pad, S); out: (BLK, W) tile."""
-    i = pl.program_id(0)
-    r0 = i * blk
-    ts_i = ts_ref[pl.ds(r0, blk), :]            # (BLK, S)
-    cnt_i = cnt_ref[pl.ds(r0, blk), :]          # (BLK, 1)
-    val_i = valid_ref[pl.ds(r0, blk), :]        # (BLK, 1)
-    s = ts_i.shape[1]
-    k_iota = jax.lax.broadcasted_iota(jnp.int32, (blk, s), 1)
-    live_i = k_iota < cnt_i                      # aligned-pair mask
-
-    for b in range(window):
-        code = _offset_code(ts_i, cnt_i, val_i, live_i,
-                            ts_ref[pl.ds(r0 + 1 + b, blk), :],
-                            cnt_ref[pl.ds(r0 + 1 + b, blk), :],
-                            valid_ref[pl.ds(r0 + 1 + b, blk), :], delta)
-        out_ref[:, b] = code[:, 0].astype(jnp.int32)
+    r0 = pl.multiple_of(pl.program_id(0) * blk, blk)
+    out_ref[...] = _codes_tile(ts_ref, cnt_ref, valid_ref, r0, blk=blk,
+                               window=window, delta=delta)
 
 
 def pairwise_codes_kernel(ts: jax.Array, cnt: jax.Array, valid: jax.Array,
@@ -96,4 +115,5 @@ def pairwise_codes_kernel(ts: jax.Array, cnt: jax.Array, valid: jax.Array,
         out_specs=pl.BlockSpec((blk, window), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_rows, window), jnp.int32),
         interpret=interpret,
+        name="mithril_mine",
     )(ts, cnt, valid)

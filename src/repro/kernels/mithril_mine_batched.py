@@ -10,7 +10,7 @@ whole (padded) timestamp matrix in VMEM — mining tables are small by
 construction (paper: 1250 rows x S=8 -> ~40KB at int32), so even dozens
 of lanes stream comfortably under the ~16MB VMEM budget — and compares
 its (BLK, S) row tile against ``window`` statically-shifted row slabs,
-exactly like the serial kernel (same ``_offset_code`` math, DESIGN.md §2).
+exactly like the serial kernel (same ``_codes_tile`` math, DESIGN.md §2).
 
 Input rows must be pre-padded per lane with ``window`` trailing invalid
 rows and to a BLK multiple (``ops.mithril_pairwise_batched`` does this).
@@ -26,27 +26,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .backend import default_interpret
-from .mithril_mine import _offset_code
+from .mithril_mine import _codes_tile
 
 
 def _mine_kernel_batched(ts_ref, cnt_ref, valid_ref, out_ref, *, delta: int,
                          window: int, blk: int):
     """Grid: (lanes, n_row_blocks). Refs carry a leading lane dim of 1."""
-    i = pl.program_id(1)
-    r0 = i * blk
-    ts_i = ts_ref[0, pl.ds(r0, blk), :]          # (BLK, S)
-    cnt_i = cnt_ref[0, pl.ds(r0, blk), :]        # (BLK, 1)
-    val_i = valid_ref[0, pl.ds(r0, blk), :]      # (BLK, 1)
-    s = ts_i.shape[1]
-    k_iota = jax.lax.broadcasted_iota(jnp.int32, (blk, s), 1)
-    live_i = k_iota < cnt_i                      # aligned-pair mask
-
-    for b in range(window):
-        code = _offset_code(ts_i, cnt_i, val_i, live_i,
-                            ts_ref[0, pl.ds(r0 + 1 + b, blk), :],
-                            cnt_ref[0, pl.ds(r0 + 1 + b, blk), :],
-                            valid_ref[0, pl.ds(r0 + 1 + b, blk), :], delta)
-        out_ref[0, :, b] = code[:, 0].astype(jnp.int32)
+    r0 = pl.multiple_of(pl.program_id(1) * blk, blk)
+    out_ref[0] = _codes_tile(ts_ref, cnt_ref, valid_ref, r0, blk=blk,
+                             window=window, delta=delta, lead=(0,))
 
 
 def pairwise_codes_batched_kernel(ts: jax.Array, cnt: jax.Array,
@@ -80,4 +68,5 @@ def pairwise_codes_batched_kernel(ts: jax.Array, cnt: jax.Array,
         out_specs=pl.BlockSpec((1, blk, window), lambda l, i: (l, i, 0)),
         out_shape=jax.ShapeDtypeStruct((lanes, n_rows, window), jnp.int32),
         interpret=interpret,
+        name="mithril_mine_batched",
     )(ts, cnt, valid)

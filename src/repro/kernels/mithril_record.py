@@ -7,31 +7,34 @@ recording and mining tables for every recorded event. This kernel fuses
 the whole record path — the ``hashindex.locate`` probe, the
 recording-table circular-buffer timestamp stamp, and the
 mining/prefetch-metadata table insert (migration) — into ONE launch per
-request slab: grid ``(lanes,)``, each program holding one lane's record
-and mining tables in VMEM via leading-1 BlockSpecs (the
-``mithril_mine_batched`` layout), with every table update a single-row
-dynamic-slice store. Memory layout, probe sequence and padded-lane
-masking are documented in DESIGN.md §11.
+request slab: grid ``(lanes,)``. Memory layout, probe sequence and
+padded-lane masking are documented in DESIGN.md §11.
 
-Table layout inside the kernel (per lane; wrapper reshapes):
+Table layout inside the kernel (per lane; the wrapper transposes):
 
-* recording table — ``rec_key/cnt/age/loc/row`` keep their ``(NB, W)``
-  shape; the probed bucket is the ``(1, W)`` slab at ``pl.ds(b, 1)``.
-  ``rec_ts`` is flattened to ``(NB*W, R)`` so the ONE way whose
-  timestamp row changes is the ``(1, R)`` slab at ``pl.ds(b*W + w, 1)``
-  — no 4-D refs, no masked whole-bucket writes;
-* mining table — ``mine_block/cnt`` carried as ``(Nm, 1)`` columns (the
-  batched mining kernel's convention), ``mine_ts`` as ``(Nm, S)``; the
-  touched row is the ``pl.ds(m, 1)`` slab;
-* scalars — ``block/enabled/mine_fill/ts`` as ``(1, 1)`` lane blocks.
+* recording table — lane-dense: ``rec_key/cnt/age/loc/row`` as
+  ``(W, NB)`` (ways on sublanes, buckets on the 128-wide lane axis) and
+  ``rec_ts`` as ``(W, R, NB)``. The probed bucket ``b`` is computed by
+  the wrapper and scalar-prefetched, so each program's BlockSpec DMAs only the
+  ``win``-wide bucket window holding ``b`` — never the whole table —
+  and the kernel addresses column ``b % win`` with a one-hot lane mask;
+* mining table — ``mine_block/cnt`` as ``(1, Nm)`` rows and ``mine_ts``
+  as ``(S, Nm)``, the whole lane's table per program (the touched row
+  ``m`` depends on table contents, so it is a one-hot lane mask too);
+* scalars — ``block/enabled/bucket/mine_fill/ts`` live in SMEM (scalar
+  prefetch); the new ``mine_fill`` comes back as a lane-dense
+  ``(1, 128)`` broadcast row, ``ts`` is advanced by the wrapper.
 
-``enabled == 0`` lanes (padded tails, gated record policies) write every
-touched row back with its old contents — the same bit-exact no-op
-contract as the scatter form, so the sweep engine needs no lane masking
-around the launch. Outputs alias inputs (``input_output_aliases``) so
-the tables update in place on TPU. Bit-identity against
-``record_event`` is pinned per event by ``tests/test_record_kernel.py``
-(frozen-oracle property tests, interpret mode).
+Every probe result is a ``(1, 1)`` vector (masked min/sum reductions,
+int32 throughout), so no value moves from the vector unit to the
+scalar unit. ``enabled == 0`` lanes (padded tails, gated record
+policies) write every touched element back with its old contents — the
+same bit-exact no-op contract as the scatter form. Outputs alias inputs
+(``input_output_aliases``): only the bucket window is written, the rest
+of each table stays in place. Bit-identity against ``record_event`` is
+pinned per event by ``tests/test_record_kernel.py`` (interpret mode),
+and the kernel is compiled for a v5e at real table sizes by
+``tests/test_tpu_compile.py``.
 """
 
 from __future__ import annotations
@@ -42,181 +45,191 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .backend import default_interpret
-from .hash_lookup import _mix32
 
-EMPTY = -1
+LANE_TILE = 128
+
+
+def bucket_window(n_buckets: int) -> int:
+    """Width of the bucket window one program touches: one 128-lane
+    tile, or the whole table when it is narrower or does not tile."""
+    if n_buckets > LANE_TILE and n_buckets % LANE_TILE == 0:
+        return LANE_TILE
+    return n_buckets
 
 
 def _first_true(mask, iota, width):
-    """Index of the first True in a (1, W) mask (W if none) — the
-    branchless equivalent of ``jnp.argmax(mask)`` first-hit semantics."""
-    return jnp.min(jnp.where(mask, iota, width))
+    """(1, 1) index of the first True along sublanes (``width`` if none)
+    — ``jnp.argmax(mask)`` first-hit semantics as a masked min."""
+    return jnp.min(jnp.where(mask, iota, width), axis=0, keepdims=True)
 
 
-def _record_kernel(block_ref, enabled_ref, rec_key_ref, rec_ts_ref,
-                   rec_cnt_ref, rec_age_ref, rec_loc_ref, rec_row_ref,
-                   mine_block_ref, mine_ts_ref, mine_cnt_ref, mine_fill_ref,
-                   ts_ref,
+def _record_kernel(blk_ref, en_ref, bkt_ref, fill_ref, ts_ref,
+                   rec_key_ref, rec_ts_ref, rec_cnt_ref, rec_age_ref,
+                   rec_loc_ref, rec_row_ref, mine_block_ref, mine_ts_ref,
+                   mine_cnt_ref,
                    o_rec_key, o_rec_ts, o_rec_cnt, o_rec_age, o_rec_loc,
-                   o_rec_row, o_mine_block, o_mine_ts, o_mine_cnt,
-                   o_mine_fill, o_ts, *, n_buckets: int, ways: int,
-                   r_sup: int, s_sup: int):
-    """Grid: (lanes,). Refs carry a leading lane dim of 1."""
+                   o_rec_row, o_mine_block, o_mine_ts, o_mine_cnt, o_fill,
+                   *, ways: int, win: int, r_sup: int, s_sup: int):
+    """Grid: (lanes,). Table refs carry a leading lane dim of 1."""
     i32 = jnp.int32
+    lane = pl.program_id(0)
 
-    # copy-through: every output ref starts as its input table, so the
-    # row stores below are true in-place updates (and un-touched rows
-    # are defined even without input/output aliasing, e.g. interpret)
-    o_rec_key[...] = rec_key_ref[...]
-    o_rec_ts[...] = rec_ts_ref[...]
-    o_rec_cnt[...] = rec_cnt_ref[...]
-    o_rec_age[...] = rec_age_ref[...]
-    o_rec_loc[...] = rec_loc_ref[...]
-    o_rec_row[...] = rec_row_ref[...]
-    o_mine_block[...] = mine_block_ref[...]
-    o_mine_ts[...] = mine_ts_ref[...]
-    o_mine_cnt[...] = mine_cnt_ref[...]
+    def vec(x):             # SMEM scalar -> (1, 1) int32 vector
+        return jnp.full((1, 1), x, i32)
 
-    blk = block_ref[0, 0]
-    en = enabled_ref[0, 0] != 0
-    ts = ts_ref[0, 0]
-    fill = mine_fill_ref[0, 0]
+    blk = vec(blk_ref[lane])
+    en = vec(en_ref[lane]) != 0
+    ts = vec(ts_ref[lane])
+    fill = vec(fill_ref[lane])
+    col = vec(bkt_ref[lane] % win)
 
     # --- hashindex.locate: probe the bucket, pick hit way or victim ---
-    b = jnp.bitwise_and(_mix32(blk), i32(n_buckets - 1))
-    keys_row = rec_key_ref[0, pl.ds(b, 1), :]             # (1, W)
-    age_row = rec_age_ref[0, pl.ds(b, 1), :]
-    cnt_row = rec_cnt_ref[0, pl.ds(b, 1), :]
-    loc_row = rec_loc_ref[0, pl.ds(b, 1), :]
-    row_row = rec_row_ref[0, pl.ds(b, 1), :]
+    keys = rec_key_ref[0]                                 # (W, win)
+    age = rec_age_ref[0]
+    cnt = rec_cnt_ref[0]
+    loc = rec_loc_ref[0]
+    row = rec_row_ref[0]
+    at_b = jax.lax.broadcasted_iota(i32, (ways, win), 1) == col
+    way_of = jax.lax.broadcasted_iota(i32, (ways, win), 0)
 
-    kw = jax.lax.broadcasted_iota(i32, (1, ways), 1)
-    hit = keys_row == blk
-    found = jnp.any(hit)
+    def bucket(tile):       # the probed bucket's (W, 1) column
+        return jnp.sum(jnp.where(at_b, tile, 0), axis=1, keepdims=True)
+
+    kw = jax.lax.broadcasted_iota(i32, (ways, 1), 0)
+    kcol, acol = bucket(keys), bucket(age)
+    hit = kcol == blk
+    found = jnp.max(jnp.where(hit, 1, 0), axis=0, keepdims=True) > 0
     way_hit = _first_true(hit, kw, ways)
-    empty = keys_row == EMPTY
+    empty = kcol == -1
+    any_empty = jnp.max(jnp.where(empty, 1, 0), axis=0, keepdims=True) > 0
     first_empty = _first_true(empty, kw, ways)
-    oldest = _first_true(age_row == jnp.min(age_row), kw, ways)
-    victim = jnp.where(jnp.any(empty), first_empty, oldest)
-    w = jnp.where(found, way_hit, victim)
-    mask_w = kw == w
+    oldest = _first_true(acol == jnp.min(acol, axis=0, keepdims=True),
+                         kw, ways)
+    w = jnp.where(found, way_hit, jnp.where(any_empty, first_empty, oldest))
+    at_bw = at_b & (way_of == w)
 
-    def pick(row):          # the (b, w) scalar out of a (1, W) slab
-        return jnp.sum(jnp.where(mask_w, row, 0))
+    def pick(tile):         # the (b, w) element as a (1, 1) vector
+        return jnp.sum(jnp.sum(jnp.where(at_bw, tile, 0), axis=1,
+                               keepdims=True), axis=0, keepdims=True)
 
-    old_cnt, old_age = pick(cnt_row), pick(age_row)
-    old_loc, old_row = pick(loc_row), pick(row_row)
+    old_cnt, old_loc, old_row = pick(cnt), pick(loc), pick(row)
     in_mine = old_loc == 1
     is_new = en & ~found
     is_rec = en & found & ~in_mine
     is_upd = en & found & in_mine
 
-    # --- recording-table circular-buffer stamp (one (1, R) row) ---
-    r = b * ways + w                                      # flat (bucket, way)
-    old_ts_row = rec_ts_ref[0, pl.ds(r, 1), :]            # (1, R)
-    kr = jax.lax.broadcasted_iota(i32, (1, r_sup), 1)
-    ts_row = jnp.where(is_new, jnp.where(kr == 0, ts, 0),
-                       jnp.where(is_rec, jnp.where(kr == old_cnt, ts,
-                                                   old_ts_row), old_ts_row))
-    cnt_val = jnp.where(is_new, 1, old_cnt + is_rec.astype(i32))
+    # --- recording-table circular-buffer stamp (way w's R timestamps) ---
+    tsr = rec_ts_ref[0]                                   # (W, R, win)
+    ts_shape = (ways, r_sup, win)
+    k_of = jax.lax.broadcasted_iota(i32, ts_shape, 1)
+    at_ts = (jax.lax.broadcasted_iota(i32, ts_shape, 2) == col) \
+        & (jax.lax.broadcasted_iota(i32, ts_shape, 0) == w)
+    stamped = jnp.where(
+        is_new, jnp.where(k_of == 0, ts, 0),
+        jnp.where(is_rec & (k_of == old_cnt), ts, tsr))
+    new_tsr = jnp.where(at_ts, stamped, tsr)
+    ts_row = [jnp.sum(jnp.where(at_ts & (k_of == j), new_tsr, 0),
+                      axis=(0, 1, 2), keepdims=True)[0]
+              for j in range(r_sup)]                      # (1, 1) each
+    cnt_val = jnp.where(is_new, 1, old_cnt + jnp.where(is_rec, 1, 0))
     migrate = is_rec & (cnt_val >= r_sup)
     if r_sup == 1:          # static branch: new rows are born mining-ready
         migrate = migrate | is_new
 
-    # --- mining-table insert (one (1, S) row at m) ---
+    # --- mining-table insert (row m, a one-hot lane mask) ---
     m = jnp.where(migrate, fill, jnp.where(is_upd, old_row, 0))
-    old_mblk = mine_block_ref[0, pl.ds(m, 1), :]          # (1, 1)
-    old_mts = mine_ts_ref[0, pl.ds(m, 1), :]              # (1, S)
-    old_mcnt_row = mine_cnt_ref[0, pl.ds(m, 1), :]        # (1, 1)
-    old_mcnt = old_mcnt_row[0, 0]
+    mblk = mine_block_ref[0]                              # (1, Nm)
+    mcnt = mine_cnt_ref[0]
+    mts = mine_ts_ref[0]                                  # (S, Nm)
+    nm = mblk.shape[1]
+    at_m = jax.lax.broadcasted_iota(i32, (1, nm), 1) == m
+    old_mcnt = jnp.sum(jnp.where(at_m, mcnt, 0), axis=1, keepdims=True)
     can = old_mcnt < s_sup
     pos = jnp.minimum(old_mcnt, s_sup - 1)
-    ks = jax.lax.broadcasted_iota(i32, (1, s_sup), 1)
-    ts_at_ks = jnp.zeros((1, s_sup), i32)
-    for j in range(r_sup):  # static unroll: S, R are small table params
-        ts_at_ks = jnp.where(ks == j, ts_row[0, j], ts_at_ks)
-    mig_ts = jnp.where(ks < r_sup, ts_at_ks, old_mts)
-    upd_ts = jnp.where((ks == pos) & can, ts, old_mts)
+    s_iota = jax.lax.broadcasted_iota(i32, (s_sup, nm), 0)
+    mig_ts = mts
+    for j in range(r_sup):  # static unroll: R is a small table param
+        mig_ts = jnp.where(s_iota == j, ts_row[j], mig_ts)
+    upd_ts = jnp.where((s_iota == pos) & can, ts, mts)
+    new_mts = jnp.where(migrate, mig_ts, jnp.where(is_upd, upd_ts, mts))
+    at_m_s = jax.lax.broadcasted_iota(i32, (s_sup, nm), 1) == m
 
-    # --- single-row stores (disabled events store the old values) ---
-    o_rec_key[0, pl.ds(b, 1), :] = jnp.where(
-        mask_w & is_new, blk, keys_row)
-    o_rec_ts[0, pl.ds(r, 1), :] = ts_row
-    o_rec_cnt[0, pl.ds(b, 1), :] = jnp.where(mask_w, cnt_val, cnt_row)
-    o_rec_age[0, pl.ds(b, 1), :] = jnp.where(
-        mask_w & is_new, ts, age_row)
-    o_rec_loc[0, pl.ds(b, 1), :] = jnp.where(
-        mask_w, jnp.where(migrate, 1, jnp.where(is_new, 0, old_loc)),
-        loc_row)
-    o_rec_row[0, pl.ds(b, 1), :] = jnp.where(
-        mask_w, jnp.where(migrate, fill, old_row), row_row)
-    o_mine_block[0, pl.ds(m, 1), :] = jnp.where(migrate, blk, old_mblk)
-    o_mine_ts[0, pl.ds(m, 1), :] = jnp.where(
-        migrate, mig_ts, jnp.where(is_upd, upd_ts, old_mts))
+    # --- stores (disabled events store the old values) ---
+    o_rec_key[0] = jnp.where(at_bw & is_new, blk, keys)
+    o_rec_ts[0] = new_tsr
+    o_rec_cnt[0] = jnp.where(at_bw, cnt_val, cnt)
+    o_rec_age[0] = jnp.where(at_bw & is_new, ts, age)
+    o_rec_loc[0] = jnp.where(
+        at_bw, jnp.where(migrate, 1, jnp.where(is_new, 0, old_loc)), loc)
+    o_rec_row[0] = jnp.where(at_bw, jnp.where(migrate, fill, old_row), row)
+    o_mine_block[0] = jnp.where(at_m & migrate, blk, mblk)
+    o_mine_ts[0] = jnp.where(at_m_s, new_mts, mts)
     # exceeding S marks the block frequent (excluded from mining)
-    o_mine_cnt[0, pl.ds(m, 1), :] = jnp.where(
-        migrate, r_sup,
-        jnp.where(is_upd, jnp.where(can, old_mcnt + 1, s_sup + 1),
-                  old_mcnt_row))
-    o_mine_fill[0, 0] = fill + migrate.astype(i32)
-    o_ts[0, 0] = ts + en.astype(i32)
+    o_mine_cnt[0] = jnp.where(
+        at_m, jnp.where(migrate, r_sup,
+                        jnp.where(is_upd,
+                                  jnp.where(can, old_mcnt + 1, s_sup + 1),
+                                  mcnt)), mcnt)
+    o_fill[0] = jnp.broadcast_to(fill + jnp.where(migrate, 1, 0),
+                                 (1, LANE_TILE))
 
 
 def record_step_kernel(block: jax.Array, enabled: jax.Array,
-                       rec_key: jax.Array, rec_ts_flat: jax.Array,
-                       rec_cnt: jax.Array, rec_age: jax.Array,
-                       rec_loc: jax.Array, rec_row: jax.Array,
-                       mine_block: jax.Array, mine_ts: jax.Array,
-                       mine_cnt: jax.Array, mine_fill: jax.Array,
-                       ts: jax.Array, *,
+                       bucket: jax.Array, mine_fill: jax.Array,
+                       ts: jax.Array, rec_key: jax.Array,
+                       rec_ts: jax.Array, rec_cnt: jax.Array,
+                       rec_age: jax.Array, rec_loc: jax.Array,
+                       rec_row: jax.Array, mine_block: jax.Array,
+                       mine_ts: jax.Array, mine_cnt: jax.Array, *,
                        interpret: Optional[bool] = None):
     """One fused record event for every lane.
 
-    ``block``/``enabled``/``mine_fill``/``ts``: (L, 1) int32;
-    ``rec_key/cnt/age/loc/row``: (L, NB, W); ``rec_ts_flat``:
-    (L, NB*W, R); ``mine_block/cnt``: (L, Nm, 1); ``mine_ts``:
-    (L, Nm, S). Returns the 11 updated state arrays in the same order
-    and layout (``ops.mithril_record_fused`` adapts ``MithrilState``).
+    ``block``/``enabled``/``bucket``/``mine_fill``/``ts``: (L,) int32
+    (``bucket = mix32(block) & (NB - 1)``); ``rec_key/cnt/age/loc/row``:
+    (L, W, NB); ``rec_ts``: (L, W, R, NB); ``mine_block/cnt``: (L, 1, Nm);
+    ``mine_ts``: (L, S, Nm). Returns the nine updated tables in the same
+    order and layout plus the new ``mine_fill`` as (L, 1, 128) rows
+    (``ops.mithril_record_fused`` adapts ``MithrilState``).
     ``interpret=None``: compiled on TPU, interpreted elsewhere.
     """
     interpret = default_interpret(interpret)
-    lanes, nb, ways = rec_key.shape
-    r_sup = rec_ts_flat.shape[-1]
-    nm, s_sup = mine_ts.shape[1:]
-    kernel = functools.partial(_record_kernel, n_buckets=nb, ways=ways,
+    lanes, ways, nb = rec_key.shape
+    r_sup = rec_ts.shape[2]
+    s_sup, nm = mine_ts.shape[1:]
+    win = bucket_window(nb)
+    kernel = functools.partial(_record_kernel, ways=ways, win=win,
                                r_sup=r_sup, s_sup=s_sup)
 
-    spec2 = pl.BlockSpec((1, 1), lambda i: (i, 0))
-    spec_rec = pl.BlockSpec((1, nb, ways), lambda i: (i, 0, 0))
-    spec_ts = pl.BlockSpec((1, nb * ways, r_sup), lambda i: (i, 0, 0))
-    spec_mblk = pl.BlockSpec((1, nm, 1), lambda i: (i, 0, 0))
-    spec_mts = pl.BlockSpec((1, nm, s_sup), lambda i: (i, 0, 0))
+    def window(*rows):
+        return pl.BlockSpec((1, *rows, win),
+                            lambda i, blk, en, bkt, fill, ts:
+                            (i, *(0,) * len(rows), bkt[i] // win))
 
+    def whole(rows):
+        return pl.BlockSpec((1, rows, nm),
+                            lambda i, blk, en, bkt, fill, ts: (i, 0, 0))
+
+    tables = [window(ways), window(ways, r_sup), window(ways),
+              window(ways), window(ways), window(ways), whole(1),
+              whole(s_sup), whole(1)]
+    fill_spec = pl.BlockSpec((1, 1, LANE_TILE),
+                             lambda i, blk, en, bkt, fill, ts: (i, 0, 0))
     sds = jax.ShapeDtypeStruct
     i32 = jnp.int32
+    tabs = (rec_key, rec_ts, rec_cnt, rec_age, rec_loc, rec_row,
+            mine_block, mine_ts, mine_cnt)
     return pl.pallas_call(
         kernel,
-        grid=(lanes,),
-        in_specs=[spec2, spec2, spec_rec, spec_ts, spec_rec, spec_rec,
-                  spec_rec, spec_rec, spec_mblk, spec_mts, spec_mblk,
-                  spec2, spec2],
-        out_specs=[spec_rec, spec_ts, spec_rec, spec_rec, spec_rec,
-                   spec_rec, spec_mblk, spec_mts, spec_mblk, spec2, spec2],
-        out_shape=[sds((lanes, nb, ways), i32),
-                   sds((lanes, nb * ways, r_sup), i32),
-                   sds((lanes, nb, ways), i32),
-                   sds((lanes, nb, ways), i32),
-                   sds((lanes, nb, ways), i32),
-                   sds((lanes, nb, ways), i32),
-                   sds((lanes, nm, 1), i32),
-                   sds((lanes, nm, s_sup), i32),
-                   sds((lanes, nm, 1), i32),
-                   sds((lanes, 1), i32),
-                   sds((lanes, 1), i32)],
-        # state arrays update in place: input i+2 -> output i
-        input_output_aliases={i + 2: i for i in range(11)},
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5, grid=(lanes,), in_specs=tables,
+            out_specs=tables + [fill_spec]),
+        out_shape=[sds(t.shape, i32) for t in tabs]
+        + [sds((lanes, 1, LANE_TILE), i32)],
+        # tables update in place: input i+5 -> output i
+        input_output_aliases={i + 5: i for i in range(len(tabs))},
         interpret=interpret,
-    )(block, enabled, rec_key, rec_ts_flat, rec_cnt, rec_age, rec_loc,
-      rec_row, mine_block, mine_ts, mine_cnt, mine_fill, ts)
+        name="mithril_record",
+    )(block, enabled, bucket, mine_fill, ts, *tabs)

@@ -58,12 +58,9 @@ def _tensor_bytes(type_str: str) -> int:
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """Version-portable ``Compiled.cost_analysis()``: older jaxlibs return
-    a one-element list of per-module dicts, newer return the dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    """``Compiled.cost_analysis()`` as a dict (empty when the backend
+    reports nothing)."""
+    return compiled.cost_analysis() or {}
 
 
 def parse_collectives(hlo_text: str):
@@ -117,7 +114,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, strategy: str,
     specs = input_specs(arch, shape)
     jfn, args = jit_cell(mesh, specs, strategy=strategy,
                          flags=RunFlags(remat=remat))
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jfn.lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()

@@ -40,8 +40,8 @@ from repro.models.attention import block_plan
 from repro.models.lm import apply_layer, layer_groups
 from repro.models.rwkv6 import CHUNK as RWKV_CHUNK
 
-PEAK_FLOPS = 197e12      # bf16 / chip
-HBM_BW = 819e9           # bytes/s / chip
+PEAK_FLOPS = 197e12      # bf16 / chip (TPU v5e)
+HBM_BW = 819e9           # bytes/s / chip (TPU v5e)
 ICI_BW = 50e9            # bytes/s / link
 
 
@@ -189,7 +189,7 @@ def _group_probe(cfg: ModelConfig, gi: int, unit, reps, mesh, kind: str,
             return body(p_sl, x, positions, c_sl)
 
     jfn = jax.jit(wrapped, in_shardings=(psh, xsh, possh, csh))
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = jfn.lower(p_slice, x_sds, pos_sds, cache_slice).compile()
     ca = cost_analysis_dict(compiled)
     coll, _ = parse_collectives(compiled.as_text())
@@ -325,13 +325,19 @@ def save_roofline(rl: Roofline, out_dir: str = "results/roofline"):
 # i.e. the copy-through upper bound for aliased in-place kernels — a
 # kernel can touch fewer bytes, never more). Flops counts the integer
 # compare/select lattice (int ops ~ flops on the VPU). Machine peaks
-# come from ``machine_peaks``: the TPU numbers are the trusted v5e
-# datasheet constants used by the model roofline; any other backend
-# gets finite nominal placeholders flagged ``trusted=False`` so CI on
-# CPU can still round-trip the report without gating on made-up peaks.
-# Interpreted-mode wall-clock never enters these numbers (DESIGN.md §11).
+# come from ``machine_peaks``: a TPU's are looked up by its
+# ``device_kind`` in ``TPU_PEAKS``, and a kind missing from the table is
+# an error; any other backend gets finite nominal placeholders flagged
+# ``trusted=False`` so CI on CPU can still round-trip the report. They
+# are never a device number. Interpreted-mode wall-clock never enters
+# these numbers (DESIGN.md §11).
 
-_NOMINAL_FLOPS = 1e12    # untrusted placeholder peaks for cpu/gpu/unknown
+# (peak bf16 flop/s, HBM bytes/s) per chip, keyed by ``device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
+# 819 GB/s HBM).
+TPU_PEAKS = {"TPU v5 lite": (PEAK_FLOPS, HBM_BW)}
+
+_NOMINAL_FLOPS = 1e12    # untrusted placeholder peaks for cpu/gpu
 _NOMINAL_BW = 100e9
 
 
@@ -343,29 +349,37 @@ class MachinePeaks:
     trusted: bool
 
 
-def machine_peaks(backend: Optional[str] = None) -> MachinePeaks:
+def machine_peaks(backend: Optional[str] = None,
+                  device_kind: Optional[str] = None) -> MachinePeaks:
     """Peak flops/bandwidth for ``backend`` (default: the live backend).
 
-    Never raises: unknown backends fall back to finite nominal peaks
-    with ``trusted=False`` so reports stay well-formed everywhere and
-    only TPU numbers are presented as machine-true.
+    A TPU's peaks come from ``TPU_PEAKS`` by ``device_kind`` (default:
+    the first live device's); a kind missing from the table raises.
+    Other backends get finite nominal peaks with ``trusted=False``, so
+    reports stay well-formed on CPU CI without passing as device peaks.
     """
     if backend is None:
         backend = jax.default_backend()
     if backend == "tpu":
-        return MachinePeaks("tpu", PEAK_FLOPS, HBM_BW, True)
+        kind = device_kind or jax.devices()[0].device_kind
+        if kind not in TPU_PEAKS:
+            raise ValueError(f"no published peaks for TPU kind {kind!r}; "
+                             f"known: {sorted(TPU_PEAKS)}")
+        return MachinePeaks("tpu", *TPU_PEAKS[kind], True)
     return MachinePeaks(str(backend), _NOMINAL_FLOPS, _NOMINAL_BW, False)
 
 
 def _record_fused_cost(g: dict):
-    """One ``mithril_record_fused`` launch: every lane's record + mining
-    tables stream through VMEM once in, once out (the copy-through
-    bound), plus the scalar lane blocks; compute is the W-way probe,
-    R-slot stamp and S-slot insert select lattice."""
+    """One ``mithril_record_fused`` launch: per lane, the probed
+    bucket's window of the recording table (``bucket_window`` buckets)
+    and the whole mining table stream through VMEM once in, once out,
+    plus the scalar-prefetched lane values and the fill row; compute
+    is the W-way probe, R-slot stamp and S-slot insert select lattice."""
+    from repro.kernels.mithril_record import LANE_TILE, bucket_window
     lanes, nb, w = g["lanes"], g["n_buckets"], g["ways"]
     r, nm, s = g["r_sup"], g["mine_rows"], g["s_sup"]
-    table_words = nb * w * (5 + r) + nm * (2 + s)
-    bytes_ = lanes * (2 * table_words + 6) * 4
+    table_words = bucket_window(nb) * w * (5 + r) + nm * (2 + s)
+    bytes_ = lanes * (2 * table_words + 5 + LANE_TILE) * 4
     flops = lanes * (16 + 8 * w + 6 * r + 8 * s)
     return float(bytes_), float(flops)
 
@@ -382,15 +396,19 @@ def _mine_batched_cost(g: dict):
 
 
 def _hash_lookup_cost(g: dict):
-    """One ``hash_lookup`` prefetch-table probe launch: the whole
-    set-associative prefetch table (keys + P-wide candidate rows)
-    streams into VMEM once per launch — every grid block reads it whole
-    — plus the query block in and the candidate lists out; compute is
-    the mix32 hash, the W-way compare/argmax and the P-wide found
-    select per query."""
+    """One ``hash_lookup`` prefetch-table probe launch: per query, the
+    probed bucket's window (``bucket_window`` buckets) of the keys and
+    the P-wide candidate rows streams into VMEM — once in all when the
+    window is the whole table, since an unchanged block is not fetched
+    again — plus the query and its bucket in and the candidate list
+    out; compute is the mix32 hash, the W-way compare/argmax and the
+    P-wide found select per query."""
+    from repro.kernels.mithril_record import bucket_window
     q, nb = g["queries"], g["n_buckets"]
     w, p = g["ways"], g["plist"]
-    bytes_ = (nb * w * (1 + p) + q * (1 + p)) * 4
+    win = bucket_window(nb)
+    fetches = 1 if win == nb else q
+    bytes_ = (fetches * win * w * (1 + p) + q * (2 + p)) * 4
     flops = q * (8.0 + 4 * w + 2 * p)
     return float(bytes_), float(flops)
 

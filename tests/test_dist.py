@@ -11,6 +11,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import ARCHS, reduced_config
 from repro.dist import sharding as shd
 from repro.dist.ctx import constrain, current, resolve, sharding_ctx
+from repro.launch.mesh import auto_mesh
 from repro.launch.specs import batch_sds, cache_sds, opt_sds, params_sds
 from repro.optim import adamw
 
@@ -29,7 +30,7 @@ MESH_POD = FakeMesh((2, 4, 4), ("pod", "data", "model"))
 
 
 def real_mesh():
-    return jax.make_mesh((jax.device_count(), 1), ("data", "model"))
+    return auto_mesh((jax.device_count(), 1), ("data", "model"))
 
 
 # ---------------------------------------------------------------------------

@@ -55,9 +55,11 @@ def test_analyzer_cost_scales_with_geometry():
 
 
 def test_machine_peaks_trusted_only_on_tpu():
-    tpu = machine_peaks("tpu")
+    tpu = machine_peaks("tpu", "TPU v5 lite")
     assert tpu.trusted and tpu.flops_per_s == PEAK_FLOPS \
         and tpu.bytes_per_s == HBM_BW
+    with pytest.raises(ValueError, match="no published peaks"):
+        machine_peaks("tpu", "TPU v99")
     for backend in ("cpu", "gpu", "warp9"):
         pk = machine_peaks(backend)
         assert not pk.trusted

@@ -10,6 +10,7 @@ import pytest
 
 from repro.checkpoint import CheckpointManager, plan_remesh
 from repro.data import DataConfig, SyntheticPipeline
+from repro.launch.mesh import auto_mesh
 from repro.runtime import (HeartbeatMonitor, StragglerPolicy, WorkerFailure,
                            compressed_psum, dequantize_int8, fake_quant_grads,
                            quantize_int8, run_with_restarts)
@@ -97,7 +98,7 @@ class TestElastic:
         from repro.launch.specs import params_sds
         cfg = reduced_config(ARCHS["llama3.2-3b"])
         p = params_sds(cfg)
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = auto_mesh((1, 1), ("data", "model"))
         rep = plan_remesh(p, (2, 2), mesh)
         assert rep["n_devices"] == 1 and rep["leaves"] > 10
 
@@ -107,7 +108,7 @@ class TestElastic:
         ckpt = CheckpointManager(str(tmp_path))
         state = {"w": jnp.arange(32.0).reshape(4, 8)}
         ckpt.save(1, state)
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = auto_mesh((1, 1), ("data", "model"))
         sh = shd.to_named(shd.param_specs(state, mesh), mesh)
         _, restored = ckpt.restore(state, shardings=sh)
         np.testing.assert_array_equal(np.asarray(restored["w"]),
@@ -130,10 +131,9 @@ class TestCompression:
 
     def test_compressed_psum_shard_map(self):
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
-        mesh = jax.make_mesh((1,), ("x",))
+        mesh = auto_mesh((1,), ("x",))
         x = jnp.asarray(np.linspace(-1, 1, 64), jnp.float32)
-        f = shard_map(functools.partial(compressed_psum, axis_name="x"),
+        f = jax.shard_map(functools.partial(compressed_psum, axis_name="x"),
                       mesh=mesh, in_specs=P(), out_specs=P())
         np.testing.assert_allclose(np.asarray(f(x)), np.asarray(x),
                                    rtol=2e-2, atol=2e-2)

@@ -70,16 +70,34 @@ def test_jit_cell_compiles_on_smoke_mesh(monkeypatch):
     import repro.configs as C
     from repro.configs import ShapeSpec, reduced_config
     from repro.launch.specs import input_specs
+    from repro.launch.mesh import auto_mesh
     from repro.launch.steps import jit_cell
 
     tiny = dataclasses.replace(reduced_config(C.ARCHS["llama3.2-3b"]),
                                name="tiny-test")
     monkeypatch.setitem(C.ARCHS, "tiny-test", tiny)
     shape = ShapeSpec("t", 64, 2, "train")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     specs = input_specs("tiny-test", shape)
     jfn, args = jit_cell(mesh, specs)
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = jfn.lower(*args).compile()
     from repro.launch.dryrun import cost_analysis_dict
     assert cost_analysis_dict(compiled).get("flops", 0) > 0
+
+
+def test_compile_cache_location(monkeypatch):
+    """The persistent compile cache goes where ``JAX_COMPILATION_CACHE_DIR``
+    says (JAX reads the variable itself), else to ``<repo>/.jax_cache``."""
+    from repro.launch import compile_cache as cc
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(cc.ENV_VAR, "/cache/from/env")
+        assert cc.enable_compile_cache() == "/cache/from/env"
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv(cc.ENV_VAR)
+        assert cc.enable_compile_cache() == str(cc.REPO_CACHE)
+        assert jax.config.jax_compilation_cache_dir == str(cc.REPO_CACHE)
+        assert (cc.REPO_CACHE.parent / "src" / "repro").is_dir()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
