@@ -198,49 +198,45 @@ def build_segments(cfg: SimConfig):
         lambda block, aux: (aux["ev"].block, aux["ev"].block != EMPTY)
     seg_record_all.record_gate = lambda block, aux: (block, aux["valid"])
 
+    # Prefetching, one segment per enabled layer (no mining in
+    # these), in the order MITHRIL, AMP, PG: each inserts into the cache
+    # the previous one left.
     def seg_prefetch(carry, block, aux):
-        """Prefetch issue for every enabled layer (no mining in here)."""
+        """MITHRIL prefetch-list check (Alg. 3 pFlag path)."""
+        cands = mithril.lookup(cfg.mithril, carry["mith"], block)
+        cache, stats, _ = _apply_prefetches(
+            cfg, carry["cache"], carry["stats"], cands, PF_MITHRIL,
+            aux["valid"], scorer=scorer)
+        return {**carry, "cache": cache, "stats": stats}, aux
+
+    def seg_amp(carry, block, aux):
+        """AMP sequential prefetching + degree feedback. Every piece is
+        source-gated: the feedbacks key off valid-gated signals
+        (used_src / eviction records are inert on invalid requests) and
+        amp_access takes `valid` directly, so no subtree select remains."""
+        valid, ev = aux["valid"], aux["ev"]
+        amp = amp_feedback_used(cfg.amp, carry["amp"], block,
+                                aux["used_src"] == PF_AMP)
+        amp, vec = amp_access(cfg.amp, amp, block, enabled=valid)
+        cache, stats, evs = _apply_prefetches(
+            cfg, carry["cache"], carry["stats"], vec, PF_AMP, valid,
+            scorer=scorer)
+        evb, evu, evsrc = evs
+        for i in range(evb.shape[0]):
+            amp = amp_feedback_evicted(cfg.amp, amp, evb[i],
+                                       evu[i] & (evsrc[i] == PF_AMP))
+        amp = amp_feedback_evicted(cfg.amp, amp, ev.block,
+                                   ev.unused_pf & (ev.pf_src == PF_AMP))
+        return {**carry, "cache": cache, "stats": stats, "amp": amp}, aux
+
+    def seg_pg(carry, block, aux):
+        """Probability-graph prefetching."""
         valid = aux["valid"]
-        cache, stats = carry["cache"], carry["stats"]
-        used_src, ev = aux["used_src"], aux["ev"]
-        out = dict(carry)
-
-        # MITHRIL prefetch-list check (Alg. 3 pFlag path)
-        if cfg.use_mithril:
-            cands = mithril.lookup(cfg.mithril, carry["mith"], block)
-            cache, stats, _ = _apply_prefetches(cfg, cache, stats, cands,
-                                                PF_MITHRIL, valid,
-                                                scorer=scorer)
-
-        # AMP sequential prefetching + degree feedback. Every piece is
-        # source-gated: the feedbacks key off valid-gated signals
-        # (used_src / eviction records are inert on invalid requests) and
-        # amp_access takes `valid` directly, so no subtree select remains
-        if cfg.use_amp:
-            amp = amp_feedback_used(cfg.amp, carry["amp"], block,
-                                    used_src == PF_AMP)
-            amp, vec = amp_access(cfg.amp, amp, block, enabled=valid)
-            cache, stats, evs = _apply_prefetches(cfg, cache, stats, vec,
-                                                  PF_AMP, valid,
-                                                  scorer=scorer)
-            evb, evu, evsrc = evs
-            for i in range(evb.shape[0]):
-                amp = amp_feedback_evicted(cfg.amp, amp, evb[i],
-                                           evu[i] & (evsrc[i] == PF_AMP))
-            amp = amp_feedback_evicted(cfg.amp, amp, ev.block,
-                                       ev.unused_pf & (ev.pf_src == PF_AMP))
-            out["amp"] = amp
-
-        # probability graph
-        if cfg.use_pg:
-            pg = carry["pg"]
-            pg, cands = pg_access(cfg.pg, pg, block, enabled=valid)
-            cache, stats, _ = _apply_prefetches(cfg, cache, stats, cands,
-                                                PF_PG, valid, scorer=scorer)
-            out["pg"] = pg
-
-        out["cache"], out["stats"] = cache, stats
-        return out, aux
+        pg, cands = pg_access(cfg.pg, carry["pg"], block, enabled=valid)
+        cache, stats, _ = _apply_prefetches(
+            cfg, carry["cache"], carry["stats"], cands, PF_PG, valid,
+            scorer=scorer)
+        return {**carry, "cache": cache, "stats": stats, "pg": pg}, aux
 
     segments = [(seg_access, False)]
     if cfg.use_mithril:
@@ -250,7 +246,9 @@ def build_segments(cfg: SimConfig):
             segments.append((seg_record_evict, True))
         if rec_on == "all":
             segments.append((seg_record_all, True))
-    segments.append((seg_prefetch, False))
+    segments += [(fn, False) for fn, on in ((seg_prefetch, cfg.use_mithril),
+                                            (seg_amp, cfg.use_amp),
+                                            (seg_pg, cfg.use_pg)) if on]
     return init_carry, segments
 
 

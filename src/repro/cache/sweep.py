@@ -47,9 +47,11 @@ Batching invariants (DESIGN.md §6–§7):
 from __future__ import annotations
 
 import collections
+import contextlib
 import functools
 import queue as _queue_mod
 import threading
+import time
 from typing import Dict, List, Mapping, NamedTuple, Optional, Sequence, \
     Tuple, Union
 
@@ -57,6 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.profiler import TraceAnnotation
 
 from repro.core import mithril
 from .simulator import SimConfig, SimResult, Stats, build_segments
@@ -127,6 +130,13 @@ def _batched_record_fn():
     return mithril_record_fused
 
 
+# Columns of the carry's per-lane ``mine_passes`` counters: passes in
+# which the lane was mined alone, all-lanes passes it took part in, and
+# all-lanes passes it led (the first lane mined in the pass), so the
+# last column sums to the all-lanes passes, one per device that ran one.
+MINE_PASS_KEYS = ("solo_passes", "fused_lanes", "fused_passes")
+
+
 def build_batched_step(cfg: SimConfig):
     """Returns (init_batched, step) for a scan over (chunk, B) request slabs.
 
@@ -135,6 +145,13 @@ def build_batched_step(cfg: SimConfig):
     each mining barrier runs one batch-level ``lax.cond`` around the
     fused ``mithril.mine_batched``, and invalid (padded) lanes keep
     their previous carry bit-for-bit.
+
+    Each part of the step runs under a ``jax.named_scope`` — ``access``,
+    ``record``, ``barrier``, ``prefetch`` (MITHRIL's lookup and inserts),
+    ``amp``, ``pg``, from the segment's name — so XLA labels every
+    operation, and a device trace splits the step by part. With MITHRIL
+    on, the carry holds per-lane ``mine_passes`` counters
+    (``MINE_PASS_KEYS``) that the barrier bumps.
     """
     init_carry, segments = build_segments(cfg)
     mine_rows = cfg.mithril.mine_rows
@@ -142,10 +159,17 @@ def build_batched_step(cfg: SimConfig):
         _batched_pairwise_fn() if cfg.use_mithril else (None, None))
     record_fn = _batched_record_fn() if cfg.use_mithril else None
 
-    def init_batched(batch_size: int):
-        return jax.vmap(lambda _: init_carry())(jnp.arange(batch_size))
+    def init_lane(_):
+        carry = init_carry()
+        if cfg.use_mithril:
+            carry["mine_passes"] = jnp.zeros((len(MINE_PASS_KEYS),),
+                                             jnp.int32)
+        return carry
 
-    def batched_maybe_mine(mith, valid):
+    def init_batched(batch_size: int):
+        return jax.vmap(init_lane)(jnp.arange(batch_size))
+
+    def batched_maybe_mine(carry, valid):
         """Mine exactly the lanes whose table filled this step.
 
         This runs at batch level — *outside* vmap — so the outer
@@ -155,14 +179,24 @@ def build_batched_step(cfg: SimConfig):
         and folds pairs in with vmapped scatter updates; lanes with
         ``need=False`` select their previous state bit-for-bit. On every
         other step the barrier costs one predicate reduction.
+        ``mine_batched`` takes its one-lane path when exactly one lane
+        is mined, and the counters say which path each lane took.
         """
+        mith = carry["mith"]
         need = (mith.mine_fill >= mine_rows) & valid
-        return lax.cond(
+        mith = lax.cond(
             jnp.any(need),
             lambda m: mithril.mine_batched(
                 cfg.mithril, m, need, pairwise_fn=pairwise_fn,
                 serial_pairwise_fn=serial_pairwise_fn),
             lambda m: m, mith)
+        n = jnp.sum(need.astype(jnp.int32))
+        fused = need & (n > 1)
+        lead = fused & (jnp.arange(need.shape[0]) == jnp.argmax(need))
+        passes = jnp.stack([need & (n == 1), fused, lead], axis=1)
+        return {**carry, "mith": mith,
+                "mine_passes": carry["mine_passes"]
+                + passes.astype(jnp.int32)}
 
     def step(carry, xs):
         block, valid = xs
@@ -177,15 +211,17 @@ def build_batched_step(cfg: SimConfig):
                 # record path (fused Pallas kernel on TPU, identical
                 # vmapped scatter form elsewhere) instead of vmapping
                 # the segment closure
-                blk, en = gate(block, aux)
-                new = {**new, "mith": mithril.record_event_batched(
-                    cfg.mithril, new["mith"], blk, en,
-                    fused_fn=record_fn)}
+                with jax.named_scope("record"):
+                    blk, en = gate(block, aux)
+                    new = {**new, "mith": mithril.record_event_batched(
+                        cfg.mithril, new["mith"], blk, en,
+                        fused_fn=record_fn)}
             else:
-                new, aux = jax.vmap(fn)(new, block, aux)
+                with jax.named_scope(fn.__name__.removeprefix("seg_")):
+                    new, aux = jax.vmap(fn)(new, block, aux)
             if mine_after:
-                new = {**new,
-                       "mith": batched_maybe_mine(new["mith"], valid)}
+                with jax.named_scope("barrier"):
+                    new = batched_maybe_mine(new, valid)
         return new, aux["hit"]
 
     return init_batched, step
@@ -355,8 +391,6 @@ def sweep(cfg: SimConfig, blocks: np.ndarray,
     (per-lane results stay bit-identical — lanes are independent);
     ``False`` forces the single-device runner.
     """
-    import time
-
     t0 = time.time()
     blocks = np.ascontiguousarray(np.asarray(blocks, np.int32))
     if blocks.ndim != 2:
@@ -656,8 +690,6 @@ def sweep_scheduled(cfg: SimConfig,
     padded with empty (length-0) lanes, which are bit-exact no-ops under
     the §6 masking contract.
     """
-    import time
-
     t0 = time.time()
     if not isinstance(traces, np.ndarray):
         # suite-like inputs carry their own lengths; a conflicting
@@ -738,6 +770,22 @@ def sweep_grid(cfgs: Dict[str, SimConfig], blocks: np.ndarray,
 # ---------------------------------------------------------------------------
 
 DEFAULT_RING_DEPTH = 4      # slabs the producer stages ahead of the device
+
+
+@contextlib.contextmanager
+def _span(name: str, timers: Optional[Dict[str, float]] = None,
+          key: Optional[str] = None, **args):
+    """A host span on the profiler's clock: a ``TraceAnnotation`` named
+    ``name``, carrying ``args`` (a slab's index), whose seconds are also
+    added to ``timers[key]`` when ``key`` is given. The engine's stage
+    timings and its profiler spans are the same intervals."""
+    t = time.perf_counter()
+    try:
+        with TraceAnnotation(name, **args):
+            yield
+    finally:
+        if key is not None:
+            timers[key] += time.perf_counter() - t
 
 
 class _Tenant:
@@ -901,17 +949,27 @@ class StreamResult(NamedTuple):
     recycling analogue of ``SweepPlan.padded_lane_steps``.
 
     ``pipeline`` carries the producer-pipeline telemetry: stage-busy
-    seconds (``produce_s`` host marshalling + H2D staging,
-    ``consume_s`` reset + chunk-scan dispatch, ``drain_s`` D2H
-    materialization + hit-curve scatter), the loop wall clock
-    ``wall_s``, the ring-buffer stall counters (``producer_stalls`` =
-    producer blocked on a full ring, ``consumer_stalls`` = consumer
-    blocked on an empty ring) and ``overlap`` = ``1 - wall / sum of
-    stage-busy`` clipped to [0, 1] — 0 when the stages serialize,
-    approaching ``1 - 1/n_stages`` when they fully overlap. Timings
-    and stalls are scheduling noise (WARN-gated in
-    ``benchmarks.compare``); every other ``streaming_stats`` key is
-    deterministic and FAIL-gated.
+    seconds (``produce_s`` admission, marshalling and the H2D staging
+    call, ``consume_s`` reset + chunk-scan dispatch, ``drain_s`` D2H
+    materialization + hit-curve scatter), ``staging_wait_s`` (the
+    producer idle, waiting for a free staging buffer; not busy), the
+    loop wall clock ``wall_s``, the ring-buffer stall counters
+    (``producer_stalls`` = producer blocked on a full ring,
+    ``consumer_stalls`` = consumer blocked on an empty ring) and
+    ``overlap`` = ``1 - wall / sum of stage-busy`` clipped to [0, 1] — 0
+    when the stages serialize, approaching ``1 - 1/n_stages`` when they
+    fully overlap. Each timing is the sum of the engine's ``sweep.*``
+    profiler spans of that stage. Timings and stalls are scheduling
+    noise (WARN-gated in ``benchmarks.compare``); every other
+    ``streaming_stats`` key is deterministic and FAIL-gated.
+
+    ``mine_passes`` (MITHRIL configurations only) holds each trace's
+    mining passes, in submission order, by the columns of
+    ``MINE_PASS_KEYS``: passes that mined its lane alone, all-lanes
+    passes that mined it, and all-lanes passes it led. A trace's solo
+    passes plus its all-lanes passes are the passes the serial
+    ``simulate`` mines it with; which path a pass takes depends on how
+    many lanes of a device fill their mining tables on the same step.
     """
 
     result: SweepResult
@@ -921,6 +979,7 @@ class StreamResult(NamedTuple):
     async_producer: bool = True
     pipeline: Optional[Dict[str, object]] = None
     n_shards: int = 1           # devices the lane axis was split over
+    mine_passes: Optional[np.ndarray] = None    # (n, 3) int64
 
     @property
     def lane_steps(self) -> int:
@@ -941,6 +1000,9 @@ class StreamResult(NamedTuple):
         }
         if self.pipeline is not None:
             stats["pipeline"] = dict(self.pipeline)
+        if self.mine_passes is not None:
+            stats["mining"] = dict(zip(
+                MINE_PASS_KEYS, map(int, self.mine_passes.sum(axis=0))))
         return stats
 
 
@@ -990,10 +1052,16 @@ def sweep_streaming(cfg: SimConfig,
     — the legacy produce/consume loop, pinned by
     ``tests/test_async_pipeline.py``). Stage timings, ring stall
     counters and the overlap ratio surface in
-    :meth:`StreamResult.streaming_stats` under ``"pipeline"``.
-    """
-    import time
+    :meth:`StreamResult.streaming_stats` under ``"pipeline"``, and the
+    mining passes by path under ``"mining"``.
 
+    Each stage runs under a ``jax.profiler.TraceAnnotation`` — per slab
+    ``sweep.staging_wait``, ``sweep.produce`` (holding ``sweep.stage``),
+    ``sweep.reset``, ``sweep.dispatch`` and ``sweep.drain``, each with
+    the slab's index as ``slab``; once a call ``sweep.setup`` and
+    ``sweep.harvest`` — so a profiler trace names what the host was
+    doing on the device's clock.
+    """
     t0 = time.time()
     if isinstance(async_producer, np.bool_):
         async_producer = bool(async_producer)
@@ -1047,9 +1115,10 @@ def sweep_streaming(cfg: SimConfig,
     tenants = [_Tenant(i, blocks[i], avails[i], int(lengths[i]))
                for i in range(n)]
 
-    init_batched, run_chunk, place = _runner(cfg, unroll, n_shards)
-    before = compile_count(cfg, unroll, n_shards)
-    template = place(init_batched(w))
+    with _span("sweep.setup"):
+        init_batched, run_chunk, place = _runner(cfg, unroll, n_shards)
+        before = compile_count(cfg, unroll, n_shards)
+        template = place(init_batched(w))
     carry = template
     if n_shards > 1:
         from repro.dist import sharding as dist_sharding
@@ -1064,8 +1133,9 @@ def sweep_streaming(cfg: SimConfig,
     queue: collections.deque = collections.deque(range(n))
     lanes: List[Optional[int]] = [None] * w
     clock = 0
-    # tenant -> (stats pytree reference, lane) snapshotted at drain time
-    stash: List[Optional[Tuple[Stats, int]]] = [None] * n
+    # tenant -> (stats pytree, mining counters, lane) references,
+    # snapshotted at drain time
+    stash: List[Optional[Tuple[Stats, Optional[jax.Array], int]]] = [None] * n
 
     # --- staging: how host slab arrays become device arrays ------------
     # Sync keeps the legacy throwaway jnp.asarray staging bit for bit.
@@ -1083,12 +1153,6 @@ def sweep_streaming(cfg: SimConfig,
             pool.put((np.zeros((chunk, w), np.int32),
                       np.zeros((chunk, w), bool)))
 
-        def alloc():
-            b, v = pool.get()
-            b.fill(0)
-            v.fill(False)
-            return b, v
-
         if n_shards > 1:
             def stage(b, v):
                 return dist_sharding.ring_put((b, v), mesh, axis=LANE_AXIS)
@@ -1096,18 +1160,21 @@ def sweep_streaming(cfg: SimConfig,
             def stage(b, v):
                 return jax.device_put((b, v))
     else:
-        def alloc():
-            return (np.zeros((chunk, w), np.int32),
-                    np.zeros((chunk, w), bool))
-
         def stage(b, v):
             return jnp.asarray(b), jnp.asarray(v)
 
-    timers = {"produce_s": 0.0, "consume_s": 0.0, "drain_s": 0.0}
+    timers = {"produce_s": 0.0, "staging_wait_s": 0.0, "consume_s": 0.0,
+              "drain_s": 0.0}
+    made = 0            # slabs produced, so the next slab's index
 
-    def produce() -> Optional[_Slab]:
-        nonlocal clock
-        tp = time.perf_counter()
+    def produce(bufs: Tuple[np.ndarray, np.ndarray]) -> Optional[_Slab]:
+        """The next slab, marshalled into ``bufs`` and staged; ``None``
+        once every trace is placed."""
+        with _span("sweep.produce", timers, "produce_s", slab=made):
+            return _produce(bufs)
+
+    def _produce(bufs) -> Optional[_Slab]:
+        nonlocal clock, made
         while True:
             t_start = clock
             reset = np.zeros((w,), bool)
@@ -1117,7 +1184,8 @@ def sweep_streaming(cfg: SimConfig,
                 # zero-length submissions drain at admission: init stats,
                 # no lane occupied (bit-identical to an all-masked lane)
                 while queue and tenants[queue[0]].length == 0:
-                    stash[queue.popleft()] = (template["stats"], 0)
+                    stash[queue.popleft()] = (template["stats"],
+                                              template.get("mine_passes"), 0)
                 if not queue:
                     break
                 head = tenants[queue[0]]
@@ -1132,13 +1200,14 @@ def sweep_streaming(cfg: SimConfig,
             if any(la is not None for la in lanes):
                 break
             if not queue:
-                timers["produce_s"] += time.perf_counter() - tp
                 return None     # fully drained
             # every lane idle, nothing arrived yet: fast-forward the
             # clock to the slab containing the head's first arrival
             head = tenants[queue[0]]
             clock = (int(head.avail[head.cursor]) // chunk) * chunk
-        slab_blocks, slab_valid = alloc()
+        slab_blocks, slab_valid = bufs
+        slab_blocks.fill(0)
+        slab_valid.fill(False)
         placements, harvest = [], []
         for lane, ti in enumerate(lanes):
             if ti is None:
@@ -1179,8 +1248,9 @@ def sweep_streaming(cfg: SimConfig,
                 harvest.append((ti, lane))
                 lanes[lane] = None      # recycled at the next admission
         clock = t_start + chunk
-        dev_blocks, dev_valid = stage(slab_blocks, slab_valid)
-        timers["produce_s"] += time.perf_counter() - tp
+        with _span("sweep.stage", slab=made):
+            dev_blocks, dev_valid = stage(slab_blocks, slab_valid)
+        made += 1
         return _Slab(dev_blocks, dev_valid,
                      reset if reset.any() else None,
                      tuple(placements), tuple(harvest),
@@ -1188,16 +1258,33 @@ def sweep_streaming(cfg: SimConfig,
 
     hit_curve = np.zeros((n, t_max), bool)
 
-    def scatter_hits(hits, placements) -> None:
-        h = np.asarray(hits)                    # (chunk, W); blocks on
-        for lane, ti, c0, row0, k, pos in placements:   # device results
-            if pos is None:
-                hit_curve[ti, c0: c0 + k] = h[row0: row0 + k, lane]
-            else:
-                hit_curve[ti, c0: c0 + k] = h[pos, lane]
+    def drain(hits, placements, index: int) -> None:
+        """Scatter slab ``index``'s hit rows into the hit curve."""
+        with _span("sweep.drain", timers, "drain_s", slab=index):
+            h = np.asarray(hits)                # (chunk, W); blocks on
+            for lane, ti, c0, row0, k, pos in placements:  # the device
+                if pos is None:
+                    hit_curve[ti, c0: c0 + k] = h[row0: row0 + k, lane]
+                else:
+                    hit_curve[ti, c0: c0 + k] = h[pos, lane]
+
+    def consume(slab: _Slab, index: int):
+        """Reset slab ``index``'s admitted lanes, dispatch its chunk scan
+        and snapshot the lanes that drain with it; returns its hit rows."""
+        nonlocal carry
+        # slab 0 skips the reset outright: the carry IS the template
+        if slab.reset is not None and index:
+            with _span("sweep.reset", timers, "consume_s", slab=index):
+                carry = _masked_reset(carry, template,
+                                      place_mask(slab.reset))
+        with _span("sweep.dispatch", timers, "consume_s", slab=index):
+            carry, hits = run_chunk(carry, slab.blocks, slab.valid)
+            for ti, lane in slab.harvest:
+                stash[ti] = (carry["stats"], carry.get("mine_passes"), lane)
+        return hits
 
     ring = RingBuffer(ring_depth)
-    n_slabs, first_slab = 0, True
+    n_slabs = 0
     t_wall = time.perf_counter()
 
     if async_producer:
@@ -1213,7 +1300,13 @@ def sweep_streaming(cfg: SimConfig,
         def producer_main():
             try:
                 while True:
-                    slab = produce()
+                    # the drain recycles a buffer pair only after the
+                    # slab's outputs materialize — by then the chunk scan
+                    # has consumed the upload, so reuse is safe
+                    with _span("sweep.staging_wait", timers,
+                               "staging_wait_s", slab=made):
+                        bufs = pool.get()
+                    slab = produce(bufs)
                     if slab is None:
                         break
                     ring.push(slab, block=True)
@@ -1227,17 +1320,14 @@ def sweep_streaming(cfg: SimConfig,
                 item = drain_q.get()
                 if item is None:
                     return
-                hits, placements, bufs = item
-                td = time.perf_counter()
+                hits, slab, k = item
                 try:
                     if not drain_err:
-                        scatter_hits(hits, placements)
+                        drain(hits, slab.placements, k)
                 except BaseException as e:  # noqa: BLE001
                     drain_err.append(e)     # keep draining: never block
                 finally:                    # the consumer on a dead drain
-                    timers["drain_s"] += time.perf_counter() - td
-                    if bufs is not None:
-                        pool.put(bufs)
+                    pool.put(slab.buffers)
 
         producer = threading.Thread(target=producer_main, daemon=True,
                                     name="sweep-producer")
@@ -1250,18 +1340,9 @@ def sweep_streaming(cfg: SimConfig,
                 slab = ring.pop(block=True)
                 if slab is None:
                     break
-                tc = time.perf_counter()
-                # slab 0 skips the reset outright: carry IS the template
-                if slab.reset is not None and not first_slab:
-                    carry = _masked_reset(carry, template,
-                                          place_mask(slab.reset))
-                first_slab = False
-                carry, hits = run_chunk(carry, slab.blocks, slab.valid)
-                for ti, lane in slab.harvest:
-                    stash[ti] = (carry["stats"], lane)
+                hits = consume(slab, n_slabs)
+                drain_q.put((hits, slab, n_slabs))
                 n_slabs += 1
-                timers["consume_s"] += time.perf_counter() - tc
-                drain_q.put((hits, slab.placements, slab.buffers))
         finally:
             ring.close()        # unblocks a producer stuck mid-push
             drain_q.put(None)
@@ -1278,7 +1359,8 @@ def sweep_streaming(cfg: SimConfig,
         producing = True
         while True:
             while producing and not ring.full:
-                slab = produce()
+                slab = produce((np.empty((chunk, w), np.int32),
+                                np.empty((chunk, w), bool)))
                 if slab is None:
                     producing = False
                     break
@@ -1286,39 +1368,35 @@ def sweep_streaming(cfg: SimConfig,
             if ring.empty:
                 break
             slab = ring.pop()
-            tc = time.perf_counter()
-            # slab 0 skips the reset outright: the carry IS the template
-            if slab.reset is not None and not first_slab:
-                carry = _masked_reset(carry, template,
-                                      place_mask(slab.reset))
-            first_slab = False
-            carry, hits = run_chunk(carry, slab.blocks, slab.valid)
-            hit_records.append((hits, slab.placements))
-            for ti, lane in slab.harvest:
-                stash[ti] = (carry["stats"], lane)
+            hit_records.append((consume(slab, n_slabs), slab.placements))
             n_slabs += 1
-            timers["consume_s"] += time.perf_counter() - tc
 
         # materialize: everything device-side resolved once, at the end
-        td = time.perf_counter()
-        for hits, placements in hit_records:
-            scatter_hits(hits, placements)
-        timers["drain_s"] += time.perf_counter() - td
+        for k, (hits, placements) in enumerate(hit_records):
+            drain(hits, placements, k)
 
     wall_s = time.perf_counter() - t_wall
-    mat: Dict[int, list] = {}
-    rows = []
-    for ti in range(n):
-        st, lane = stash[ti]
-        if id(st) not in mat:
-            mat[id(st)] = [np.asarray(leaf) for leaf in st]
-        rows.append([leaf[lane] for leaf in mat[id(st)]])
-    stats = Stats(*(np.stack([r[j] for r in rows])
-                    for j in range(len(Stats._fields))))
+    with _span("sweep.harvest"):
+        mat: Dict[int, list] = {}
+        rows, passes = [], []
+        for ti in range(n):
+            st, mp, lane = stash[ti]
+            if id(st) not in mat:
+                mat[id(st)] = ([np.asarray(leaf) for leaf in st],
+                               None if mp is None else np.asarray(mp))
+            leaves, mps = mat[id(st)]
+            rows.append([leaf[lane] for leaf in leaves])
+            if mps is not None:
+                passes.append(mps[lane])
+        stats = Stats(*(np.stack([r[j] for r in rows])
+                        for j in range(len(Stats._fields))))
+        mine_passes = (np.stack(passes).astype(np.int64)
+                       if cfg.use_mithril else None)
 
     busy = timers["produce_s"] + timers["consume_s"] + timers["drain_s"]
     pipeline = {
         "produce_s": round(timers["produce_s"], 4),
+        "staging_wait_s": round(timers["staging_wait_s"], 4),
         "consume_s": round(timers["consume_s"], 4),
         "drain_s": round(timers["drain_s"], 4),
         "wall_s": round(wall_s, 4),
@@ -1332,4 +1410,5 @@ def sweep_streaming(cfg: SimConfig,
                          seconds=time.time() - t0)
     return StreamResult(result=result, lane_width=w, chunk=chunk,
                         n_slabs=n_slabs, async_producer=async_producer,
-                        pipeline=pipeline, n_shards=n_shards)
+                        pipeline=pipeline, n_shards=n_shards,
+                        mine_passes=mine_passes)

@@ -182,12 +182,40 @@ class TestAsyncBitIdentity:
         stream = sweep_streaming(CFG, _corpus(7, n=3), lane_width=3,
                                  chunk=48, async_producer=True)
         p = stream.streaming_stats()["pipeline"]
-        for k in ("produce_s", "consume_s", "drain_s", "wall_s",
-                  "producer_stalls", "consumer_stalls", "overlap"):
+        for k in ("produce_s", "staging_wait_s", "consume_s", "drain_s",
+                  "wall_s", "producer_stalls", "consumer_stalls",
+                  "overlap"):
             assert k in p
         assert p["wall_s"] >= 0 and 0.0 <= p["overlap"] <= 1.0
         assert p["producer_stalls"] >= 0 and p["consumer_stalls"] >= 0
         assert stream.streaming_stats()["async_producer"] is True
+
+    def test_produce_time_leaves_out_the_staging_wait(self, monkeypatch):
+        """A slow drain holds the staging buffers, so the producer waits
+        for a free one: that wait is ``staging_wait_s``, and
+        ``produce_s`` stays the producer's own work, well under the
+        wall clock."""
+        import contextlib
+        import importlib
+        sweep_mod = importlib.import_module("repro.cache.sweep")
+        real = sweep_mod._span
+
+        @contextlib.contextmanager
+        def slow_drain(name, *args, **kw):
+            with real(name, *args, **kw):
+                if name == "sweep.drain":
+                    time.sleep(0.05)
+                yield
+
+        monkeypatch.setattr(sweep_mod, "_span", slow_drain)
+        stream = sweep_streaming(CFG, _corpus(3, n=3), lane_width=3,
+                                 chunk=32, ring_depth=1,
+                                 async_producer=True)
+        p = stream.streaming_stats()["pipeline"]
+        assert stream.n_slabs >= 8
+        assert p["drain_s"] >= 0.05 * stream.n_slabs
+        assert p["produce_s"] < 0.25 * p["wall_s"]
+        assert p["staging_wait_s"] > p["produce_s"]
 
     def test_zero_length_tenants_drain_in_async_mode(self):
         corpus = {"empty_a": np.empty((0,), np.int32),

@@ -122,12 +122,17 @@ def test_paged_decode_compiles(one_chip, scale):
 
 def test_sweep_runner_compiles_with_kernels(one_chip, monkeypatch):
     """The chunk runner the sweep builds on a TPU (record kernel in the
-    request step, batched mining kernel at the barrier) compiles."""
+    request step, batched mining kernel at the barrier, AMP beside
+    MITHRIL) compiles, and every part of its step keeps its named scope
+    in the op_names XLA gives the loop body's operations."""
+    import re
+
     from repro.cache import SimConfig
     from repro.kernels import backend
     sweep_mod = importlib.import_module("repro.cache.sweep")
 
-    cfg = SimConfig(capacity=512, use_mithril=True, mithril=SUITE_MITHRIL)
+    cfg = SimConfig(capacity=512, use_mithril=True, use_amp=True,
+                    mithril=SUITE_MITHRIL)
     monkeypatch.setattr(backend, "on_tpu", lambda: True)
     sweep_mod.reset_runners()
     try:
@@ -140,3 +145,7 @@ def test_sweep_runner_compiles_with_kernels(one_chip, monkeypatch):
     finally:
         sweep_mod.reset_runners()
     assert text.count("tpu_custom_call") >= 2
+    body = {c for name in re.findall(r'op_name="([^"]*)"', text)
+            if "/while/body/" in name for c in name.split("/")}
+    for scope in ("access", "record", "barrier", "prefetch", "amp"):
+        assert scope in body, scope
