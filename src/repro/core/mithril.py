@@ -66,9 +66,17 @@ def lookup(cfg: MithrilConfig, state: MithrilState, block: jax.Array) -> jax.Arr
 
     Pure read (pFlag path): never touches state, so it needs no mining
     barrier and may be called at any point of the record/maybe_mine cycle.
+
+    The P slots are read as P scalar gathers, not as one ``(P,)`` row
+    (``pf_vals[b, way]``, same values): a row gather wants the table laid
+    out with P minor-most, while the sweep's scan carries it in the
+    layout the mining branch writes (buckets minor), so the TPU compiler
+    would relayout the whole table into padded ``(PW, P)`` tiles, 64
+    times its bytes at P=2, on every step (PERF.md section 5).
     """
     b, way, found = probe(state.pf_key, block, cfg.pf_buckets)
-    vals = state.pf_vals[b, way]
+    vals = jnp.stack([state.pf_vals[b, way, p]
+                      for p in range(cfg.prefetch_list)])
     return jnp.where(found, vals, jnp.full((cfg.prefetch_list,), EMPTY, jnp.int32))
 
 

@@ -9,7 +9,9 @@ code is kept VERBATIM below as the oracle (the same pattern
 ``core.mining`` uses with ``mine_reference_sequential``); property tests
 drive both over random traces — including the ``min_support == 1``
 immediate-migrate branch and the cache's second-chance eviction — and
-compare every state leaf after every event.
+compare every state leaf after every event. The same file keeps the
+row-gather ``mithril.lookup`` that the per-slot read replaced, and
+checks the two agree.
 """
 
 import functools
@@ -17,6 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 from jax import lax
 
@@ -24,8 +27,8 @@ from repro.cache import base
 from repro.cache.base import CacheState, Evicted
 from repro.cache.pg import PgConfig, PgState, init_pg, pg_access
 from repro.core import MithrilConfig, init, mine, mine_batched
-from repro.core.hashindex import EMPTY, choose_victim, probe
-from repro.core.mithril import add_association, record_event
+from repro.core.hashindex import EMPTY, bucket_of, choose_victim, probe
+from repro.core.mithril import add_association, lookup, record_event
 from repro.core.state import MithrilState
 
 
@@ -461,3 +464,88 @@ def test_mine_batched_matches_serial_mine(seed, need_bits):
         want = mine(cfg, lane) if need[i] else lane
         got_i = jax.tree_util.tree_map(lambda x: x[i], got)
         assert_trees_equal(got_i, want, f"lane {i} (need={need[i]})")
+
+
+# ---------------------------------------------------------------------------
+# Frozen reference: the row-gather lookup (before the per-slot read)
+# ---------------------------------------------------------------------------
+
+def lookup_reference(cfg: MithrilConfig, state: MithrilState,
+                     block: jax.Array) -> jax.Array:
+    b, way, found = probe(state.pf_key, block, cfg.pf_buckets)
+    vals = state.pf_vals[b, way]
+    return jnp.where(found, vals, jnp.full((cfg.prefetch_list,), EMPTY,
+                                           jnp.int32))
+
+
+def _prefetch_lane(cfg, rng):
+    """A lane whose prefetch table holds random sources, each in its own
+    hash bucket, with 1..P associations (EMPTY past them); free rows
+    hold junk a miss must not return. Bucket 0 is full. Returns the
+    state and, per case, a query block for it."""
+    nb, w, p = cfg.pf_buckets, cfg.pf_ways, cfg.prefetch_list
+    universe = np.arange(1, 4000, dtype=np.int32)
+    owner = np.asarray(bucket_of(jnp.asarray(universe), nb))
+    key = np.full((nb, w), -1, np.int32)
+    vals = rng.integers(0, 1 << 20, size=(nb, w, p)).astype(np.int32)
+    n_vals = np.zeros((nb, w), np.int64)
+    for b in range(nb):
+        here = rng.permutation(universe[owner == b])
+        n_keys = w if b == 0 else int(rng.integers(0, w))
+        ways = rng.permutation(w)[:n_keys]
+        key[b, ways] = here[:n_keys]
+        for way in ways:
+            n_vals[b, way] = rng.integers(1, p + 1)
+            vals[b, way, n_vals[b, way]:] = -1
+    occupied = np.argwhere(key != -1)
+    whole = [tuple(bw) for bw in occupied if n_vals[tuple(bw)] == p]
+    holes = [tuple(bw) for bw in occupied if n_vals[tuple(bw)] < p]
+    stored = set(key[key != -1].tolist())
+    absent = [int(k) for k in universe
+              if k not in stored and (key[owner[k - 1]] == -1).any()]
+    outside = [int(k) for k in universe[owner == 0] if k not in stored]
+
+    def pick(xs):
+        return xs[int(rng.integers(len(xs)))]
+
+    queries = {
+        "found": int(key[pick(whole)]),
+        "empty_slots": int(key[pick(holes)]),
+        "absent": pick(absent),
+        "full_bucket": pick([int(k) for k in key[0]] + outside[:w]),
+    }
+    state = init(cfg)._replace(pf_key=jnp.asarray(key),
+                               pf_vals=jnp.asarray(vals))
+    return state, queries
+
+
+@pytest.mark.parametrize("case", ["found", "empty_slots", "absent",
+                                  "full_bucket"])
+@pytest.mark.parametrize("p", [2, 3], ids=["paper_p2", "suite_p3"])
+def test_lookup_matches_row_gather(p, case):
+    """``lookup`` reads each slot with its own gather; it returns what
+    the row gather ``pf_vals[b, way]`` did, masked to EMPTY on a miss,
+    for one lane and under ``vmap`` over 16 lanes (the sweep's form):
+    a source with all P slots, one with EMPTY slots, an absent block,
+    and a query into a full bucket (present or not)."""
+    cfg = small_cfg(pf_buckets=8, pf_ways=4, prefetch_list=p)
+    rng = np.random.default_rng(1000 * p + len(case))
+    lanes = [_prefetch_lane(cfg, rng) for _ in range(16)]
+    states = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
+                                    *[s for s, _ in lanes])
+    blocks = jnp.asarray([q[case] for _, q in lanes], jnp.int32)
+
+    got = jax.jit(jax.vmap(functools.partial(lookup, cfg)))(states, blocks)
+    want = jax.vmap(functools.partial(lookup_reference, cfg))(states, blocks)
+    assert got.dtype == want.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    for i, (stt, q) in enumerate(lanes):
+        one = lookup(cfg, stt, jnp.int32(q[case]))
+        np.testing.assert_array_equal(np.asarray(one), np.asarray(want[i]))
+    hit = np.asarray(want != EMPTY).any(axis=1)
+    if case in ("found", "empty_slots"):
+        assert hit.all()
+    if case == "absent":
+        assert not hit.any()
+    if case == "empty_slots":
+        assert (np.asarray(want) == EMPTY).any(axis=1).all()

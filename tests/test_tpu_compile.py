@@ -7,9 +7,11 @@ the served tier run, to the TPU compiler for a described (not attached)
 v5e. The record kernel and the mining kernels are compiled at the
 benchmark's table sizes (SUITE_MITHRIL) and at the paper's
 (PAPER_MITHRIL), ``hash_lookup`` at both prefetch-table sizes, and
-``paged_decode`` at each ``serving_bench`` tier geometry. The last test
-compiles the sweep's whole chunk runner with its dispatch steered to
-the kernels, as it is on a TPU.
+``paged_decode`` at each ``serving_bench`` tier geometry. The last tests
+compile the sweep's whole chunk runner with its dispatch steered to
+the kernels, as it is on a TPU: once at the SUITE tables for its named
+scopes, and at the benchmark cells' paper-size tables for the layout of
+the prefetch table inside the scan's step.
 
 The topology is described inside a module fixture, never at import:
 one process at a time may load the TPU library, so only the test
@@ -17,7 +19,9 @@ worker that runs this file loads it, and every worker collects the
 same tests. Nothing runs; only the compiler is exercised.
 """
 
+import dataclasses
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -120,32 +124,90 @@ def test_paged_decode_compiles(one_chip, scale):
     assert _kernels(compiled) == 1
 
 
-def test_sweep_runner_compiles_with_kernels(one_chip, monkeypatch):
-    """The chunk runner the sweep builds on a TPU (record kernel in the
-    request step, batched mining kernel at the barrier, AMP beside
-    MITHRIL) compiles, and every part of its step keeps its named scope
-    in the op_names XLA gives the loop body's operations."""
-    import re
-
-    from repro.cache import SimConfig
+def _runner_hlo(one_chip, monkeypatch, cfg, lanes=16, chunk=256) -> str:
+    """Compiled HLO text of the sweep's chunk runner for ``cfg`` on the
+    described chip, with the backend dispatch steered to the kernels."""
     from repro.kernels import backend
     sweep_mod = importlib.import_module("repro.cache.sweep")
 
-    cfg = SimConfig(capacity=512, use_mithril=True, use_amp=True,
-                    mithril=SUITE_MITHRIL)
     monkeypatch.setattr(backend, "on_tpu", lambda: True)
     sweep_mod.reset_runners()
     try:
         init_batched, run_chunk, _ = sweep_mod._runner(cfg, 1, 1)
         carry = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
-                             jax.eval_shape(lambda: init_batched(16)))
-        text = run_chunk.lower(
-            carry, _sds(one_chip, (256, 16)),
-            _sds(one_chip, (256, 16), jnp.bool_)).compile().as_text()
+                             jax.eval_shape(lambda: init_batched(lanes)))
+        return run_chunk.lower(
+            carry, _sds(one_chip, (chunk, lanes)),
+            _sds(one_chip, (chunk, lanes), jnp.bool_)).compile().as_text()
     finally:
         sweep_mod.reset_runners()
+
+
+def _computations(text: str) -> dict:
+    """Each computation of compiled HLO text -> its instruction lines."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY\s+)?%([\w.\-]+)\s*\(.*\{\s*$", line)
+        if name is None and head:
+            name, comps[head[1]] = head[1], []
+        elif name is not None and line.strip() == "}":
+            name = None
+        elif name is not None:
+            comps[name].append(line.strip())
+    return comps
+
+
+def _step_body(text: str) -> list:
+    """The instructions of the scan's while body: the one computation
+    that calls the barrier's ``lax.cond`` and holds MITHRIL's lookup."""
+    def has(lines, pattern):
+        return any(re.search(pattern, ln) for ln in lines)
+
+    bodies = [lines for lines in _computations(text).values()
+              if has(lines, r' conditional\(.*op_name="[^"]*/barrier/cond"')
+              and has(lines, r'op_name="[^"]*/prefetch/[^"]*gather"')]
+    assert len(bodies) == 1, len(bodies)
+    return bodies[0]
+
+
+def test_sweep_runner_compiles_with_kernels(one_chip, monkeypatch):
+    """The chunk runner the sweep builds on a TPU (record kernel in the
+    request step, batched mining kernel at the barrier, AMP beside
+    MITHRIL) compiles, and every part of its step keeps its named scope
+    in the op_names XLA gives the loop body's operations."""
+    from repro.cache import SimConfig
+
+    cfg = SimConfig(capacity=512, use_mithril=True, use_amp=True,
+                    mithril=SUITE_MITHRIL)
+    text = _runner_hlo(one_chip, monkeypatch, cfg)
     assert text.count("tpu_custom_call") >= 2
     body = {c for name in re.findall(r'op_name="([^"]*)"', text)
             if "/while/body/" in name for c in name.split("/")}
     for scope in ("access", "record", "barrier", "prefetch", "amp"):
         assert scope in body, scope
+
+
+@pytest.mark.parametrize("amp", [False, True], ids=["mithril", "mithril_amp"])
+def test_sweep_step_keeps_prefetch_table_layout(one_chip, monkeypatch, amp):
+    """At the benchmark cells' tables (the paper's R, S, Delta, P and
+    table sizes, 1,250 mining rows, a fully associative 512-block cache,
+    16 lanes, chunks of 256), no instruction of the scan's step writes
+    the lanes' prefetch table ``pf_vals`` with its P axis minor-most.
+
+    That layout pads each (ways, P) pair to a whole tile, and a lookup
+    that reads a slot row whole makes the compiler relayout the entire
+    table into it on every step, 64 times its bytes. The mining
+    branches' own computations are not checked: they run only on the
+    steps that mine."""
+    from repro.cache import SimConfig
+
+    mith = dataclasses.replace(PAPER_MITHRIL, mine_rows=1250)
+    cfg = SimConfig(capacity=512, ways=512, use_mithril=True, use_amp=amp,
+                    mithril=mith)
+    table = "s32[16,{},{},{}]".format(mith.pf_buckets, mith.pf_ways,
+                                      mith.prefetch_list)
+    p_minor = re.escape(table) + r"\{3,"
+    body = _step_body(_runner_hlo(one_chip, monkeypatch, cfg))
+    padded = [ln.split(", metadata=")[0][:160] for ln in body
+              if re.search(p_minor, ln.split(", metadata=")[0])]
+    assert not padded, padded
