@@ -5,10 +5,11 @@ program. Each must come out not correct.
 
     python3 bench/control.py --workload <cell> --seeds 1 2 3
 
-For each seed and each control of ``reference.CONTROLS`` it builds the
-seed's jobs as a run does, takes every volume's counts from the control,
-and prints the run's checks against the reference, one JSON line each.
-It needs no accelerator: the controls run on the host.
+For each seed and each control of the cell's driver (its ``CONTROLS``)
+it builds the seed's jobs as a run does, takes every volume's counts
+from the control, and prints the run's checks against the reference,
+one JSON line each. It needs no accelerator: the controls run on the
+host.
 """
 
 from __future__ import annotations
@@ -26,22 +27,15 @@ for _p in (str(ROOT), str(ROOT / "src")):
         sys.path.insert(0, _p)
 
 from bench import run  # noqa: E402
-from bench.lib import generate, reference  # noqa: E402
 
 
 def control_checks(cell: run.Cell, seed: int, control: str) -> dict:
     """The run's checks with every job's counts taken from ``control``."""
-    shape = cell.shape
-    pool = [generate.make_job(cell.traffic, seed, j,
-                              shape["volumes_per_job"],
-                              shape["nominal_length"])
-            for j in range(shape["pool_jobs"])]
-    jobs = []
-    for i, vols in enumerate(pool):
-        counts = run.reference_counts(cell.config, vols, control,
-                                      shape["chunk"])
-        jobs.append(run.Job(i, counts, int(counts[:, 0].sum()), 0, 0, 0))
-    return run.compare(cell.config, pool, jobs)
+    pool = cell.driver.make_pool(cell, seed)
+    jobs = [run.Job(i, run.reference_counts(cell, vols, control), 0, 0, 0,
+                    0, {})
+            for i, vols in enumerate(pool)]
+    return run.compare(cell, pool, jobs)
 
 
 def main(argv=None) -> int:
@@ -51,7 +45,7 @@ def main(argv=None) -> int:
     a = ap.parse_args(argv)
     cell = run.load_cell(ROOT, a.workload)
     for seed in a.seeds:
-        for control in sorted(c for c in reference.CONTROLS if c):
+        for control in sorted(c for c in cell.driver.CONTROLS if c):
             t0 = time.perf_counter()
             checks = control_checks(cell, seed, control)
             print(json.dumps({

@@ -83,7 +83,7 @@ def runner_hlo(cell) -> str:
         import jax
         import numpy as np
 
-        from bench.run import sim_config
+        from bench.drivers.block_sweep import sim_config
 
         sweep = importlib.import_module("repro.cache.sweep")
         lanes, chunk = cell.shape["lanes"], cell.shape["chunk"]

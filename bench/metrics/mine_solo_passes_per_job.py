@@ -1,0 +1,11 @@
+"""One-lane mining passes a job ran, the mean over the window's jobs: the
+streaming engine's per-lane ``mine_passes`` counters as its
+``streaming_stats()["mining"]["solo_passes"]`` sums them. A program that
+keeps no such counter reads nothing."""
+
+
+def read(run):
+    passes = [j.stats.get("mining", {}).get("solo_passes") for j in run.jobs]
+    if not passes or None in passes:
+        return None
+    return sum(passes) / len(passes)

@@ -4,26 +4,29 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 A cell names a configuration (``bench/configs/<config>.json``: the
-simulated cache and its MITHRIL and AMP settings), a traffic mix
+deployment under test, its settings and guarantees), a traffic mix
 (``bench/traffic/<traffic>.json``) and its job shape
-(``bench/cells/<cell>.json``: lanes, volumes per job, nominal volume
-length, chunk, and how many distinct jobs the seed makes).
+(``bench/cells/<cell>.json``). The configuration names a driver
+(``bench/drivers/<driver>.py``, ``block_sweep`` where it names none),
+which owns what is particular to its kind of deployment: the program
+call, the jobs made from ``--seed``, the warm-up, the counts a job hands
+back and the plain reference they are compared with
+(``bench/drivers/__init__.py`` states the contract).
 
-Set-up generates the jobs' volumes on the host from ``--seed``, loads the
-sweep engine's compiled programs from the persistent compilation cache
-(``<checkout>/.jax_cache`` unless ``JAX_COMPILATION_CACHE_DIR`` says
-otherwise) and warms the cell's ``(chunk, lanes)`` runner and the lane
-reset on a small job. The window then runs whole jobs back to back, each
-one ``sweep_streaming`` call over the job's volumes through the cell's
-recycled lanes, until the first job that ends after ``--seconds``; the
-jobs cycle through the seed's distinct jobs. No job may compile.
+Set-up checks that the driver's reference can take the configuration,
+makes the seed's jobs, loads the compiled programs from the persistent
+compilation cache (``<checkout>/.jax_cache`` unless
+``JAX_COMPILATION_CACHE_DIR`` says otherwise) and warms up the cell's
+shapes. The window then runs whole jobs back to back until the first
+job that ends after ``--seconds``; the jobs cycle through the seed's
+distinct jobs. No job may compile.
 
 With ``--trace 0`` the result line carries the cell's end-to-end metrics;
 with ``--trace 1`` the JAX profiler records the window's first
 ``TRACE_S`` seconds and the line carries the per-layer metrics. Each
 metric is computed by ``bench/metrics/<name>.py``. Afterwards every
-job's per-volume counts are compared with the plain reference
-(``bench/lib/reference.py``); ``correct`` holds when no count differs.
+job's per-volume counts are compared with the driver's plain reference;
+``correct`` holds when no count differs.
 
 The run needs a TPU with as many chips as the cell asks for; without one
 it exits with code 3 and prints no result.
@@ -43,6 +46,7 @@ import shutil               # noqa: E402
 import sys                  # noqa: E402
 import tempfile             # noqa: E402
 from pathlib import Path    # noqa: E402
+from types import ModuleType  # noqa: E402
 from typing import Dict, List, Optional  # noqa: E402
 
 BENCH = Path(__file__).resolve().parent
@@ -53,16 +57,14 @@ for _p in (str(ROOT), str(ROOT / "src")):
 
 import numpy as np          # noqa: E402
 
-from bench.lib import generate, reference  # noqa: E402
+from bench import drivers   # noqa: E402
+# tests/test_sweep_tracing.py imports the sweep's configuration builder
+# from here
+from bench.drivers.block_sweep import sim_config  # noqa: E402,F401
 
 WINDOW_SPAN = "bench_window"        # host annotation around the traced span
 TRACE_S = 1.0                       # seconds of the window a trace records
 REFERENCE_WORKERS = 8               # host processes for the reference
-# Slabs the streaming engine stages and dispatches ahead of the device:
-# at ~0.32 s of device work a slab in both cells, some 8 s, so that a
-# host that stands still for a few seconds leaves the chip fed. Each
-# slab in flight holds its own output carry on the device (~97 MB).
-RING_DEPTH = 24
 
 
 class NoChip(RuntimeError):
@@ -76,6 +78,7 @@ class Cell:
     name: str
     chips: int
     config: dict            # bench/configs/<config>.json
+    driver: ModuleType      # bench/drivers/<config's driver>.py
     traffic: dict           # bench/traffic/<traffic>.json
     shape: dict             # bench/cells/<name>.json
     end_to_end: List[dict]  # the cell's end-to-end metric entries
@@ -84,14 +87,15 @@ class Cell:
 
 @dataclasses.dataclass
 class Job:
-    """One timed ``sweep_streaming`` call and what it handed back."""
+    """One timed job and what the driver's ``run_job`` handed back."""
 
     pool_index: int
-    counts: np.ndarray      # (volumes, 14) per-volume counts
+    counts: np.ndarray      # (volumes, k) per-volume counts
     requests: int
     n_slabs: int
     lane_steps: int
     ideal_lane_steps: int
+    stats: dict             # the program's own counters and spans
 
 
 @dataclasses.dataclass
@@ -119,31 +123,15 @@ def load_cell(root: Path, name: str) -> Cell:
     w = cells[name]
     configs = {c["name"]: c for c in spec["configs"]}
     bench = root / "bench"
+    config = json.loads((root / configs[w["config"]]["file"]).read_text())
     return Cell(
-        name=name, chips=int(w["chips"]),
-        config=json.loads((root / configs[w["config"]]["file"]).read_text()),
+        name=name, chips=int(w["chips"]), config=config,
+        driver=drivers.load(root, config),
         traffic=json.loads(
             (bench / "traffic" / f"{w['traffic']}.json").read_text()),
         shape=json.loads((bench / "cells" / f"{name}.json").read_text()),
         end_to_end=[m for m in spec["end_to_end"] if _applies(m, name)],
         per_layer=[m for m in spec["per_layer"] if _applies(m, name)])
-
-
-def sim_config(config: dict):
-    """The program's ``SimConfig`` for a configuration file, whose keys
-    are the fields of ``SimConfig``, ``MithrilConfig`` and ``AmpConfig``."""
-    from repro.cache import SimConfig
-    from repro.cache.amp import AmpConfig
-    from repro.core import MithrilConfig
-
-    def pick(cls):
-        return {f.name: config[f.name] for f in dataclasses.fields(cls)
-                if f.name in config}
-
-    return SimConfig(**{k: v for k, v in pick(SimConfig).items()
-                        if k not in ("mithril", "amp", "pg", "learned")},
-                     mithril=MithrilConfig(**pick(MithrilConfig)),
-                     amp=AmpConfig(**pick(AmpConfig)))
 
 
 def read_metric(root: Path, entry: dict, run: Run) -> Optional[float]:
@@ -170,36 +158,9 @@ def check_chips(chips: int):
     return devices[:chips]
 
 
-def counts_of(stats) -> np.ndarray:
-    """(volumes, 14): requests, hits, then issued, used and evicted
-    unused per prefetch source."""
-    return np.concatenate(
-        [np.asarray(stats.requests)[:, None], np.asarray(stats.hits)[:, None],
-         np.asarray(stats.pf_issued), np.asarray(stats.pf_used),
-         np.asarray(stats.pf_evicted_unused)], axis=1).astype(np.int64)
-
-
-def run_job(cfg, volumes, shape: dict):
-    from repro.cache.sweep import sweep_streaming
-
-    return sweep_streaming(cfg, [v.blocks for v in volumes],
-                           lane_width=shape["lanes"], chunk=shape["chunk"],
-                           ring_depth=RING_DEPTH)
-
-
-def warm_up(cfg, shape: dict) -> None:
-    """Run the cell's ``(chunk, lanes)`` runner and the lane reset once:
-    ``lanes + 1`` one-slab volumes, so one lane is recycled."""
-    chunk, lanes = shape["chunk"], shape["lanes"]
-    blocks = np.arange(chunk, dtype=np.int32)
-    out = run_job(cfg, [generate.Volume("warm", {}, blocks + i * chunk)
-                        for i in range(lanes + 1)], shape)
-    counts_of(out.result.stats)
-
-
-def timed_jobs(cfg, pool, shape: dict, seconds: float):
-    """Whole jobs back to back until the first that ends after
-    ``seconds``; returns (jobs, wall seconds)."""
+def timed_jobs(driver, program, pool, shape: dict, seconds: float):
+    """Whole jobs of ``driver.run_job`` back to back until the first that
+    ends after ``seconds``; returns (jobs, wall seconds)."""
     from jax.profiler import TraceAnnotation
 
     jobs: List[Job] = []
@@ -208,21 +169,19 @@ def timed_jobs(cfg, pool, shape: dict, seconds: float):
     while True:
         i = k % len(pool)
         with TraceAnnotation("job"):
-            out = run_job(cfg, pool[i], shape)
-        with TraceAnnotation("readback"):
-            counts = counts_of(out.result.stats)
-        if out.result.compiles:
-            raise RuntimeError(f"job {k} compiled {out.result.compiles} "
+            out = driver.run_job(program, pool[i], shape)
+        compiles = out.pop("compiles")
+        if compiles:
+            raise RuntimeError(f"job {k} compiled {compiles} "
                                "programs inside the window")
-        st = out.streaming_stats()
-        jobs.append(Job(i, counts, int(counts[:, 0].sum()), out.n_slabs,
-                        st["lane_steps"], st["ideal_lane_steps"]))
+        jobs.append(Job(i, **out))
         k += 1
         if time.perf_counter() - t0 >= seconds:
             return jobs, time.perf_counter() - t0
 
 
-def traced_jobs(cfg, pool, shape: dict, seconds: float, trace_dir: str):
+def traced_jobs(driver, program, pool, shape: dict, seconds: float,
+                trace_dir: str):
     """:func:`timed_jobs` with the profiler recording the window's first
     ``TRACE_S`` seconds (or the whole window, where it is shorter) under
     the host span ``WINDOW_SPAN``. A span and not the whole window: the
@@ -254,7 +213,7 @@ def traced_jobs(cfg, pool, shape: dict, seconds: float, trace_dir: str):
     stopper = threading.Thread(target=span, name="bench-trace")
     stopper.start()
     try:
-        out = timed_jobs(cfg, pool, shape, seconds)
+        out = timed_jobs(driver, program, pool, shape, seconds)
     finally:
         window_done.set()
         stopper.join()
@@ -263,28 +222,28 @@ def traced_jobs(cfg, pool, shape: dict, seconds: float, trace_dir: str):
     return out
 
 
-def reference_counts(config: dict, volumes, control: str = "",
-                     slab: int = 256) -> np.ndarray:
-    """(volumes, 14) reference counts (of ``control`` when given, see
-    ``reference.CONTROLS``), one volume per task on a pool of host
-    processes that import nothing of JAX (spawned, never forked from a
-    process that holds the chip)."""
+def reference_counts(cell: Cell, volumes, control: str = "") -> np.ndarray:
+    """(volumes, k) counts of the driver's plain reference (of
+    ``control`` when given, a key of the driver's ``CONTROLS``), one
+    volume per task on a pool of host processes that import nothing of
+    JAX (spawned, never forked from a process that holds the chip)."""
     import multiprocessing
     from concurrent.futures import ProcessPoolExecutor
 
-    workers = max(1, min(REFERENCE_WORKERS, len(volumes)))
+    n = len(volumes)
+    workers = max(1, min(REFERENCE_WORKERS, n))
     with ProcessPoolExecutor(
             workers, mp_context=multiprocessing.get_context("spawn")) as ex:
-        n = len(volumes)
-        rows = ex.map(reference.simulate_flat, [config] * n,
-                      [v.blocks for v in volumes], [control] * n, [slab] * n)
+        rows = ex.map(drivers.reference_row, [cell.driver.__file__] * n,
+                      [cell.config] * n, [cell.shape] * n, volumes,
+                      [control] * n)
         return np.asarray(list(rows), np.int64)
 
 
-def compare(config: dict, pool, jobs: List[Job]):
+def compare(cell: Cell, pool, jobs: List[Job]):
     """The checks: per-volume counts of every job against the reference."""
     due = sorted({j.pool_index for j in jobs})
-    flat = reference_counts(config, [v for i in due for v in pool[i]])
+    flat = reference_counts(cell, [v for i in due for v in pool[i]])
     want: Dict[int, np.ndarray] = {}
     for k, i in enumerate(due):
         want[i] = flat[k * len(pool[i]): (k + 1) * len(pool[i])]
@@ -308,23 +267,22 @@ def measure(root: Path, cell: Cell, seed: int, seconds: float, trace: bool,
 
     enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    shape = cell.shape
-    cfg = sim_config(cell.config)
+    driver, shape = cell.driver, cell.shape
+    driver.check(cell.config)
+    program = driver.program(cell.config)
     with TraceAnnotation("generate"):
-        pool = [generate.make_job(cell.traffic, seed, j,
-                                  shape["volumes_per_job"],
-                                  shape["nominal_length"])
-                for j in range(shape["pool_jobs"])]
-    warm_up(cfg, shape)
+        pool = driver.make_pool(cell, seed)
+    driver.warm_up(program, shape)
     setup_s = time.perf_counter() - t_start
 
     summary = None
     if not trace:
-        jobs, window_s = timed_jobs(cfg, pool, shape, seconds)
+        jobs, window_s = timed_jobs(driver, program, pool, shape, seconds)
     else:
         tmp = tempfile.mkdtemp(prefix="bench_trace_")
         try:
-            jobs, window_s = traced_jobs(cfg, pool, shape, seconds, tmp)
+            jobs, window_s = traced_jobs(driver, program, pool, shape,
+                                         seconds, tmp)
             summary = trace_lib.reduce(trace_lib.find_xplane(tmp),
                                        WINDOW_SPAN, len(devices),
                                        skip_names=("job", "readback"))
@@ -340,7 +298,7 @@ def measure(root: Path, cell: Cell, seed: int, seconds: float, trace: bool,
         value = read_metric(root, e, run)
         if value is not None:
             metrics[e["name"]] = {"value": value, "unit": e["unit"]}
-    checks = compare(cell.config, pool, jobs)
+    checks = compare(cell, pool, jobs)
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind, "count": len(devices),
               "memory_peak_bytes": int(peak)}

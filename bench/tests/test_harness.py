@@ -1,7 +1,9 @@
 """The harness end to end on the CPU at a tiny size: adding a cell, a
-configuration, a traffic mix or a metric is adding files; a run whose
-timed path is broken underneath comes out not correct; and a run without
-a TPU, or outside a checkout of the program, prints no result."""
+configuration, a traffic mix or a metric is adding files, and so is a
+deployment with new semantics, which brings its own driver
+(``test_drivers.py``); a run whose timed path is broken underneath comes
+out not correct; and a run without a TPU, or outside a checkout of the
+program, prints no result."""
 
 import json
 import os
@@ -57,11 +59,12 @@ def test_new_cell_and_metric_are_files(tmp_path, cpu_devices):
 def test_per_layer_reader_reads_the_run(tmp_path, cpu_devices):
     root = write_root(tmp_path, BENCH / "metrics")
     cell = run.load_cell(root, "t-cell")
-    cfg = run.sim_config(cell.config)
+    cfg = cell.driver.program(cell.config)
     from bench.lib import generate
     pool = [generate.make_job(cell.traffic, 1, 0, 6, 384)]
-    run.warm_up(cfg, cell.shape)
-    jobs, window_s = run.timed_jobs(cfg, pool, cell.shape, 0.0)
+    cell.driver.warm_up(cfg, cell.shape)
+    jobs, window_s = run.timed_jobs(cell.driver, cfg, pool, cell.shape,
+                                    0.0)
     r = run.Run(cell, jobs, window_s, 1.0, "cpu")
     waste = run.read_metric(root, cell.per_layer[0], r)
     steps = jobs[0].lane_steps
@@ -149,12 +152,12 @@ def test_traced_window_records_one_span(tmp_path, cpu_devices, monkeypatch):
 
     root = write_root(tmp_path / "root", BENCH / "metrics")
     cell = run.load_cell(root, "t-cell")
-    cfg = run.sim_config(cell.config)
+    cfg = cell.driver.program(cell.config)
     pool = [generate.make_job(cell.traffic, 5, 0, 6, 384)]
-    run.warm_up(cfg, cell.shape)
+    cell.driver.warm_up(cfg, cell.shape)
     monkeypatch.setattr(run, "TRACE_S", 60.0)
-    jobs, window_s = run.traced_jobs(cfg, pool, cell.shape, 0.0,
-                                     str(tmp_path / "tr"))
+    jobs, window_s = run.traced_jobs(cell.driver, cfg, pool, cell.shape,
+                                     0.0, str(tmp_path / "tr"))
     assert len(jobs) == 1
     data = ProfileData.from_file(trace.find_xplane(str(tmp_path / "tr")))
     spans = [e for p in data.planes if p.name == trace.HOST_PLANE

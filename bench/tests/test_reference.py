@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from bench.lib import generate, reference
-from bench.run import sim_config
+from bench.drivers.block_sweep import sim_config
 
 from tiny import TINY_CONFIG, TRAFFIC
 

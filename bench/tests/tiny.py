@@ -2,6 +2,9 @@
 tables, so that mining fires many times in a few hundred requests."""
 
 import json
+from pathlib import Path
+
+DRIVERS = Path(__file__).resolve().parents[1] / "drivers"
 
 TINY_CONFIG = {
     "name": "tiny", "source": "test", "reduced": [], "assumed": {},
@@ -29,14 +32,17 @@ TRAFFIC = {"volumes": [
      "params": {"alpha": 1.2, "catalog": 4096}}]}
 
 
-def write_root(root, metrics_src, extra_metrics=(), per_layer_extra=()):
+def write_root(root, metrics_src, extra_metrics=(), per_layer_extra=(),
+               config=TINY_CONFIG):
     """A checkout-like tree under ``root`` with one tiny cell ``t-cell``:
     BENCHMARK.json plus the files it names. The metric readers are copied
-    from ``metrics_src``."""
+    from ``metrics_src``, the drivers from the benchmark's own."""
     bench = root / "bench"
-    for d in ("configs", "traffic", "cells", "metrics"):
+    for d in ("configs", "traffic", "cells", "metrics", "drivers"):
         (bench / d).mkdir(parents=True, exist_ok=True)
-    (bench / "configs" / "tiny.json").write_text(json.dumps(TINY_CONFIG))
+    (bench / "configs" / "tiny.json").write_text(json.dumps(config))
+    for p in DRIVERS.glob("*.py"):
+        (bench / "drivers" / p.name).write_text(p.read_text())
     (bench / "traffic" / "mix.json").write_text(json.dumps(TRAFFIC))
     (bench / "cells" / "t-cell.json").write_text(json.dumps(TINY_SHAPE))
     for p in metrics_src.glob("*.py"):
