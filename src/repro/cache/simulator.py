@@ -7,12 +7,18 @@ step per configuration; statistics match the paper's metrics:
 
   hit ratio            = hits / requests
   prefetch precision   = used prefetches / issued prefetches (per source)
+
+A write-back cache (``SimConfig.write_back``, DESIGN.md §14) takes a
+per-request write flag beside each block (``writes=``) and counts
+writes, write hits, write-backs and the dirty blocks resident: a
+running count, +1 when a write turns a clean block dirty and -1 at each
+write-back, so its value at the end of a volume is the volume's flush.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -37,6 +43,7 @@ class SimConfig:
     use_amp: bool = False
     use_pg: bool = False
     use_learned: bool = False     # learned admission/eviction (DESIGN.md §12)
+    write_back: bool = False      # dirty state and write-back counts (§14)
     mithril: MithrilConfig = dataclasses.field(default_factory=MithrilConfig)
     amp: AmpConfig = dataclasses.field(default_factory=AmpConfig)
     pg: PgConfig = dataclasses.field(default_factory=PgConfig)
@@ -53,7 +60,8 @@ class SimConfig:
         parts = [n for n, u in [("learned", self.use_learned),
                                 ("mithril", self.use_mithril),
                                 ("amp", self.use_amp),
-                                ("pg", self.use_pg)] if u]
+                                ("pg", self.use_pg),
+                                ("wb", self.write_back)] if u]
         return "-".join(parts + [self.policy])
 
 
@@ -63,12 +71,33 @@ class Stats(NamedTuple):
     pf_issued: jax.Array          # (N_PF_SRC,)
     pf_used: jax.Array            # (N_PF_SRC,)
     pf_evicted_unused: jax.Array  # (N_PF_SRC,)
+    # write-back caches only; None (no leaf) in a read-only one
+    writes: Optional[jax.Array] = None          # ()
+    write_hits: Optional[jax.Array] = None      # ()
+    writebacks: Optional[jax.Array] = None      # () dirty evictions
+    dirty_resident: Optional[jax.Array] = None  # () the flush still owed
 
 
-def init_stats() -> Stats:
+WRITE_BACK_FIELDS = ("writes", "write_hits", "writebacks", "dirty_resident")
+
+
+def init_stats(write_back: bool = False) -> Stats:
     z = jnp.zeros((), jnp.int32)
     zv = jnp.zeros((N_PF_SRC,), jnp.int32)
-    return Stats(z, z, zv, zv.copy(), zv.copy())
+    stats = Stats(z, z, zv, zv.copy(), zv.copy())
+    if write_back:
+        stats = stats._replace(**dict.fromkeys(WRITE_BACK_FIELDS, z))
+    return stats
+
+
+def _count_writebacks(stats: Stats, ev) -> Stats:
+    """Count the write-back a dirty victim owes (write-back caches)."""
+    if ev.dirty is None:
+        return stats
+    with jax.named_scope("writeback"):
+        d = ev.dirty.astype(jnp.int32)
+        return stats._replace(writebacks=stats.writebacks + d,
+                              dirty_resident=stats.dirty_resident - d)
 
 
 class SimResult(NamedTuple):
@@ -94,6 +123,7 @@ def _apply_prefetches(cfg, cache, stats, cands, src, enable, scorer=None):
             pf_issued=stats.pf_issued.at[src].add(issued.astype(jnp.int32)),
             pf_evicted_unused=stats.pf_evicted_unused.at[ev.pf_src].add(
                 ev.unused_pf.astype(jnp.int32)))
+        stats = _count_writebacks(stats, ev)
         ev_blocks.append(ev.block)
         ev_unused.append(ev.unused_pf)
         ev_srcs.append(ev.pf_src)
@@ -107,7 +137,8 @@ def build_segments(cfg: SimConfig):
     Returns ``(init_carry, segments)`` where ``segments`` is a list of
     ``(fn, mine_after)`` pairs and each ``fn(carry, block, aux)`` returns
     ``(carry, aux)``. ``aux`` threads per-request values (``valid``,
-    ``hit``, ``used_src``, the demand eviction) between segments.
+    ``hit``, ``used_src``, the demand eviction, and in a write-back
+    configuration the request's ``write`` flag) between segments.
     ``mine_after=True`` marks a point where a MITHRIL recording event may
     have filled the mining table, so the mining trigger —
     ``mithril.maybe_mine`` per lane in the serial ``build_step``, the
@@ -132,8 +163,9 @@ def build_segments(cfg: SimConfig):
 
     def init_carry():
         carry = {
-            "cache": base.init_cache(cfg.capacity, cfg.ways),
-            "stats": init_stats(),
+            "cache": base.init_cache(cfg.capacity, cfg.ways,
+                                     write_back=cfg.write_back),
+            "stats": init_stats(cfg.write_back),
         }
         if cfg.use_mithril:
             carry["mith"] = mithril.init(cfg.mithril)
@@ -153,15 +185,26 @@ def build_segments(cfg: SimConfig):
         # (a pure pf-table read, so no mining-barrier interaction)
         hint = (mithril.assoc_count(cfg.mithril, carry["mith"], block)
                 if cfg.use_learned and cfg.use_mithril else None)
-        cache, hit, used_src, ev = base.access(cache, block, cfg.policy,
-                                               enabled=valid, scorer=scorer,
-                                               assoc_hint=hint)
+        wb = ({"write": aux["write"]} if cfg.write_back else {})
+        cache, hit, used_src, ev, dirtied = base.access(
+            cache, block, cfg.policy, enabled=valid, scorer=scorer,
+            assoc_hint=hint, **wb)
         stats = stats._replace(
             hits=stats.hits + hit.astype(jnp.int32),
             pf_used=stats.pf_used.at[used_src].add(
                 (used_src != PF_NONE).astype(jnp.int32)),
             pf_evicted_unused=stats.pf_evicted_unused.at[ev.pf_src].add(
                 ev.unused_pf.astype(jnp.int32)))
+        if cfg.write_back:
+            with jax.named_scope("writeback"):
+                write = aux["write"] & valid
+                stats = stats._replace(
+                    writes=stats.writes + write.astype(jnp.int32),
+                    write_hits=stats.write_hits
+                    + (write & hit).astype(jnp.int32),
+                    dirty_resident=stats.dirty_resident
+                    + dirtied.astype(jnp.int32))
+            stats = _count_writebacks(stats, ev)
         out = dict(carry)
         out["cache"], out["stats"] = cache, stats
         return out, {**aux, "hit": hit, "used_src": used_src, "ev": ev}
@@ -257,12 +300,16 @@ def build_step(cfg: SimConfig):
 
     Serial composition of ``build_segments`` with the per-lane
     ``mithril.maybe_mine`` trigger at every mining barrier — the
-    record/maybe_mine contract in its one-lane form.
+    record/maybe_mine contract in its one-lane form. A write-back
+    configuration scans ``(block, write)`` pairs.
     """
     init_carry, segments = build_segments(cfg)
 
-    def step(carry, block):
+    def step(carry, xs):
+        block, write = xs if cfg.write_back else (xs, None)
         aux = {"valid": jnp.array(True)}
+        if cfg.write_back:
+            aux["write"] = write
         for fn, mine_after in segments:
             carry, aux = fn(carry, block, aux)
             if mine_after:
@@ -274,17 +321,38 @@ def build_step(cfg: SimConfig):
     return init_carry, step
 
 
+def write_flags(cfg: SimConfig, writes, n: int) -> np.ndarray:
+    """``writes`` as ``n`` request write flags: all reads where ``None``.
+    Only a write-back configuration takes write flags."""
+    if writes is None:
+        return np.zeros((n,), bool)
+    if not cfg.write_back:
+        raise ValueError("writes= needs a write-back configuration "
+                         "(SimConfig(write_back=True))")
+    writes = np.asarray(writes)
+    if writes.shape != (n,):
+        raise ValueError(f"writes must have shape ({n},), "
+                         f"got {writes.shape}")
+    return writes.astype(bool)
+
+
 def simulate(cfg: SimConfig, trace: np.ndarray,
-             unroll: int = 1) -> SimResult:
-    """Run ``trace`` (1-D int array of block ids) through the configuration."""
+             unroll: int = 1, writes=None) -> SimResult:
+    """Run ``trace`` (1-D int array of block ids) through the configuration;
+    ``writes`` gives a write-back configuration each request's write flag
+    (default all reads)."""
     init_carry, step = build_step(cfg)
+    flags = write_flags(cfg, writes, len(trace))
 
     @jax.jit
-    def run(tr):
-        carry, hits = lax.scan(step, init_carry(), tr, unroll=unroll)
+    def run(xs):
+        carry, hits = lax.scan(step, init_carry(), xs, unroll=unroll)
         return carry["stats"], hits
 
-    stats, hits = run(jnp.asarray(trace, jnp.int32))
+    xs = jnp.asarray(trace, jnp.int32)
+    if cfg.write_back:
+        xs = (xs, jnp.asarray(flags))
+    stats, hits = run(xs)
     return SimResult(jax.device_get(stats), np.asarray(hits))
 
 
@@ -307,7 +375,8 @@ class SimSession:
     curve are bit-identical to ``simulate`` on the concatenated feed
     regardless of how the feed was sliced — the chunk boundary is
     invisible under the §6 masking contract
-    (``tests/test_streaming.py`` pins this).
+    (``tests/test_streaming.py`` pins this). A write-back configuration
+    takes each fed request's write flag (``feed(blocks, writes=)``).
     """
 
     def __init__(self, cfg: SimConfig, chunk: int = 256, unroll: int = 1):
@@ -315,9 +384,11 @@ class SimSession:
         if chunk < 1:
             raise ValueError(f"chunk must be >= 1, got {chunk}")
         init_batched, self._run, place = _runner(cfg, unroll, 1)
+        self._cfg = cfg
         self._carry = place(init_batched(1))
         self._chunk = int(chunk)
         self._pending = np.empty((0,), np.int32)
+        self._pending_w = np.empty((0,), bool)
         self._hits: list = []
         self._fed = 0
         self._done = False
@@ -326,23 +397,30 @@ class SimSession:
     def requests_fed(self) -> int:
         return self._fed
 
-    def _run_chunk(self, blk: np.ndarray, valid: np.ndarray) -> None:
-        self._carry, hits = self._run(self._carry,
-                                      jnp.asarray(blk[:, None]),
-                                      jnp.asarray(valid[:, None]))
+    def _run_chunk(self, blk: np.ndarray, valid: np.ndarray,
+                   wr: np.ndarray) -> None:
+        slabs = [jnp.asarray(blk[:, None]), jnp.asarray(valid[:, None])]
+        if self._cfg.write_back:
+            slabs.append(jnp.asarray(wr[:, None]))
+        self._carry, hits = self._run(self._carry, *slabs)
         self._hits.append(hits)
 
-    def feed(self, blocks) -> None:
+    def feed(self, blocks, writes=None) -> None:
         """Append arrived requests; full chunks run immediately."""
         if self._done:
             raise RuntimeError("session already finished")
         blocks = np.atleast_1d(np.asarray(blocks, np.int32))
+        writes = write_flags(self._cfg, None if writes is None
+                             else np.atleast_1d(writes), len(blocks))
         self._fed += len(blocks)
         self._pending = np.concatenate([self._pending, blocks])
+        self._pending_w = np.concatenate([self._pending_w, writes])
         while len(self._pending) >= self._chunk:
             blk = self._pending[: self._chunk]
+            wr = self._pending_w[: self._chunk]
             self._pending = self._pending[self._chunk:]
-            self._run_chunk(blk, np.ones((self._chunk,), bool))
+            self._pending_w = self._pending_w[self._chunk:]
+            self._run_chunk(blk, np.ones((self._chunk,), bool), wr)
 
     def finish(self) -> SimResult:
         """Flush the padded remainder and return the SimResult."""
@@ -352,10 +430,12 @@ class SimSession:
         if len(self._pending):
             blk = np.zeros((self._chunk,), np.int32)
             blk[: len(self._pending)] = self._pending
+            wr = np.zeros((self._chunk,), bool)
+            wr[: len(self._pending)] = self._pending_w
             valid = np.arange(self._chunk) < len(self._pending)
-            self._run_chunk(blk, valid)
-        stats = Stats(*(np.asarray(leaf)[0]
-                        for leaf in self._carry["stats"]))
+            self._run_chunk(blk, valid, wr)
+        stats = jax.tree.map(lambda leaf: np.asarray(leaf)[0],
+                             self._carry["stats"])
         hits = (np.concatenate([np.asarray(h)[:, 0] for h in self._hits])
                 if self._hits else np.zeros((0,), bool))
         return SimResult(stats, hits[: self._fed])

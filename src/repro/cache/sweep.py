@@ -62,7 +62,8 @@ from jax import lax
 from jax.profiler import TraceAnnotation
 
 from repro.core import mithril
-from .simulator import SimConfig, SimResult, Stats, build_segments
+from .simulator import (SimConfig, SimResult, Stats, build_segments,
+                        write_flags)
 
 DEFAULT_CHUNK = 4096
 DEFAULT_LANE_WIDTH = 16     # lanes per scheduled group (rounded to devices)
@@ -140,7 +141,8 @@ MINE_PASS_KEYS = ("solo_passes", "fused_lanes", "fused_passes")
 def build_batched_step(cfg: SimConfig):
     """Returns (init_batched, step) for a scan over (chunk, B) request slabs.
 
-    ``step(carry, (blocks, valid))`` advances every trace lane by one
+    ``step(carry, (blocks, valid))`` (in a write-back configuration
+    ``(blocks, valid, writes)``) advances every trace lane by one
     request: the branchless scatter-form segments run under ``vmap``,
     each mining barrier runs one batch-level ``lax.cond`` around the
     fused ``mithril.mine_batched``, and invalid (padded) lanes keep
@@ -148,8 +150,10 @@ def build_batched_step(cfg: SimConfig):
 
     Each part of the step runs under a ``jax.named_scope`` — ``access``,
     ``record``, ``barrier``, ``prefetch`` (MITHRIL's lookup and inserts),
-    ``amp``, ``pg``, from the segment's name — so XLA labels every
-    operation, and a device trace splits the step by part. With MITHRIL
+    ``amp``, ``pg``, from the segment's name, with a write-back cache's
+    dirty bookkeeping under ``writeback`` inside ``access`` and the
+    prefetch inserts — so XLA labels every operation, and a device trace
+    splits the step by part. With MITHRIL
     on, the carry holds per-lane ``mine_passes`` counters
     (``MINE_PASS_KEYS``) that the barrier bumps.
     """
@@ -199,11 +203,13 @@ def build_batched_step(cfg: SimConfig):
                 + passes.astype(jnp.int32)}
 
     def step(carry, xs):
-        block, valid = xs
+        block, valid, *write = xs
         # padded tails: aux["valid"] gates every state write at source
         # (scatter-form no-ops), so ended lanes keep their carry with no
         # carry-wide select — the old whole-table copy per step
         new, aux = carry, {"valid": valid}
+        if cfg.write_back:
+            aux["write"] = write[0]
         for fn, mine_after in segments:
             gate = getattr(fn, "record_gate", None)
             if gate is not None:
@@ -270,8 +276,14 @@ def _runner(cfg: SimConfig, unroll: int, n_shards: int = 1):
     """
     init_batched, step = build_batched_step(cfg)
 
-    def scan_chunk(carry, blocks, valid):
-        return lax.scan(step, carry, (blocks, valid), unroll=unroll)
+    if cfg.write_back:
+        # the write column is a third slab, staged only where it is read
+        def scan_chunk(carry, blocks, valid, writes):
+            return lax.scan(step, carry, (blocks, valid, writes),
+                            unroll=unroll)
+    else:
+        def scan_chunk(carry, blocks, valid):
+            return lax.scan(step, carry, (blocks, valid), unroll=unroll)
 
     if n_shards <= 1:
         return init_batched, jax.jit(scan_chunk), lambda carry: carry
@@ -304,20 +316,25 @@ def _runner(cfg: SimConfig, unroll: int, n_shards: int = 1):
         return jax.device_put(carry, dist_sharding.to_named(specs, mesh))
 
     @jax.jit
-    def run_chunk(carry, blocks, valid):
+    def run_chunk(carry, *slabs):
         cspec = dist_sharding.lane_specs(carry, mesh, axis=LANE_AXIS)
         # (chunk, W) slabs are lane-LAST (ring_specs): the time axis
         # stays whole on every device, the lane axis splits — the same
         # layout the streaming ring buffer stages, so recycled lanes
         # keep their shard across admissions
-        bspec, vspec = dist_sharding.ring_specs((blocks, valid), mesh,
-                                                axis=LANE_AXIS)
+        sspecs = dist_sharding.ring_specs(slabs, mesh, axis=LANE_AXIS)
         return jax.shard_map(scan_chunk, mesh=mesh,
-                             in_specs=(cspec, bspec, vspec),
-                             out_specs=(cspec, bspec),
-                             check_vma=False)(carry, blocks, valid)
+                             in_specs=(cspec, *sspecs),
+                             out_specs=(cspec, sspecs[0]),
+                             check_vma=False)(carry, *slabs)
 
     return init_batched, run_chunk, place
+
+
+def _refuse_write_back(cfg: SimConfig, entry: str) -> None:
+    if cfg.write_back:
+        raise ValueError(f"{entry} runs read-only caches; a write-back "
+                         "configuration runs through sweep_streaming")
 
 
 def compile_count(cfg: SimConfig, unroll: int = 1, n_shards: int = 1) -> int:
@@ -347,7 +364,7 @@ class SweepResult(NamedTuple):
 
     def result(self, i: int) -> SimResult:
         """Per-trace view, same type the serial ``simulate`` returns."""
-        stats = Stats(*(np.asarray(leaf)[i] for leaf in self.stats))
+        stats = jax.tree.map(lambda leaf: np.asarray(leaf)[i], self.stats)
         return SimResult(stats, self.hit_curve[i, : int(self.lengths[i])])
 
     def hit_ratios(self) -> np.ndarray:
@@ -389,8 +406,10 @@ def sweep(cfg: SimConfig, blocks: np.ndarray,
     ``shard`` selects the device layout: ``None``/``True`` shard the
     lane axis over all local devices whenever the batch width divides
     (per-lane results stay bit-identical — lanes are independent);
-    ``False`` forces the single-device runner.
+    ``False`` forces the single-device runner. A write-back
+    configuration is refused: it runs through :func:`sweep_streaming`.
     """
+    _refuse_write_back(cfg, "sweep")
     t0 = time.time()
     blocks = np.ascontiguousarray(np.asarray(blocks, np.int32))
     if blocks.ndim != 2:
@@ -688,8 +707,9 @@ def sweep_scheduled(cfg: SimConfig,
     their ``(chunk, width)`` slab geometry from the packer's bounded
     shape set. Groups holding fewer traces than their lane width are
     padded with empty (length-0) lanes, which are bit-exact no-ops under
-    the §6 masking contract.
+    the §6 masking contract. A write-back configuration is refused.
     """
+    _refuse_write_back(cfg, "sweep_scheduled")
     t0 = time.time()
     if not isinstance(traces, np.ndarray):
         # suite-like inputs carry their own lengths; a conflicting
@@ -728,17 +748,19 @@ def sweep_scheduled(cfg: SimConfig,
         res = sweep(cfg, gb, gl, chunk=g.chunk, unroll=unroll,
                     shard=shard)
         compiles += res.compiles
+        leaves, treedef = jax.tree.flatten(res.stats)
         if stats_out is None:
             stats_out = [np.zeros((n,) + np.asarray(leaf).shape[1:],
                                   np.asarray(leaf).dtype)
-                         for leaf in res.stats]
+                         for leaf in leaves]
         for j, idx in enumerate(g.indices):
             ln = int(lengths[idx])
             hit[idx, :ln] = res.hit_curve[j, :ln]
-            for leaf_out, leaf in zip(stats_out, res.stats):
+            for leaf_out, leaf in zip(stats_out, leaves):
                 leaf_out[idx] = np.asarray(leaf)[j]
 
-    return SweepResult(stats=Stats(*stats_out), hit_curve=hit,
+    return SweepResult(stats=jax.tree.unflatten(treedef, stats_out),
+                       hit_curve=hit,
                        lengths=lengths,
                        compiles=compiles,
                        seconds=time.time() - t0)
@@ -753,8 +775,10 @@ def sweep_grid(cfgs: Dict[str, SimConfig], blocks: np.ndarray,
     Grid entries with *equal* configs — e.g. a parameter sweep whose
     pivot equals the baseline — share one simulation pass outright (the
     frozen configs are hashable), on top of the per-config executable
-    cache in ``_runner``.
+    cache in ``_runner``. A write-back configuration is refused.
     """
+    for cfg in cfgs.values():
+        _refuse_write_back(cfg, "sweep_grid")
     memo: Dict[SimConfig, SweepResult] = {}
     out = {}
     for name, cfg in cfgs.items():
@@ -796,18 +820,21 @@ class _Tenant:
     means the whole trace is available at step 0 (the offline case).
     ``cursor`` is the next unplaced request — the ONLY progress state,
     and it is host-known, which is what lets the scheduler run ahead of
-    the device (see :class:`RingBuffer`).
+    the device (see :class:`RingBuffer`). ``writes`` holds each request's
+    write flag in a write-back configuration, else ``None``.
     """
 
-    __slots__ = ("index", "blocks", "avail", "length", "cursor")
+    __slots__ = ("index", "blocks", "avail", "length", "cursor", "writes")
 
     def __init__(self, index: int, blocks: np.ndarray,
-                 avail: Optional[np.ndarray], length: int):
+                 avail: Optional[np.ndarray], length: int,
+                 writes: Optional[np.ndarray] = None):
         self.index = index
         self.blocks = blocks
         self.avail = avail
         self.length = length
         self.cursor = 0
+        self.writes = writes
 
 
 class _Slab(NamedTuple):
@@ -826,6 +853,8 @@ class _Slab(NamedTuple):
     the host staging pair so the async drain can recycle it into the
     producer's pool once the slab's outputs materialize (``None`` on
     the synchronous path, where staging arrays are throwaway).
+    ``writes`` is the staged write column of a write-back configuration
+    (``None`` where the configuration reads only, and nothing is staged).
     """
 
     blocks: jax.Array                       # (chunk, W) int32, staged
@@ -834,7 +863,14 @@ class _Slab(NamedTuple):
     placements: Tuple[Tuple[int, int, int, int, int,
                             Optional[np.ndarray]], ...]
     harvest: Tuple[Tuple[int, int], ...]
-    buffers: Optional[Tuple[np.ndarray, np.ndarray]] = None
+    buffers: Optional[Tuple[np.ndarray, ...]] = None
+    writes: Optional[jax.Array] = None      # (chunk, W) bool, staged
+
+    @property
+    def slabs(self) -> tuple:
+        """The chunk runner's slab arguments."""
+        return ((self.blocks, self.valid) if self.writes is None
+                else (self.blocks, self.valid, self.writes))
 
 
 class RingBuffer:
@@ -1016,7 +1052,9 @@ def sweep_streaming(cfg: SimConfig,
                     chunk: int = DEFAULT_CHUNK, unroll: int = 1,
                     shard: Optional[bool] = None,
                     ring_depth: int = DEFAULT_RING_DEPTH,
-                    async_producer: bool = True) -> StreamResult:
+                    async_producer: bool = True,
+                    writes: Optional[Sequence[np.ndarray]] = None
+                    ) -> StreamResult:
     """Online ingestion: arrival is the primitive, traces stream through
     a recycled lane pool (DESIGN.md §10).
 
@@ -1029,6 +1067,11 @@ def sweep_streaming(cfg: SimConfig,
     mid-run after a masked init reset (:func:`_masked_reset`) instead of
     the engine scanning padded tails. Slabs stage through a
     :class:`RingBuffer` ``ring_depth`` ahead of the device.
+
+    ``writes`` gives a write-back configuration (``SimConfig.write_back``)
+    each trace's per-request write flags, one bool array per trace in
+    the style of ``arrivals`` (``None`` = reads); the producer places
+    them beside the blocks and stages them as a third slab.
 
     ``arrivals`` gives per-trace nondecreasing request arrival steps
     (``None`` = everything at step 0); when every trace arrives at 0 and
@@ -1054,6 +1097,9 @@ def sweep_streaming(cfg: SimConfig,
     counters and the overlap ratio surface in
     :meth:`StreamResult.streaming_stats` under ``"pipeline"``, and the
     mining passes by path under ``"mining"``.
+
+    ``pipeline["staged_bytes"]`` counts the bytes the producer put to the
+    device: every staged slab array, the write column included.
 
     Each stage runs under a ``jax.profiler.TraceAnnotation`` — per slab
     ``sweep.staging_wait``, ``sweep.produce`` (holding ``sweep.stage``),
@@ -1107,12 +1153,19 @@ def sweep_streaming(cfg: SimConfig,
                 raise ValueError(f"arrivals[{i}] must be nondecreasing "
                                  "and nonnegative")
             avails[i] = a
+    if writes is not None and len(writes) != n:
+        raise ValueError(f"writes must give one array per trace "
+                         f"({n}), got {len(writes)}")
 
     w = min(n, DEFAULT_LANE_WIDTH) if lane_width is None \
         else max(1, int(lane_width))
     n_shards = _lane_shards(w, shard)
     chunk = max(1, min(int(chunk), max(1, t_max)))
-    tenants = [_Tenant(i, blocks[i], avails[i], int(lengths[i]))
+    wb = cfg.write_back
+    tenants = [_Tenant(i, blocks[i], avails[i], int(lengths[i]),
+                       write_flags(cfg, None if writes is None else writes[i],
+                                   int(lengths[i]))
+                       if wb or writes is not None else None)
                for i in range(n)]
 
     with _span("sweep.setup"):
@@ -1147,34 +1200,40 @@ def sweep_streaming(cfg: SimConfig,
     # (same avals + default sharding as jnp.asarray, so no extra
     # executable), pre-sharded per ring_specs on a mesh (ring_put) so
     # the shard_map consumer skips the dispatch-time reshard.
+    def new_buffers():
+        """One slab's host arrays: blocks, valid and, in a write-back
+        configuration, the write column."""
+        return ((np.zeros((chunk, w), np.int32), np.zeros((chunk, w), bool))
+                + ((np.zeros((chunk, w), bool),) if wb else ()))
+
     if async_producer:
         pool: _queue_mod.Queue = _queue_mod.Queue()
         for _ in range(ring_depth + 3):
-            pool.put((np.zeros((chunk, w), np.int32),
-                      np.zeros((chunk, w), bool)))
+            pool.put(new_buffers())
 
         if n_shards > 1:
-            def stage(b, v):
-                return dist_sharding.ring_put((b, v), mesh, axis=LANE_AXIS)
+            def stage(*arrs):
+                return dist_sharding.ring_put(arrs, mesh, axis=LANE_AXIS)
         else:
-            def stage(b, v):
-                return jax.device_put((b, v))
+            def stage(*arrs):
+                return jax.device_put(arrs)
     else:
-        def stage(b, v):
-            return jnp.asarray(b), jnp.asarray(v)
+        def stage(*arrs):
+            return tuple(jnp.asarray(a) for a in arrs)
 
     timers = {"produce_s": 0.0, "staging_wait_s": 0.0, "consume_s": 0.0,
               "drain_s": 0.0}
     made = 0            # slabs produced, so the next slab's index
+    staged_bytes = 0    # what the producer put to the device
 
-    def produce(bufs: Tuple[np.ndarray, np.ndarray]) -> Optional[_Slab]:
+    def produce(bufs: Tuple[np.ndarray, ...]) -> Optional[_Slab]:
         """The next slab, marshalled into ``bufs`` and staged; ``None``
         once every trace is placed."""
         with _span("sweep.produce", timers, "produce_s", slab=made):
             return _produce(bufs)
 
     def _produce(bufs) -> Optional[_Slab]:
-        nonlocal clock, made
+        nonlocal clock, made, staged_bytes
         while True:
             t_start = clock
             reset = np.zeros((w,), bool)
@@ -1205,9 +1264,9 @@ def sweep_streaming(cfg: SimConfig,
             # clock to the slab containing the head's first arrival
             head = tenants[queue[0]]
             clock = (int(head.avail[head.cursor]) // chunk) * chunk
-        slab_blocks, slab_valid = bufs
-        slab_blocks.fill(0)
-        slab_valid.fill(False)
+        slab_blocks, slab_valid, *slab_writes = bufs
+        for arr in bufs:
+            arr.fill(0)
         placements, harvest = [], []
         for lane, ti in enumerate(lanes):
             if ti is None:
@@ -1235,13 +1294,12 @@ def sweep_streaming(cfg: SimConfig,
                 else:
                     row0, pos = 0, p
             if k:
-                if pos is None:
-                    slab_blocks[row0: row0 + k, lane] = \
-                        t.blocks[t.cursor: t.cursor + k]
-                    slab_valid[row0: row0 + k, lane] = True
-                else:
-                    slab_blocks[pos, lane] = t.blocks[t.cursor: t.cursor + k]
-                    slab_valid[pos, lane] = True
+                rows = slice(row0, row0 + k) if pos is None else pos
+                slab_blocks[rows, lane] = t.blocks[t.cursor: t.cursor + k]
+                slab_valid[rows, lane] = True
+                if wb:
+                    slab_writes[0][rows, lane] = \
+                        t.writes[t.cursor: t.cursor + k]
                 placements.append((lane, ti, t.cursor, row0, k, pos))
                 t.cursor += k
             if t.cursor == t.length:
@@ -1249,12 +1307,14 @@ def sweep_streaming(cfg: SimConfig,
                 lanes[lane] = None      # recycled at the next admission
         clock = t_start + chunk
         with _span("sweep.stage", slab=made):
-            dev_blocks, dev_valid = stage(slab_blocks, slab_valid)
+            dev = stage(*bufs)
+        staged_bytes += sum(arr.nbytes for arr in bufs)
         made += 1
-        return _Slab(dev_blocks, dev_valid,
+        return _Slab(dev[0], dev[1],
                      reset if reset.any() else None,
                      tuple(placements), tuple(harvest),
-                     (slab_blocks, slab_valid) if async_producer else None)
+                     bufs if async_producer else None,
+                     dev[2] if wb else None)
 
     hit_curve = np.zeros((n, t_max), bool)
 
@@ -1278,7 +1338,7 @@ def sweep_streaming(cfg: SimConfig,
                 carry = _masked_reset(carry, template,
                                       place_mask(slab.reset))
         with _span("sweep.dispatch", timers, "consume_s", slab=index):
-            carry, hits = run_chunk(carry, slab.blocks, slab.valid)
+            carry, hits = run_chunk(carry, *slab.slabs)
             for ti, lane in slab.harvest:
                 stash[ti] = (carry["stats"], carry.get("mine_passes"), lane)
         return hits
@@ -1359,8 +1419,7 @@ def sweep_streaming(cfg: SimConfig,
         producing = True
         while True:
             while producing and not ring.full:
-                slab = produce((np.empty((chunk, w), np.int32),
-                                np.empty((chunk, w), bool)))
+                slab = produce(new_buffers())
                 if slab is None:
                     producing = False
                     break
@@ -1379,17 +1438,20 @@ def sweep_streaming(cfg: SimConfig,
     with _span("sweep.harvest"):
         mat: Dict[int, list] = {}
         rows, passes = [], []
+        treedef = jax.tree.structure(template["stats"])
         for ti in range(n):
             st, mp, lane = stash[ti]
             if id(st) not in mat:
-                mat[id(st)] = ([np.asarray(leaf) for leaf in st],
+                mat[id(st)] = ([np.asarray(leaf)
+                                for leaf in jax.tree.leaves(st)],
                                None if mp is None else np.asarray(mp))
             leaves, mps = mat[id(st)]
             rows.append([leaf[lane] for leaf in leaves])
             if mps is not None:
                 passes.append(mps[lane])
-        stats = Stats(*(np.stack([r[j] for r in rows])
-                        for j in range(len(Stats._fields))))
+        stats = jax.tree.unflatten(treedef, [
+            np.stack([r[j] for r in rows])
+            for j in range(treedef.num_leaves)])
         mine_passes = (np.stack(passes).astype(np.int64)
                        if cfg.use_mithril else None)
 
@@ -1403,6 +1465,7 @@ def sweep_streaming(cfg: SimConfig,
         "producer_stalls": int(ring.push_stalls),
         "consumer_stalls": int(ring.pop_stalls),
         "overlap": round(max(0.0, 1.0 - wall_s / busy), 4) if busy else 0.0,
+        "staged_bytes": int(staged_bytes),
     }
     after = compile_count(cfg, unroll, n_shards)
     result = SweepResult(stats=stats, hit_curve=hit_curve, lengths=lengths,

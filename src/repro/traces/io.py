@@ -121,7 +121,7 @@ def _rebase(blocks: np.ndarray, rebase: bool) -> np.ndarray:
 
 def ingest_msr_csv(path: str, block_size: int = BLOCK_SIZE,
                    only: Optional[str] = None, rebase: bool = True,
-                   chunk_rows: int = 1 << 18) -> np.ndarray:
+                   chunk_rows: int = 1 << 18, writes: bool = False):
     """MSR-Cambridge-style CSV -> int64 block-id stream.
 
     Each record covers ``ceil`` of its byte range in blocks; multi-block
@@ -129,13 +129,18 @@ def ingest_msr_csv(path: str, block_size: int = BLOCK_SIZE,
     property). ``only`` filters on the Type column (e.g. ``"Read"``,
     case-insensitive). Rows stream in ``chunk_rows`` batches.
 
+    ``writes=True`` returns ``(blocks, writes)``: beside the blocks, one
+    bool per expanded block, set where its record's Type is ``Write``
+    (every block of a write is a write), as ``sweep_streaming(...,
+    writes=)`` and ``simulate(..., writes=)`` take them.
+
     Malformed rows raise ``ValueError`` with file:line context — a
     truncated row, non-integer field, decreasing timestamp, negative
     offset or zero-length byte range would otherwise shift or silently
     drop requests (the fuzz battery used to surface exactly that: short
     rows were skipped and ``size=0`` was coerced to one byte).
     """
-    parts = []
+    parts, flag_parts = [], []
     last_ts = None
     lineno = 0
     with open(path) as f:
@@ -143,7 +148,7 @@ def ingest_msr_csv(path: str, block_size: int = BLOCK_SIZE,
             lines = f.readlines(chunk_rows * 64)   # ~64B/row hint
             if not lines:
                 break
-            offs, sizes = [], []
+            offs, sizes, kinds = [], [], []
             for ln in lines:
                 lineno += 1
                 ln = ln.strip()
@@ -177,10 +182,12 @@ def ingest_msr_csv(path: str, block_size: int = BLOCK_SIZE,
                     raise ValueError(
                         f"{path}:{lineno}: byte range [{off}, {off + size})"
                         " overflows int64 offset arithmetic")
-                if only and cols[_MSR_TYPE].strip().lower() != only.lower():
+                kind = cols[_MSR_TYPE].strip().lower()
+                if only and kind != only.lower():
                     continue
                 offs.append(off)
                 sizes.append(size)
+                kinds.append(kind == "write")
             if not offs:
                 continue
             off = np.asarray(offs, np.int64)
@@ -193,9 +200,14 @@ def ingest_msr_csv(path: str, block_size: int = BLOCK_SIZE,
             within = np.arange(total) - np.repeat(
                 np.cumsum(nblk) - nblk, nblk)
             parts.append(reps + within)
+            flag_parts.append(np.repeat(np.asarray(kinds, bool), nblk))
     blocks = (np.concatenate(parts) if parts
               else np.empty((0,), np.int64))
-    return _rebase(blocks, rebase)
+    blocks = _rebase(blocks, rebase)
+    if not writes:
+        return blocks
+    return blocks, (np.concatenate(flag_parts) if flag_parts
+                    else np.empty((0,), bool))
 
 
 def ingest_raw(path: str, block_size: int = BLOCK_SIZE,

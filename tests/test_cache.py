@@ -31,7 +31,7 @@ def run_cache(trace, capacity, ways=16, policy="lru"):
     acc = jax.jit(lambda s, b: base.access(s, b, policy))
     hits = 0
     for b in trace:
-        stt, hit, _, _ = acc(stt, jnp.int32(b))
+        stt, hit, _, _, _ = acc(stt, jnp.int32(b))
         hits += int(hit)
     return hits / len(trace), stt
 
@@ -61,10 +61,10 @@ class TestPrefetchBookkeeping:
         stt, issued2, _ = base.insert_prefetch(
             stt, jnp.int32(42), jnp.int32(PF_MITHRIL), jnp.array(True))
         assert not bool(issued2)
-        stt, hit, used_src, _ = base.access(stt, jnp.int32(42))
+        stt, hit, used_src, _, _ = base.access(stt, jnp.int32(42))
         assert bool(hit) and int(used_src) == PF_MITHRIL
         # second access: no longer counted as prefetch-use
-        stt, hit, used_src, _ = base.access(stt, jnp.int32(42))
+        stt, hit, used_src, _, _ = base.access(stt, jnp.int32(42))
         assert bool(hit) and int(used_src) == PF_NONE
 
     def test_second_chance(self):
@@ -73,10 +73,10 @@ class TestPrefetchBookkeeping:
         stt, _, _ = base.insert_prefetch(
             stt, jnp.int32(1000), jnp.int32(PF_MITHRIL), jnp.array(True))
         for b in range(4):                  # fill + overflow the bucket
-            stt, _, _, _ = base.access(stt, jnp.int32(b))
+            stt, _, _, _, _ = base.access(stt, jnp.int32(b))
         assert bool(base.contains(stt, jnp.int32(1000)))  # second chance
         for b in range(4, 12):
-            stt, _, _, _ = base.access(stt, jnp.int32(b))
+            stt, _, _, _, _ = base.access(stt, jnp.int32(b))
         assert not bool(base.contains(stt, jnp.int32(1000)))  # now gone
 
 
@@ -86,7 +86,7 @@ def test_capacity_never_exceeded(trace):
     stt = base.init_cache(16, ways=4)
     acc = jax.jit(lambda s, b: base.access(s, b, "lru"))
     for b in trace:
-        stt, _, _, _ = acc(stt, jnp.int32(b))
+        stt, _, _, _, _ = acc(stt, jnp.int32(b))
     assert int(np.sum(np.asarray(stt.key) != -1)) <= 16
 
 
@@ -98,7 +98,7 @@ def test_hit_iff_previously_inserted_and_not_evicted(trace):
     acc = jax.jit(lambda s, b: base.access(s, b, "lru"))
     seen = set()
     for b in trace:
-        stt, hit, _, _ = acc(stt, jnp.int32(b))
+        stt, hit, _, _, _ = acc(stt, jnp.int32(b))
         if bool(hit):
             assert b in seen
         seen.add(b)

@@ -208,7 +208,7 @@ def test_scored_path_matches_numpy_oracle(events, kind):
                                                hint, lcfg)
             assert bool(issued) == want_issued, msg
         else:
-            state, hit, used, ev = access(state, jnp.int32(blk),
+            state, hit, used, ev, _ = access(state, jnp.int32(blk),
                                           assoc_hint=jnp.int32(hint))
             want_hit, want_used, want_ev = np_access(stt, blk, hint, lcfg)
             assert bool(hit) == want_hit, msg
@@ -228,9 +228,9 @@ def test_scored_access_disabled_is_noop(events, kind):
     access, _, dis = _STEPS[kind]
     state = base.init_cache(capacity=32, ways=4)
     for blk, _, hint in events:
-        state, _, _, _ = access(state, jnp.int32(blk),
+        state, _, _, _, _ = access(state, jnp.int32(blk),
                                 assoc_hint=jnp.int32(hint))
-        frozen, hit, used, ev = dis(state, jnp.int32(blk),
+        frozen, hit, used, ev, _ = dis(state, jnp.int32(blk),
                                     assoc_hint=jnp.int32(hint))
         for f in state._fields:
             np.testing.assert_array_equal(

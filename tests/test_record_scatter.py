@@ -408,7 +408,7 @@ def test_cache_access_matches_reference(blocks):
     for policy, (acc, acc_ref) in _CACHE_STEPS.items():
         got = want = base.init_cache(capacity=8, ways=2)
         for i, blk in enumerate(blocks):
-            got, g_hit, g_src, g_ev = acc(got, jnp.int32(blk))
+            got, g_hit, g_src, g_ev, _ = acc(got, jnp.int32(blk))
             want, w_hit, w_src, w_ev = acc_ref(want, jnp.int32(blk))
             assert_trees_equal((got, g_hit, g_src, g_ev),
                                (want, w_hit, w_src, w_ev),
@@ -430,8 +430,8 @@ def test_cache_access_disabled_is_noop(blocks):
     dis = jax.jit(functools.partial(base.access, enabled=False))
     stt = base.init_cache(capacity=8, ways=2)
     for blk in blocks:
-        stt, _, _, _ = acc(stt, jnp.int32(blk))
-        frozen, hit, used, ev = dis(stt, jnp.int32(blk))
+        stt, _, _, _, _ = acc(stt, jnp.int32(blk))
+        frozen, hit, used, ev, _ = dis(stt, jnp.int32(blk))
         assert_trees_equal(frozen, stt,
                            f"enabled=False mutated cache on block {blk}")
         assert not bool(hit) and int(used) == base.PF_NONE

@@ -11,7 +11,9 @@ benchmark's table sizes (SUITE_MITHRIL) and at the paper's
 compile the sweep's whole chunk runner with its dispatch steered to
 the kernels, as it is on a TPU: once at the SUITE tables for its named
 scopes, and at the benchmark cells' paper-size tables for the layout of
-the prefetch table inside the scan's step.
+the prefetch table inside the scan's step (read-only and write-back),
+and for what the read-only runner keeps from before write-back existed:
+its carry, its slab arguments and its scatters.
 
 The topology is described inside a module fixture, never at import:
 one process at a time may load the TPU library, so only the test
@@ -124,9 +126,21 @@ def test_paged_decode_compiles(one_chip, scale):
     assert _kernels(compiled) == 1
 
 
+_HLO_TEXT: dict = {}
+
+
 def _runner_hlo(one_chip, monkeypatch, cfg, lanes=16, chunk=256) -> str:
     """Compiled HLO text of the sweep's chunk runner for ``cfg`` on the
-    described chip, with the backend dispatch steered to the kernels."""
+    described chip, with the backend dispatch steered to the kernels;
+    compiled once per configuration and shape in this module."""
+    key = (cfg, lanes, chunk)
+    if key not in _HLO_TEXT:
+        _HLO_TEXT[key] = _compile_runner(one_chip, monkeypatch, cfg, lanes,
+                                         chunk)
+    return _HLO_TEXT[key]
+
+
+def _compile_runner(one_chip, monkeypatch, cfg, lanes, chunk) -> str:
     from repro.kernels import backend
     sweep_mod = importlib.import_module("repro.cache.sweep")
 
@@ -136,9 +150,11 @@ def _runner_hlo(one_chip, monkeypatch, cfg, lanes=16, chunk=256) -> str:
         init_batched, run_chunk, _ = sweep_mod._runner(cfg, 1, 1)
         carry = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
                              jax.eval_shape(lambda: init_batched(lanes)))
-        return run_chunk.lower(
-            carry, _sds(one_chip, (chunk, lanes)),
-            _sds(one_chip, (chunk, lanes), jnp.bool_)).compile().as_text()
+        slabs = [_sds(one_chip, (chunk, lanes)),
+                 _sds(one_chip, (chunk, lanes), jnp.bool_)]
+        if cfg.write_back:
+            slabs.append(_sds(one_chip, (chunk, lanes), jnp.bool_))
+        return run_chunk.lower(carry, *slabs).compile().as_text()
     finally:
         sweep_mod.reset_runners()
 
@@ -187,12 +203,17 @@ def test_sweep_runner_compiles_with_kernels(one_chip, monkeypatch):
         assert scope in body, scope
 
 
-@pytest.mark.parametrize("amp", [False, True], ids=["mithril", "mithril_amp"])
-def test_sweep_step_keeps_prefetch_table_layout(one_chip, monkeypatch, amp):
+@pytest.mark.parametrize("amp,write_back",
+                         [(False, False), (True, False), (False, True)],
+                         ids=["mithril", "mithril_amp", "mithril_wb"])
+def test_sweep_step_keeps_prefetch_table_layout(one_chip, monkeypatch, amp,
+                                                write_back):
     """At the benchmark cells' tables (the paper's R, S, Delta, P and
     table sizes, 1,250 mining rows, a fully associative 512-block cache,
     16 lanes, chunks of 256), no instruction of the scan's step writes
-    the lanes' prefetch table ``pf_vals`` with its P axis minor-most.
+    the lanes' prefetch table ``pf_vals`` with its P axis minor-most;
+    nor in the write-back cache, whose dirty table sits in the same
+    bucket rows the prefetch inserts write.
 
     That layout pads each (ways, P) pair to a whole tile, and a lookup
     that reads a slot row whole makes the compiler relayout the entire
@@ -203,7 +224,7 @@ def test_sweep_step_keeps_prefetch_table_layout(one_chip, monkeypatch, amp):
 
     mith = dataclasses.replace(PAPER_MITHRIL, mine_rows=1250)
     cfg = SimConfig(capacity=512, ways=512, use_mithril=True, use_amp=amp,
-                    mithril=mith)
+                    mithril=mith, write_back=write_back)
     table = "s32[16,{},{},{}]".format(mith.pf_buckets, mith.pf_ways,
                                       mith.prefetch_list)
     p_minor = re.escape(table) + r"\{3,"
@@ -211,3 +232,56 @@ def test_sweep_step_keeps_prefetch_table_layout(one_chip, monkeypatch, amp):
     padded = [ln.split(", metadata=")[0][:160] for ln in body
               if re.search(p_minor, ln.split(", metadata=")[0])]
     assert not padded, padded
+
+
+# The read-only runner's cache and statistics leaves, as they were before
+# write-back existed; (lanes, buckets, ways) tables and per-lane counters.
+READ_ONLY_LEAVES = {
+    **{f"['cache'].{f}": ((16, 1, 512), "int32")
+       for f in ("key", "stamp", "pf_flag", "pf_sc", "pf_src", "freq",
+                 "assoc")},
+    "['cache'].clock": ((16,), "int32"),
+    "['stats'].requests": ((16,), "int32"),
+    "['stats'].hits": ((16,), "int32"),
+    **{f"['stats'].{f}": ((16, 4), "int32")
+       for f in ("pf_issued", "pf_used", "pf_evicted_unused")},
+}
+
+
+@pytest.mark.parametrize("amp,leaves,scatters",
+                         [(False, 32, 29), (True, 38, 108)],
+                         ids=["mithril", "mithril_amp"])
+def test_read_only_runner_is_unchanged_by_write_back(one_chip, monkeypatch,
+                                                     amp, leaves, scatters):
+    """With ``write_back`` off, the benchmark cells' chunk runner keeps
+    the carry, the slab arguments and the scatters it had before the
+    write-back cache existed: no dirty table and no write-back counter in
+    the carry, the slabs ``(int32 blocks, bool valid)`` and no third,
+    no operation under the ``writeback`` scope, and as many scatter
+    instructions in the v5e program (29 without AMP, 108 with)."""
+    from repro.cache import SimConfig
+
+    sweep_mod = importlib.import_module("repro.cache.sweep")
+    mith = dataclasses.replace(PAPER_MITHRIL, mine_rows=1250)
+    cfg = SimConfig(capacity=512, ways=512, use_mithril=True, use_amp=amp,
+                    mithril=mith)
+    sweep_mod.reset_runners()
+    try:
+        init_batched, run_chunk, _ = sweep_mod._runner(cfg, 1, 1)
+        carry = jax.eval_shape(lambda: init_batched(16))
+        flat = {jax.tree_util.keystr(p): (tuple(a.shape), str(a.dtype))
+                for p, a in jax.tree_util.tree_flatten_with_path(carry)[0]}
+        assert len(flat) == leaves
+        assert {k: v for k, v in flat.items()
+                if k.startswith(("['cache']", "['stats']"))} \
+            == READ_ONLY_LEAVES
+        blocks = jax.ShapeDtypeStruct((256, 16), jnp.int32)
+        valid = jax.ShapeDtypeStruct((256, 16), jnp.bool_)
+        jax.eval_shape(run_chunk, carry, blocks, valid)
+        with pytest.raises(TypeError):
+            jax.eval_shape(run_chunk, carry, blocks, valid, valid)
+    finally:
+        sweep_mod.reset_runners()
+    text = _runner_hlo(one_chip, monkeypatch, cfg)
+    assert "writeback" not in text
+    assert len(re.findall(r" scatter\(", text)) == scatters

@@ -94,6 +94,42 @@ class TestIngest:
         got = ingest_msr_csv(path, block_size=4096, only="Read")
         np.testing.assert_array_equal(got, [0, 1])   # rebased, writes out
 
+    def test_msr_csv_write_column(self, tmp_path):
+        """``writes=True``: one flag per expanded block, set on every
+        block of a Write record, through the filter and the rebase; the
+        blocks are the default return's."""
+        path = os.path.join(tmp_path, "vol.csv")
+        self._write_msr(path, [("Read", 8192, 4096),
+                               ("Write", 20480, 8192),
+                               ("Read", 12800, 4096),
+                               ("write", 40960, 4096)])
+        blocks, writes = ingest_msr_csv(path, block_size=4096,
+                                        rebase=False, writes=True)
+        np.testing.assert_array_equal(blocks, [2, 5, 6, 3, 4, 10])
+        np.testing.assert_array_equal(writes, [0, 1, 1, 0, 0, 1])
+        assert writes.dtype == bool
+        np.testing.assert_array_equal(
+            ingest_msr_csv(path, block_size=4096, rebase=False), blocks)
+        blocks, writes = ingest_msr_csv(path, block_size=4096,
+                                        only="Write", writes=True)
+        np.testing.assert_array_equal(blocks, [0, 1, 5])    # rebased
+        assert writes.all()
+        blocks, writes = ingest_msr_csv(path, block_size=4096, only="Read",
+                                        chunk_rows=1, writes=True)
+        np.testing.assert_array_equal(blocks, [0, 1, 2])
+        assert not writes.any()
+
+    def test_msr_fixture_write_column(self):
+        path = os.path.join(os.path.dirname(__file__), "fixtures",
+                            "msr_tiny.csv")
+        blocks, writes = ingest_msr_csv(path, writes=True)
+        np.testing.assert_array_equal(blocks, ingest_msr_csv(path))
+        assert writes.shape == blocks.shape
+        # the file's four 4 KB writes, one block each
+        assert int(writes.sum()) == 4
+        reads = ingest_msr_csv(path, only="Read", rebase=False)
+        assert len(reads) == len(blocks) - 4
+
     def test_msr_csv_streams_in_chunks(self, tmp_path):
         path = os.path.join(tmp_path, "big.csv")
         offs = np.arange(500) * 4096
