@@ -91,7 +91,7 @@ def jnp_forms():
 
     saved = sweep_mod._batched_record_fn, sweep_mod._batched_pairwise_fn
     sweep_mod._batched_record_fn = lambda: None
-    sweep_mod._batched_pairwise_fn = lambda: (None, None)
+    sweep_mod._batched_pairwise_fn = lambda: None
     sweep_mod.reset_runners()
     try:
         yield
@@ -153,7 +153,7 @@ def phase_mid() -> str:
     _, blocks, lengths = corpus_suite("mid", 20_000)
     plan = plan_sweep(lengths)
     kernels = kernels_in_runner(cfg, *plan.shapes[-1])
-    if not {"mithril_record", "mithril_mine_batched"} <= set(kernels):
+    if not {"mithril_record", "mithril_mine"} <= set(kernels):
         raise AssertionError(f"chunk runner kernels: {kernels}")
     t0 = time.perf_counter()
     for chunk, width in plan.shapes:
@@ -191,7 +191,7 @@ def phase_paper() -> str:
     blocks, lengths = full_corpus()
     lanes = len(lengths)
     kernels = kernels_in_runner(cfg, CHUNK, lanes)
-    if not {"mithril_record", "mithril_mine_batched"} <= set(kernels):
+    if not {"mithril_record", "mithril_mine"} <= set(kernels):
         raise AssertionError(f"chunk runner kernels: {kernels}")
     out, compile_s, steady_s = stream(cfg, blocks, lengths, lanes)
     peak = (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")

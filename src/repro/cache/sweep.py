@@ -34,11 +34,11 @@ Batching invariants (DESIGN.md §6–§7):
 * the one expensive rare branch — the MITHRIL mining pass — is hoisted
   out of the vmapped step via the segment barriers of
   ``simulator.build_segments`` and guarded by a *batch-level*
-  ``lax.cond`` (``jnp.any(need)``) around the fused
-  ``mithril.mine_batched`` (one Pallas launch over all lanes on TPU), so
-  it only executes when some live lane actually filled its mining table
-  — callers of ``record_event`` owe that barrier before the next record
-  (the record/maybe_mine contract);
+  ``lax.cond`` (``jnp.any(need)``) around ``mithril.mine_batched``,
+  which mines the flagged lanes one at a time (one Pallas launch per
+  lane on TPU), so it only executes when some live lane actually filled
+  its mining table — callers of ``record_event`` owe that barrier
+  before the next record (the record/maybe_mine contract);
 * padded-tail requests carry ``valid=False`` into every segment, whose
   scatter updates then write back old values — an exhausted lane can
   neither change state, contribute to statistics, nor trigger mining.
@@ -95,21 +95,20 @@ def pad_traces(traces: Union[Mapping[str, np.ndarray],
 
 
 def _batched_pairwise_fn():
-    """Pairwise-check implementations for the batched mining barrier.
+    """Pairwise-check implementation for the batched mining barrier.
 
-    Returns ``(batched_fn, serial_fn)`` for ``mithril.mine_batched``: on
-    TPU the lanes-axis Pallas kernel covers every mining lane with one
-    launch (grid over (lane, row-block) — DESIGN.md §7) and the
-    row-block kernel serves the single-flagged-lane fast path; elsewhere
-    the pure-jnp oracles are faster than interpreted kernels, so
-    ``(None, None)`` defers to ``mine_batched``'s defaults. Kernel and
-    oracle are bit-identical (``tests/test_kernels.py``).
+    Returns the per-lane ``pairwise_fn`` for ``mithril.mine_batched``,
+    which mines the flagged lanes one at a time: on TPU the row-block
+    Pallas kernel (``kernels.ops.mithril_pairwise``); elsewhere the
+    pure-jnp oracle is faster than an interpreted kernel, so ``None``
+    defers to ``mine_batched``'s default. Kernel and oracle are
+    bit-identical (``tests/test_kernels.py``).
     """
     from repro.kernels.backend import on_tpu
     if not on_tpu():
-        return None, None
-    from repro.kernels.ops import mithril_pairwise, mithril_pairwise_batched
-    return mithril_pairwise_batched, mithril_pairwise
+        return None
+    from repro.kernels.ops import mithril_pairwise
+    return mithril_pairwise
 
 
 def _batched_record_fn():
@@ -132,9 +131,10 @@ def _batched_record_fn():
 
 
 # Columns of the carry's per-lane ``mine_passes`` counters: passes in
-# which the lane was mined alone, all-lanes passes it took part in, and
-# all-lanes passes it led (the first lane mined in the pass), so the
-# last column sums to the all-lanes passes, one per device that ran one.
+# which the lane was mined alone, multi-lane passes (steps on which the
+# barrier mined several lanes) it took part in, and multi-lane passes it
+# led (the first lane mined in the pass), so the last column sums to the
+# multi-lane passes, one per device that ran one.
 MINE_PASS_KEYS = ("solo_passes", "fused_lanes", "fused_passes")
 
 
@@ -144,9 +144,9 @@ def build_batched_step(cfg: SimConfig):
     ``step(carry, (blocks, valid))`` (in a write-back configuration
     ``(blocks, valid, writes)``) advances every trace lane by one
     request: the branchless scatter-form segments run under ``vmap``,
-    each mining barrier runs one batch-level ``lax.cond`` around the
-    fused ``mithril.mine_batched``, and invalid (padded) lanes keep
-    their previous carry bit-for-bit.
+    each mining barrier runs one batch-level ``lax.cond`` around
+    ``mithril.mine_batched``'s loop over the flagged lanes, and invalid
+    (padded) lanes keep their previous carry bit-for-bit.
 
     Each part of the step runs under a ``jax.named_scope`` — ``access``,
     ``record``, ``barrier``, ``prefetch`` (MITHRIL's lookup and inserts),
@@ -159,8 +159,7 @@ def build_batched_step(cfg: SimConfig):
     """
     init_carry, segments = build_segments(cfg)
     mine_rows = cfg.mithril.mine_rows
-    pairwise_fn, serial_pairwise_fn = (
-        _batched_pairwise_fn() if cfg.use_mithril else (None, None))
+    pairwise_fn = _batched_pairwise_fn() if cfg.use_mithril else None
     record_fn = _batched_record_fn() if cfg.use_mithril else None
 
     def init_lane(_):
@@ -178,21 +177,20 @@ def build_batched_step(cfg: SimConfig):
 
         This runs at batch level — *outside* vmap — so the outer
         ``lax.cond`` is a real runtime conditional: on the (rare)
-        triggering steps, ``mithril.mine_batched`` runs one fused
-        association search over ALL lanes (one Pallas launch on TPU)
-        and folds pairs in with vmapped scatter updates; lanes with
-        ``need=False`` select their previous state bit-for-bit. On every
-        other step the barrier costs one predicate reduction.
-        ``mine_batched`` takes its one-lane path when exactly one lane
-        is mined, and the counters say which path each lane took.
+        triggering steps, ``mithril.mine_batched`` mines the flagged
+        lanes one at a time, each with the serial association search
+        (one Pallas launch per lane on TPU) and a fold into that lane's
+        own tables; lanes with ``need=False`` are never touched. On
+        every other step the barrier costs one predicate reduction.
+        The counters say whether a lane was mined alone on its step or
+        beside others (a multi-lane pass).
         """
         mith = carry["mith"]
         need = (mith.mine_fill >= mine_rows) & valid
         mith = lax.cond(
             jnp.any(need),
             lambda m: mithril.mine_batched(
-                cfg.mithril, m, need, pairwise_fn=pairwise_fn,
-                serial_pairwise_fn=serial_pairwise_fn),
+                cfg.mithril, m, need, pairwise_fn=pairwise_fn),
             lambda m: m, mith)
         n = jnp.sum(need.astype(jnp.int32))
         fused = need & (n > 1)
@@ -1001,10 +999,10 @@ class StreamResult(NamedTuple):
 
     ``mine_passes`` (MITHRIL configurations only) holds each trace's
     mining passes, in submission order, by the columns of
-    ``MINE_PASS_KEYS``: passes that mined its lane alone, all-lanes
-    passes that mined it, and all-lanes passes it led. A trace's solo
-    passes plus its all-lanes passes are the passes the serial
-    ``simulate`` mines it with; which path a pass takes depends on how
+    ``MINE_PASS_KEYS``: passes that mined its lane alone, multi-lane
+    passes that mined it, and multi-lane passes it led. A trace's solo
+    passes plus its multi-lane passes are the passes the serial
+    ``simulate`` mines it with; which kind a pass is depends on how
     many lanes of a device fill their mining tables on the same step.
     """
 

@@ -7,7 +7,7 @@ Public API (all pure, jit/scan-safe):
     cand  = lookup(cfg, state, block)            # pFlag path; (P,) block ids or EMPTY
     state, cand = access(cfg, state, block, do_record, do_lookup)
     state = mine(cfg, state)                     # usually triggered by record()
-    states = mine_batched(cfg, states, need)     # lanes-axis mine for the sweep
+    states = mine_batched(cfg, states, need)     # flagged lanes, one by one
 
 The recording table is set-associative with in-bucket storage; migration to
 the mining table happens when a block accumulates ``min_support`` timestamps;
@@ -50,8 +50,7 @@ from jax import lax
 
 from .config import MithrilConfig
 from .hashindex import EMPTY, locate, probe
-from .mining import (associations_dense, associations_dense_batched,
-                     pairwise_codes, pairwise_codes_batched)
+from .mining import associations_dense, pairwise_codes
 from .state import MithrilState, init_state
 
 init = init_state
@@ -182,58 +181,38 @@ def mine(cfg: MithrilConfig, state: MithrilState,
 
 
 def mine_batched(cfg: MithrilConfig, states: MithrilState, need: jax.Array,
-                 pairwise_fn: Optional[Callable] = None,
-                 serial_pairwise_fn: Optional[Callable] = None
-                 ) -> MithrilState:
+                 pairwise_fn: Optional[Callable] = None) -> MithrilState:
     """Mine every lane flagged in ``need``; other lanes are untouched.
 
     ``states`` is a stacked :class:`MithrilState` with a leading ``(B,)``
     lanes axis (the sweep engine's carry); ``need`` is a ``(B,)`` bool.
-    Per-lane results are bit-identical to calling :func:`mine` on
-    exactly the needed lanes (``tests/test_record_scatter.py``,
-    ``tests/test_sweep.py``). Two paths behind a batch-level
-    ``lax.cond`` (a real runtime conditional — this function is meant to
-    be called *outside* any vmap):
+    A ``lax.while_loop`` (meant to run *outside* any vmap) takes the
+    first lane still flagged, extracts it, runs the serial :func:`mine`
+    on it (with ``pairwise_fn``, the per-lane contract of
+    ``mining.pairwise_codes``, e.g. the row-block Pallas kernel
+    ``kernels.ops.mithril_pairwise`` on TPU), writes it back and clears
+    its flag, until no flag is left: mining work in proportion to the
+    lanes mined, whatever the batch width. Per-lane results are
+    bit-identical to calling :func:`mine` on exactly the needed lanes
+    (``tests/test_record_scatter.py``, ``tests/test_sweep.py``).
 
-    * exactly ONE lane flagged — the common case when unsynchronized
-      trace lanes fill their tables at their own pace — extracts that
-      lane, runs the serial :func:`mine` (with ``serial_pairwise_fn``,
-      e.g. the row-block Pallas kernel ``kernels.ops.mithril_pairwise``
-      on TPU), and scatters it back: O(1) mining work per trigger
-      regardless of the batch width;
-    * several lanes flagged: one fused pass over ALL lanes —
-      ``pairwise_fn`` takes the batched ``(B, N, S)`` contract of
-      ``mining.pairwise_codes_batched``, which the Pallas kernel
-      ``kernels.ops.mithril_pairwise_batched`` implements with one grid
-      over (lane, row-block) — then a vmapped scan of the scatter-form
-      :func:`add_association` folds pairs in, and lanes with
-      ``need=False`` select their previous state wholesale.
+    Each pass folds its pairs into ONE lane's unbatched prefetch table.
+    A fold vmapped over the lanes axis would make the TPU compiler
+    relayout every lane's ``pf_key``/``pf_vals``/``pf_age`` on each
+    folded pair (DESIGN.md §7).
     """
-    fn = pairwise_fn or pairwise_codes_batched
-
-    def one_lane(sts: MithrilState) -> MithrilState:
-        i = jnp.argmax(need).astype(jnp.int32)
+    def body(carry):
+        sts, left = carry
+        i = jnp.argmax(left).astype(jnp.int32)
         lane = jax.tree_util.tree_map(lambda x: x[i], sts)
-        mined = mine(cfg, lane, pairwise_fn=serial_pairwise_fn)
-        return jax.tree_util.tree_map(lambda x, v: x.at[i].set(v),
-                                      sts, mined)
+        mined = mine(cfg, lane, pairwise_fn=pairwise_fn)
+        sts = jax.tree_util.tree_map(lambda x, v: x.at[i].set(v),
+                                     sts, mined)
+        return sts, left.at[i].set(False)
 
-    def fused(sts: MithrilState) -> MithrilState:
-        src, dst, valid, dropped = associations_dense_batched(
-            sts.mine_block, sts.mine_ts, sts.mine_cnt,
-            cfg.min_support, cfg.max_support, cfg.lookahead,
-            cfg.window, cfg.pairs_cap, pairwise_fn=fn)
-        mined = jax.vmap(functools.partial(_fold_pairs, cfg))(
-            sts, src, dst, valid, dropped)
-
-        def sel(new, old):
-            nd = need.reshape(need.shape + (1,) * (new.ndim - need.ndim))
-            return jnp.where(nd, new, old)
-
-        return jax.tree_util.tree_map(sel, mined, sts)
-
-    return lax.cond(jnp.sum(need.astype(jnp.int32)) == 1,
-                    one_lane, fused, states)
+    states, _ = lax.while_loop(lambda c: jnp.any(c[1]), body,
+                               (states, need))
+    return states
 
 
 # ---------------------------------------------------------------------------

@@ -20,10 +20,13 @@ kernel (``ops`` wrapper)  on TPU                      off TPU
                           via ``sweep.               ``mithril.
                           _batched_record_fn``        record_event_batched``
                                                       default)
-``mithril_pairwise[_batched]``  mining barrier, one   ``core.mining``
-(``mithril_mine[_batched].py``) launch over (lane,    pairwise oracles (via
-                          row-block) via ``sweep.     ``mine_batched``
-                          _batched_pairwise_fn``      defaults)
+``mithril_pairwise``      mining barrier, one         ``core.mining``
+(``mithril_mine.py``)     launch per flagged lane     pairwise oracle (via
+                          via ``sweep.               ``mine_batched``
+                          _batched_pairwise_fn``      default)
+``mithril_pairwise_       one launch over (lane,      ``core.mining``
+batched`` (``mithril_     row-block); no caller on    ``pairwise_codes_
+mine_batched.py``)        the sweep's path            batched``
 ``prefetch_lookup``       batched pFlag probe         same kernel,
 (``hash_lookup.py``)      (serving layer)             interpreted
 ``paged_decode``          flash-decode over paged     same kernel,

@@ -1,9 +1,11 @@
 """Pallas TPU kernel: MITHRIL pairwise association check over a lanes axis.
 
-Batched sibling of ``mithril_mine`` for the sweep engine's mining barrier
-(DESIGN.md §7): when the batch-level trigger fires, EVERY lane flagged for
-mining runs its (rows x window x S) timestamp comparison in one kernel
-launch instead of a ``fori_loop``-of-``lax.cond`` over lanes.
+Batched sibling of ``mithril_mine``: every lane runs its (rows x window
+x S) timestamp comparison in one kernel launch. The sweep's mining
+barrier no longer calls it: it mines the flagged lanes one at a time
+with the serial kernel, since folding every lane's pairs at once made
+the TPU compiler relayout the lanes' prefetch tables per pair
+(DESIGN.md §7).
 
 Grid layout: ``(lanes, n_row_blocks)``. Each program holds ONE lane's
 whole (padded) timestamp matrix in VMEM — mining tables are small by

@@ -442,28 +442,50 @@ _MINE_CFG = small_cfg(mine_rows=8, lookahead=12)
 _MINE_STEP = jax.jit(functools.partial(record_event, _MINE_CFG))
 
 
-@settings(max_examples=10, deadline=None)
-@given(st.integers(0, 10_000), st.integers(0, 7))
-def test_mine_batched_matches_serial_mine(seed, need_bits):
-    """Per-lane equality: mined lanes == mine(lane), others untouched."""
+def _mining_lanes(n_lanes: int, seed: int) -> list:
+    """Lanes that recorded random blocks, each mined serially whenever
+    its table filled, so each holds a part-full mining table."""
     cfg = _MINE_CFG
     rng = np.random.default_rng(seed)
     lanes = []
-    for lane in range(3):
+    for lane in range(n_lanes):
         stt = init(cfg)
         for blk in rng.integers(0, 30, size=60):
             stt = _MINE_STEP(stt, jnp.int32(blk))
             if int(stt.mine_fill) >= cfg.mine_rows:   # keep the invariant
                 stt = mine(cfg, stt)
         lanes.append(stt)
-    states = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *lanes)
-    need = np.array([bool(need_bits & (1 << i)) for i in range(3)])
+    return lanes
 
+
+def _assert_mine_batched_matches(lanes: list, need: np.ndarray) -> None:
+    """Per-lane equality: mined lanes == mine(lane), others untouched."""
+    cfg = _MINE_CFG
+    states = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *lanes)
     got = mine_batched(cfg, states, jnp.asarray(need))
     for i, lane in enumerate(lanes):
         want = mine(cfg, lane) if need[i] else lane
         got_i = jax.tree_util.tree_map(lambda x: x[i], got)
         assert_trees_equal(got_i, want, f"lane {i} (need={need[i]})")
+
+
+@settings(max_examples=10, deadline=None)
+@given(st.integers(0, 10_000), st.integers(0, 7))
+def test_mine_batched_matches_serial_mine(seed, need_bits):
+    """Per-lane equality: mined lanes == mine(lane), others untouched."""
+    need = np.array([bool(need_bits & (1 << i)) for i in range(3)])
+    _assert_mine_batched_matches(_mining_lanes(3, seed), need)
+
+
+@pytest.mark.parametrize("need", [(1, 1, 1, 1, 1), (0, 1, 0, 1, 0),
+                                  (0, 0, 0, 0, 0)],
+                         ids=["every_lane", "two_apart", "no_lane"])
+def test_mine_batched_matches_serial_mine_at_five_lanes(need):
+    """At 5 lanes: with every lane flagged, two flagged lanes that are
+    not neighbours, or none, each flagged lane equals the serial
+    ``mine`` bit for bit and every other lane is untouched."""
+    _assert_mine_batched_matches(_mining_lanes(5, 2024),
+                                 np.array(need, bool))
 
 
 # ---------------------------------------------------------------------------

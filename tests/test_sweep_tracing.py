@@ -69,7 +69,7 @@ def test_mined_lanes_equal_the_serial_passes_per_volume(stream,
     mp = stream.mine_passes
     solo, fused_lanes, fused = mp.T
     np.testing.assert_array_equal(solo + fused_lanes, passes)
-    assert solo.sum() > 0 and fused_lanes.sum() > 0   # both paths ran
+    assert solo.sum() > 0 and fused_lanes.sum() > 0   # alone and beside others
     assert 0 < 2 * fused.sum() <= fused_lanes.sum()   # >= 2 lanes a pass
     assert stream.streaming_stats()["mining"] == dict(
         zip(MINE_PASS_KEYS, map(int, mp.sum(axis=0))))

@@ -11,9 +11,10 @@ benchmark's table sizes (SUITE_MITHRIL) and at the paper's
 compile the sweep's whole chunk runner with its dispatch steered to
 the kernels, as it is on a TPU: once at the SUITE tables for its named
 scopes, and at the benchmark cells' paper-size tables for the layout of
-the prefetch table inside the scan's step (read-only and write-back),
-and for what the read-only runner keeps from before write-back existed:
-its carry, its slab arguments and its scatters.
+the prefetch table inside the scan's step and inside the mining
+barrier's loops (read-only and write-back), and for what the read-only
+runner keeps from before write-back existed: its carry, its slab
+arguments and its scatters.
 
 The topology is described inside a module fixture, never at import:
 one process at a time may load the TPU library, so only the test
@@ -188,7 +189,7 @@ def _step_body(text: str) -> list:
 
 def test_sweep_runner_compiles_with_kernels(one_chip, monkeypatch):
     """The chunk runner the sweep builds on a TPU (record kernel in the
-    request step, batched mining kernel at the barrier, AMP beside
+    request step, the mining kernel at the barrier, AMP beside
     MITHRIL) compiles, and every part of its step keeps its named scope
     in the op_names XLA gives the loop body's operations."""
     from repro.cache import SimConfig
@@ -234,6 +235,44 @@ def test_sweep_step_keeps_prefetch_table_layout(one_chip, monkeypatch, amp,
     assert not padded, padded
 
 
+def _barrier_loop_bodies(text: str) -> dict:
+    """The body computations of every ``while`` under the ``barrier``
+    scope (the mining barrier's loops) -> their instruction lines."""
+    comps = _computations(text)
+    loops = [re.search(r" while\(.*body=%([\w.\-]+)", ln)
+             for lines in comps.values() for ln in lines
+             if re.search(r'op_name="[^"]*/barrier/', ln)]
+    return {m[1]: comps[m[1]] for m in loops if m}
+
+
+@pytest.mark.parametrize("amp,write_back",
+                         [(False, False), (True, False), (False, True)],
+                         ids=["mithril", "mithril_amp", "mithril_wb"])
+def test_mining_barrier_never_relays_lane_tables(one_chip, monkeypatch, amp,
+                                                 write_back):
+    """At the benchmark cells' tables, no loop of the mining barrier
+    copies a lanes-stacked prefetch table (``pf_key``, ``pf_age`` or
+    ``pf_vals`` of all 16 lanes). A pair fold vmapped over the lanes
+    axis relays all three into other tiles on every folded pair, ~3.8M
+    estimated cycles each; the barrier folds one lane's own tables at a
+    time instead, whose loop holds no such copy."""
+    from repro.cache import SimConfig
+
+    mith = dataclasses.replace(PAPER_MITHRIL, mine_rows=1250)
+    cfg = SimConfig(capacity=512, ways=512, use_mithril=True, use_amp=amp,
+                    mithril=mith, write_back=write_back)
+    tables = "|".join(re.escape("s32[16,{},{}{}]".format(
+        mith.pf_buckets, mith.pf_ways, tail))
+        for tail in ("", f",{mith.prefetch_list}"))
+    copy = re.compile(r"= (?:{})\{{[^}}]*\}} copy\(".format(tables))
+    bodies = _barrier_loop_bodies(_runner_hlo(one_chip, monkeypatch, cfg))
+    assert bodies                  # the lanes loop and each lane's fold
+    relays = [ln.split(", metadata=")[0][:160]
+              for lines in bodies.values() for ln in lines
+              if copy.search(ln.split(", metadata=")[0])]
+    assert not relays, relays
+
+
 # The read-only runner's cache and statistics leaves, as they were before
 # write-back existed; (lanes, buckets, ways) tables and per-lane counters.
 READ_ONLY_LEAVES = {
@@ -249,7 +288,7 @@ READ_ONLY_LEAVES = {
 
 
 @pytest.mark.parametrize("amp,leaves,scatters",
-                         [(False, 32, 29), (True, 38, 108)],
+                         [(False, 32, 25), (True, 38, 104)],
                          ids=["mithril", "mithril_amp"])
 def test_read_only_runner_is_unchanged_by_write_back(one_chip, monkeypatch,
                                                      amp, leaves, scatters):
@@ -258,7 +297,7 @@ def test_read_only_runner_is_unchanged_by_write_back(one_chip, monkeypatch,
     write-back cache existed: no dirty table and no write-back counter in
     the carry, the slabs ``(int32 blocks, bool valid)`` and no third,
     no operation under the ``writeback`` scope, and as many scatter
-    instructions in the v5e program (29 without AMP, 108 with)."""
+    instructions in the v5e program (25 without AMP, 104 with)."""
     from repro.cache import SimConfig
 
     sweep_mod = importlib.import_module("repro.cache.sweep")
