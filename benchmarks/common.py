@@ -14,7 +14,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from repro.cache import SimConfig, SweepPlan, SweepResult
+from repro.cache import SimConfig, SweepResult
 from repro.cache.base import PF_AMP, PF_MITHRIL, PF_PG
 from repro.configs.mithril_paper import SUITE_MITHRIL
 
@@ -71,7 +71,6 @@ def pf_src_of(cfg: SimConfig) -> int:
 # --------------------------------------------------------------------------
 
 _TELEMETRY: List[dict] = []
-_PACKER: List[dict] = []
 _SERVING: List[dict] = []
 _STREAMING: List[dict] = []
 _KERNELS: List[dict] = []
@@ -109,30 +108,6 @@ def record_sweep(job: str, config: str, cfg: SimConfig,
 
 def sweep_telemetry() -> List[dict]:
     return list(_TELEMETRY)
-
-
-def record_packer(job: str, plan: SweepPlan, scale: str,
-                  trace_len: int) -> None:
-    """Log one schedule's packer-efficiency stats for BENCH json.
-
-    The plan depends only on the corpus geometry, so repeated calls for
-    the same (job, trace_len) — e.g. one per fig6 capacity — record
-    exactly once.
-    """
-    if any(p["job"] == job and p["trace_len"] == trace_len
-           for p in _PACKER):
-        return
-    entry = {"job": job, "scale": scale, "trace_len": trace_len,
-             **plan.packer_stats()}
-    _PACKER.append(entry)
-    print(f"  [{job}] packer: shapes={entry['shapes']} "
-          f"groups={entry['n_groups']} waste={entry['waste_ratio']:.4f} "
-          f"(fixed-shape {entry['fixed_waste_ratio']:.4f}, "
-          f"reduction {entry['reduction_vs_fixed']:.4f})")
-
-
-def packer_telemetry() -> List[dict]:
-    return list(_PACKER)
 
 
 def record_serving(job: str, config: str, metrics: Dict) -> None:
@@ -233,7 +208,6 @@ def write_bench_json(meta: dict, jobs: List[dict]) -> str:
     with open(path, "w") as f:
         json.dump({"meta": meta, "jobs": jobs,
                    "sweeps": sweep_telemetry(),
-                   "packer": packer_telemetry(),
                    "serving": serving_telemetry(),
                    "streaming": streaming_telemetry(),
                    "kernels": kernels_telemetry(),

@@ -21,10 +21,6 @@ Policy (make CI *compare* trajectories, not just archive them):
 * sweeps missing from the baseline are reported and skipped (new
   benchmarks seed their own trajectory on the next baseline refresh);
   sweeps missing from the fresh run FAIL (a benchmark silently died);
-* packer efficiency (ISSUE 5): the lane packer's padded-waste ratio is
-  pure arithmetic over the corpus lengths, so with the same geometry
-  and device count any waste-ratio regression vs the baseline is a
-  scheduling-semantics change and FAILS; improvements are noted;
 * measured serving (ISSUE 6): the ``TieredServeEngine`` metrics split
   two ways — virtual-step counters (tokens, turnaround percentiles,
   batch occupancy, the whole tier counter dict) are deterministic
@@ -156,37 +152,6 @@ def compare(fresh: dict, baseline: dict, wallclock_warn: float):
 
     for key in fresh_ix.keys() - base_ix.keys():
         notes.append(f"{key}: not in baseline (new sweep, unchecked)")
-
-    # packer efficiency: deterministic given geometry + device count
-    same_devices = (fresh_meta.get("n_devices") is not None
-                    and fresh_meta.get("n_devices")
-                    == base_meta.get("n_devices"))
-    base_pk = {p["job"]: p for p in
-               _baseline_section(baseline, fresh, "packer", warnings)}
-    for p in fresh.get("packer", []):
-        b = base_pk.get(p["job"])
-        if b is None:
-            notes.append(f"packer {p['job']}: not in baseline "
-                         "(new schedule, unchecked)")
-            continue
-        if not base_ix:     # geometry mismatch cleared the comparison
-            continue
-        if not same_devices or b.get("trace_len") != p.get("trace_len"):
-            notes.append(f"packer {p['job']}: geometry/devices differ, "
-                         "waste ratio not compared")
-            continue
-        if b.get("waste_ratio") is None:
-            warnings.append(f"packer {p['job']}: baseline entry has no "
-                            "'waste_ratio' (older schema) — unchecked")
-        elif p["waste_ratio"] > b["waste_ratio"] + HIT_TOL:
-            failures.append(
-                f"packer {p['job']}: padded-waste ratio regressed "
-                f"{b['waste_ratio']:.6f} -> {p['waste_ratio']:.6f}")
-        elif p["waste_ratio"] < b["waste_ratio"] - HIT_TOL:
-            notes.append(
-                f"packer {p['job']}: padded-waste ratio improved "
-                f"{b['waste_ratio']:.6f} -> {p['waste_ratio']:.6f} "
-                "(baseline refresh will pin it)")
 
     # measured serving: deterministic counters FAIL, wall-clock WARNs
     det_keys = ("requests", "tokens", "steps", "mean_batch_occupancy",

@@ -1,14 +1,13 @@
-"""Corpus-native figure engine: one scheduled sweep per config, shared.
+"""Corpus-native figure engine: one streaming sweep per config, shared.
 
 Every figure driver (``table1_hit_ratio``, ``fig34_trace_sweep``,
 ``fig5_representative``, ``fig6_hrc_precision``, ``fig7_params``,
 ``fig9_midfreq``) and the corpus Table-1 job run through this engine
 instead of private simulation passes: a :class:`CorpusRun` builds the
 corpus registry slice once (traces, per-trace workload families,
-degenerate flags, the packer's :class:`~repro.cache.SweepPlan`) and
-memoizes one ``sweep_scheduled`` result per configuration — so the
-whole figure set costs ONE scheduled sweep per distinct config, however
-many figures read it (DESIGN.md §9).
+degenerate flags) and memoizes one ``sweep_streaming`` result per
+configuration — so the whole figure set costs ONE sweep (and one
+compile) per distinct config, however many figures read it.
 
 Two aggregation schemas come with it:
 
@@ -31,10 +30,10 @@ the suite's full slice.
 ``--corpus-dir`` (or the ``REPRO_CORPUS_DIR`` env var) naming an
 ingested corpus directory (``traces.io.ingest_to_dir``); the engine
 then builds its bundle from :class:`~repro.traces.RealCorpus` instead
-of the synthetic registry — same packer schedule, same scheduler, same
-CSV schemas — and suffixes every BENCH job key with the corpus
-fingerprint so ``benchmarks.compare`` skips cleanly across different
-trace populations.
+of the synthetic registry — same engine, same CSV schemas — and
+suffixes every BENCH job key with the corpus fingerprint so
+``benchmarks.compare`` skips cleanly across different trace
+populations.
 """
 
 from __future__ import annotations
@@ -44,13 +43,13 @@ from typing import Dict, List, Mapping, Optional
 
 import numpy as np
 
-from repro.cache import SimConfig, SweepResult, plan_sweep, sweep_scheduled
+from repro.cache import SimConfig, StreamResult, SweepResult, \
+    sweep_streaming
 from repro.traces import (FAMILIES, SCALES, RealCorpus, build_corpus,
                           corpus_specs, family_of, resolve_corpus_dir)
 from repro.traces.synthetic import stack_padded
 
-from .common import (CAPACITY, configs, job_tag, record_packer,
-                     record_sweep, write_csv)
+from .common import CAPACITY, configs, job_tag, record_sweep, write_csv
 
 # nominal per-trace request counts per suite (same geometry the
 # benchmark harness pins in run.py / compare.py baselines)
@@ -61,10 +60,10 @@ ELIGIBLE_MIN_HR = 0.01      # LRU baseline below this -> relative gain
 
 
 class CorpusRun:
-    """One corpus slice + the memoized scheduled sweeps over it.
+    """One corpus slice + the memoized streaming sweeps over it.
 
     ``result(cname)`` sweeps a registry config (``benchmarks.common
-    .configs``) through the packer schedule and memoizes by config, so
+    .configs``) through ``sweep_streaming`` and memoizes by config, so
     every figure reading the same config shares one pass;
     ``extra_result(cfg, cname, job)`` does the same for figure-specific
     configs (fig6 capacities, fig7 parameter grid) — equal configs
@@ -79,14 +78,11 @@ class CorpusRun:
         self.capacity = capacity
         self.corpus_dir = resolve_corpus_dir(corpus_dir)
         (self.names, self.blocks, self.lengths, self.families,
-         self.degenerate, self.plan,
-         self.fingerprint) = _corpus_bundle(scale, self.trace_len,
-                                            self.corpus_dir)
+         self.degenerate, self.fingerprint) = _corpus_bundle(
+             scale, self.trace_len, self.corpus_dir)
         self.job = self.job_name(f"corpus_figures_{scale}")
-        record_packer(self.job_name(f"corpus_{scale}"), self.plan,
-                      scale, self.trace_len)
         self._configs = configs(capacity)
-        self._results: Dict[SimConfig, SweepResult] = {}
+        self._streams: Dict[SimConfig, StreamResult] = {}
         self._recorded: set = set()
 
     @property
@@ -106,11 +102,16 @@ class CorpusRun:
     def config(self, cname: str) -> SimConfig:
         return self._configs[cname]
 
-    def _sweep(self, cfg: SimConfig) -> SweepResult:
-        if cfg not in self._results:
-            self._results[cfg] = sweep_scheduled(
-                cfg, self.blocks, self.lengths, plan=self.plan)
-        return self._results[cfg]
+    def _stream(self, cfg: SimConfig) -> StreamResult:
+        if cfg not in self._streams:
+            self._streams[cfg] = sweep_streaming(cfg, self.blocks,
+                                                 self.lengths)
+        return self._streams[cfg]
+
+    def stream(self, cname: str) -> StreamResult:
+        """A registry config's memoized sweep with its schedule
+        telemetry (``StreamResult.streaming_stats``)."""
+        return self._stream(self.config(cname))
 
     def result(self, cname: str) -> SweepResult:
         """Memoized sweep of a registry config, recorded once under the
@@ -118,7 +119,7 @@ class CorpusRun:
         which figure asks first — even when a figure-specific
         ``extra_result`` with an equal config swept it earlier)."""
         cfg = self.config(cname)
-        res = self._sweep(cfg)
+        res = self._stream(cfg).result
         if (self.job, cname) not in self._recorded:
             self._recorded.add((self.job, cname))
             record_sweep(self.job, cname, cfg, res)
@@ -134,7 +135,7 @@ class CorpusRun:
                      job: str) -> SweepResult:
         """Sweep a figure-specific config; memoized by the config value,
         telemetry recorded once per (job, cname)."""
-        res = self._sweep(cfg)
+        res = self._stream(cfg).result
         if (job, cname) not in self._recorded:
             self._recorded.add((job, cname))
             record_sweep(job, cname, cfg, res)
@@ -147,7 +148,7 @@ _BUNDLES: Dict[tuple, tuple] = {}
 
 def _corpus_bundle(scale: str, trace_len: int,
                    corpus_dir: Optional[str] = None) -> tuple:
-    """Traces/metadata/plan per (scale, trace_len, corpus) —
+    """Traces/metadata per (scale, trace_len, corpus) —
     capacity-agnostic, so the fig6 capacity grid shares one corpus
     slice instead of rebuilding it per capacity.
 
@@ -171,8 +172,7 @@ def _corpus_bundle(scale: str, trace_len: int,
             families = np.array([family_of(n) for n in names])
             fingerprint = None
         _BUNDLES[key] = (names, blocks, lengths, families,
-                         np.asarray(lengths) <= 1,
-                         plan_sweep(lengths), fingerprint)
+                         np.asarray(lengths) <= 1, fingerprint)
     return _BUNDLES[key]
 
 

@@ -2,14 +2,14 @@
 
 The paper's headline numbers (55% avg hit-ratio gain over LRU, 36% over
 AMP) are averages over 135 block-storage traces. This job sweeps the
-corpus registry through the scheduled figure engine
-(``benchmarks.corpus_figures`` -> ``cache.sweep.sweep_scheduled``): the
-cost-model packer buckets traces into variable-width lane groups, the
-lane axis shards over local devices, and the whole corpus costs at most
-two compiles per config — shared with every figure driver reading the
-same configs. Emits the per-trace CSV (family + degenerate flags — a
-len<=1 trace is surfaced, never silently dropped), the improvement
-summary, the per-family breakdown, and the packer-efficiency stats.
+corpus registry through the figure engine (``benchmarks.corpus_figures``
+-> ``cache.sweep.sweep_streaming``): traces stream through a pool of
+recycled lanes, the lane axis shards over local devices, and the whole
+corpus costs one compile per config — shared with every figure driver
+reading the same configs. Emits the per-trace CSV (family + degenerate
+flags — a len<=1 trace is surfaced, never silently dropped), the
+improvement summary and the per-family breakdown, and prints the
+schedule's lane waste.
 
     PYTHONPATH=src python -m benchmarks.corpus_sweep --scale quick
 
@@ -32,9 +32,7 @@ def main(scale: str = "quick", trace_len: int | None = None,
     job = run.job_name(f"corpus_{scale}")
     n_degenerate = int(run.degenerate.sum())
     print(f"  [{job}] {run.n_traces} traces (len {run.lengths.min()}..."
-          f"{run.lengths.max()}), {len(run.plan.groups)} groups, "
-          f"shapes={['x'.join(map(str, s)) for s in run.plan.shapes]}, "
-          f"shards={run.plan.n_shards}")
+          f"{run.lengths.max()})")
     if n_degenerate:
         print(f"  [{job}] {n_degenerate} degenerate trace(s) (len<=1) "
               "surfaced via the degenerate column, not dropped")
@@ -52,17 +50,15 @@ def main(scale: str = "quick", trace_len: int | None = None,
               improvement_summary(hrs, run.degenerate))
     write_family_csv(f"corpus_{scale}_by_family.csv", run.families, hrs)
 
-    st = run.plan.packer_stats()
-    write_csv(f"corpus_{scale}_packer.csv",
-              ",".join(st), [[st[k] if not isinstance(st[k], list)
-                              else " ".join(map(str, st[k]))
-                              for k in st]])
+    # the schedule depends only on the lengths, so any config's will do
+    st = run.stream(NAMES[0]).streaming_stats()
+    print(f"  [{job}] {st['lane_width']} lanes x chunk {st['chunk']}, "
+          f"{st['n_slabs']} slabs, lane waste {st['waste_ratio']}")
 
     worst = max(max(results[c].compiles, 0) for c in NAMES)
     return (f"traces={run.n_traces};max_compiles={worst};"
             f"degenerate={n_degenerate};"
-            f"packer_waste={st['waste_ratio']};"
-            f"packer_reduction={st['reduction_vs_fixed']}")
+            f"lane_waste={st['waste_ratio']}")
 
 
 def _parser():

@@ -118,12 +118,11 @@ def bench_mine(rows, roofs, rng):
         vmem, comp = mine_accounting(n, s, window)
         shape = f"n={n},w={window}"
         rows.append(["mithril_mine", shape, ok, f"{t_k*1e6:.0f}", vmem, comp])
-        rl = analyze_kernel("mithril_mine_batched",
-                            dict(lanes=1, mine_rows=n, s_sup=s,
-                                 window=window))
+        rl = analyze_kernel("mithril_mine",
+                            dict(mine_rows=n, s_sup=s, window=window))
         rl.geometry_label = shape
         roofs.append(rl)
-        record_kernel("mithril_mine_batched", shape, ok, rl.to_dict(),
+        record_kernel("mithril_mine", shape, ok, rl.to_dict(),
                       wallclock_us=t_k * 1e6)
         print(f"mine n={n} w={window}: match={ok} vmem={vmem/1024:.0f}KB "
               f"compares={comp/1e6:.1f}M interp={t_k*1e3:.1f}ms")

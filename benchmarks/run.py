@@ -14,7 +14,7 @@ paper-scale 135-trace corpus. ``--quick`` stays as an alias for
 Prints ``name,seconds,derived`` CSV summary lines, writes detailed CSVs
 to results/bench/, and emits ``results/bench/BENCH_sweep.json`` — the
 machine-readable perf trajectory (per-config hit ratios, precision,
-wall-clock, compile counts, packer efficiency) that CI archives so
+wall-clock, compile counts) that CI archives so
 future PRs can compare against it. (The multi-pod dry-run + roofline
 table have their own entry points: repro.launch.dryrun and
 benchmarks.roofline_table — they need the 512-device XLA flag set

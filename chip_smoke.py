@@ -5,7 +5,8 @@
     python3 chip_smoke.py --four-chips   # four chips: the sharded sweep only
 
 (a) ``mithril-lru`` (SUITE_MITHRIL tables, capacity 512) over the mid
-    corpus (64 traces x 20,000 requests) through ``sweep_scheduled``.
+    corpus (64 traces x 20,000 requests) through ``sweep_streaming``'s
+    16 recycled lanes.
     The per-trace hit ratios must equal the ``corpus_figures_mid``
     entry of ``results/bench/BENCH_baseline_mid.json`` exactly, and the
     chunk runner must carry the compiled record and mining kernels.
@@ -144,26 +145,19 @@ def assert_same(a, b, rows_a, rows_b, what: str) -> None:
 
 def phase_mid() -> str:
     import numpy as np
-    from repro.cache import SimConfig, plan_sweep, sweep_scheduled
-    from repro.cache.sweep import sweep_streaming
+    from repro.cache import SimConfig
+    from repro.cache.sweep import DEFAULT_LANE_WIDTH
     from repro.configs.mithril_paper import SUITE_MITHRIL
     from repro.traces import corpus_suite
 
     cfg = SimConfig(capacity=512, use_mithril=True, mithril=SUITE_MITHRIL)
     _, blocks, lengths = corpus_suite("mid", 20_000)
-    plan = plan_sweep(lengths)
-    kernels = kernels_in_runner(cfg, *plan.shapes[-1])
+    lanes = DEFAULT_LANE_WIDTH
+    kernels = kernels_in_runner(cfg, CHUNK, lanes)
     if not {"mithril_record", "mithril_mine"} <= set(kernels):
         raise AssertionError(f"chunk runner kernels: {kernels}")
-    t0 = time.perf_counter()
-    for chunk, width in plan.shapes:
-        sweep_streaming(cfg, np.ones((width, chunk), np.int32),
-                        lane_width=width, chunk=chunk)
-    t1 = time.perf_counter()
-    res = sweep_scheduled(cfg, blocks, lengths, plan=plan)
-    t2 = time.perf_counter()
-    if res.compiles != 0:
-        raise AssertionError(f"{res.compiles} compiles in the timed sweep")
+    out, compile_s, steady_s = stream(cfg, blocks, lengths, lanes)
+    res = out.result
     want = next(s for s in json.loads(MID_BASELINE.read_text())["sweeps"]
                 if s["job"] == "corpus_figures_mid"
                 and s["config"] == cfg.label())["hit_ratios"]
@@ -175,8 +169,8 @@ def phase_mid() -> str:
     requests = int(np.asarray(res.stats.requests).sum())
     return (f"traces={len(got)} requests={requests} "
             f"hits={int(np.asarray(res.stats.hits).sum())} "
-            f"shapes={list(plan.shapes)} kernels={kernels} "
-            f"compile_s={t1 - t0:.3f} steady_s={t2 - t1:.3f} "
+            f"lanes={out.lane_width} slabs={out.n_slabs} kernels={kernels} "
+            f"compile_s={compile_s:.3f} steady_s={steady_s:.3f} "
             f"baseline_equal=True")
 
 

@@ -7,10 +7,9 @@ from .amp import AmpConfig, AmpState, amp_access, init_amp
 from .pg import PgConfig, PgState, init_pg, pg_access
 from .simulator import (SimConfig, SimResult, SimSession, Stats,
                         build_segments, build_step, max_hit_ratio, simulate)
-from .sweep import (LaneGroup, PaddedSuite, RingBuffer, StreamResult,
-                    SweepPlan, SweepResult, build_batched_step,
-                    compile_count, pad_traces, plan_sweep, sweep,
-                    sweep_grid, sweep_scheduled, sweep_streaming)
+from .sweep import (PaddedSuite, RingBuffer, StreamResult, SweepResult,
+                    build_batched_step, compile_count, pad_traces, sweep,
+                    sweep_streaming)
 
 __all__ = [
     "CacheState", "Evicted", "access", "contains", "init_cache",
@@ -19,8 +18,7 @@ __all__ = [
     "PgConfig", "PgState", "init_pg", "pg_access",
     "SimConfig", "SimResult", "SimSession", "Stats", "build_segments",
     "build_step", "max_hit_ratio", "simulate",
-    "LaneGroup", "PaddedSuite", "RingBuffer", "StreamResult", "SweepPlan",
-    "SweepResult", "build_batched_step", "compile_count", "pad_traces",
-    "plan_sweep", "sweep", "sweep_grid", "sweep_scheduled",
+    "PaddedSuite", "RingBuffer", "StreamResult", "SweepResult",
+    "build_batched_step", "compile_count", "pad_traces", "sweep",
     "sweep_streaming",
 ]

@@ -1,24 +1,25 @@
-"""Batched trace-sweep scheduler: corpus in, a handful of compiles out.
+"""Batched trace-sweep engine: corpus in, one compile per config out.
 
 The serial ``simulate`` compiles one ``lax.scan`` per (trace, config)
 pair, so sweeping a benchmark suite is compile-bound long before it is
-compute-bound. This module instead
+compute-bound. Its one engine, ``sweep_streaming`` (DESIGN.md §6, §10;
+``sweep`` is its ``(B, T)`` convenience),
 
-* pads a suite of traces to a common length (``pad_traces`` /
-  ``repro.traces.padded_suite``),
-* ``vmap``s the per-request step over the trace axis (requests at the
-  same position of every trace advance together),
+* takes a suite as a mapping or sequence of traces, a
+  :class:`PaddedSuite` or a zero-padded ``(B, T)`` block array with
+  ``lengths``, normalised and checked once (``_suite_arrays``),
+* ``vmap``s the per-request step over a pool of lanes, each holding one
+  trace at a time (requests at the same slab row of every lane advance
+  together),
 * scans over fixed-size time *chunks* so peak memory is bounded by
-  ``chunk * n_traces`` and arbitrarily long traces stream through the
+  ``chunk * lane_width`` and arbitrarily long traces stream through the
   same compiled executable,
-* gates padded tails per trace so statistics are bit-identical to the
-  per-trace ``simulate`` (``tests/test_sweep.py`` asserts this),
-* **schedules** corpus-scale suites (``plan_sweep``/``sweep_scheduled``,
-  DESIGN.md §8–§9): the cost-model lane packer sorts traces by length
-  and packs them into variable-width *lane groups* drawn from a bounded
-  width set — every group runs through one of at most ``max_shapes``
-  compiled ``(chunk, width)`` executables (default 2), so a 135-trace
-  corpus costs one or two compiles per config — and
+* **recycles** a lane whose trace drains: the next queued trace takes
+  it at the following slab after a masked reset, so every slab has one
+  ``(chunk, lane_width)`` shape and a corpus of any lengths costs one
+  compile per config,
+* gates padded rows per lane so statistics are bit-identical to the
+  per-trace ``simulate`` (``tests/test_sweep.py`` asserts this), and
 * **shards** the lane axis across local devices
   (``dist.sharding.lane_specs`` + ``shard_map``): lanes are independent,
   so each device simulates its slice of the batch and per-lane results
@@ -66,8 +67,8 @@ from .simulator import (SimConfig, SimResult, Stats, build_segments,
                         write_flags)
 
 DEFAULT_CHUNK = 4096
-DEFAULT_LANE_WIDTH = 16     # lanes per scheduled group (rounded to devices)
-LANE_AXIS = "lanes"         # mesh axis the scheduler shards lanes over
+DEFAULT_LANE_WIDTH = 16     # lanes in the streaming engine's pool
+LANE_AXIS = "lanes"         # mesh axis the engine shards lanes over
 
 
 class PaddedSuite(NamedTuple):
@@ -92,6 +93,36 @@ def pad_traces(traces: Union[Mapping[str, np.ndarray],
     for i, a in enumerate(arrs):
         blocks[i, : len(a)] = a
     return PaddedSuite(names, blocks, lengths)
+
+
+def _suite_arrays(traces, lengths: Optional[np.ndarray]
+                  ) -> Tuple[np.ndarray, np.ndarray]:
+    """A sweep's input as ``(blocks (B, T) int32, lengths (B,) int64)``.
+
+    ``traces`` is a mapping or sequence of unequal-length traces, a
+    :class:`PaddedSuite`, or a ``(B, T)`` block array; only the array
+    takes ``lengths`` (default: full T), because a suite carries its
+    own. Raises ``ValueError`` on a conflicting ``lengths``, a block
+    array that is not 2-D, or a length outside ``[0, T]``.
+    """
+    if not isinstance(traces, np.ndarray):
+        if lengths is not None:
+            raise ValueError("pass lengths only with a (B, T) block array"
+                             " — suites already carry per-trace lengths")
+        if not isinstance(traces, PaddedSuite):
+            traces = pad_traces(traces)
+        blocks, lengths = traces.blocks, traces.lengths
+    else:
+        blocks = np.asarray(traces, np.int32)
+    if blocks.ndim != 2:
+        raise ValueError(f"traces must stack to (B, T), got {blocks.shape}")
+    n, t_max = blocks.shape
+    lengths = (np.full((n,), t_max, np.int64) if lengths is None
+               else np.asarray(lengths, np.int64))
+    if lengths.shape != (n,) or (lengths > t_max).any() \
+            or (lengths < 0).any():
+        raise ValueError("lengths must be (B,) within [0, trace axis]")
+    return blocks, lengths
 
 
 def _batched_pairwise_fn():
@@ -329,12 +360,6 @@ def _runner(cfg: SimConfig, unroll: int, n_shards: int = 1):
     return init_batched, run_chunk, place
 
 
-def _refuse_write_back(cfg: SimConfig, entry: str) -> None:
-    if cfg.write_back:
-        raise ValueError(f"{entry} runs read-only caches; a write-back "
-                         "configuration runs through sweep_streaming")
-
-
 def compile_count(cfg: SimConfig, unroll: int = 1, n_shards: int = 1) -> int:
     """Compiled-executable count for ``cfg``'s chunk runner.
 
@@ -382,409 +407,28 @@ def sweep(cfg: SimConfig, blocks: np.ndarray,
           shard: Optional[bool] = None) -> SweepResult:
     """Run a (B, T) padded trace batch through one configuration.
 
-    This is the OFFLINE SPECIAL CASE of the streaming ingestion engine
-    (:func:`sweep_streaming`, DESIGN.md §10): every trace is submitted
-    at virtual step 0 on its own lane (``lane_width = B``), so the
-    scheduler admits the whole batch into the first slab, no lane ever
-    recycles, and the staged slabs are exactly the ``(chunk, B)``
-    transposes of the padded block matrix — the same compiled
-    executable, carry evolution and results as the pre-streaming
-    chunk loop, bit for bit.
+    The offline special case of :func:`sweep_streaming` (DESIGN.md
+    §10): every trace is submitted at virtual step 0 on its own lane
+    (``lane_width = B``), so the whole batch is admitted into the first
+    slab and no lane ever recycles.
 
     ``lengths`` gives each trace's valid prefix (default: full T).
     Requests past a trace's length are bit-exact no-ops excluded from
-    all statistics (source-gated, DESIGN.md §6). Time is padded up to a
-    chunk multiple so every chunk has the same shape — one compilation
-    serves the whole stream. Results are bit-identical to running each
-    trace through ``simulate`` serially; the record/maybe_mine contract
-    (``core.mithril``) is honored internally via the batch-level mining
-    barriers of ``build_batched_step`` — callers never interleave their
-    own recording with a sweep's.
-
+    all statistics (source-gated, DESIGN.md §6). Results are
+    bit-identical to running each trace through ``simulate`` serially.
     ``shard`` selects the device layout: ``None``/``True`` shard the
     lane axis over all local devices whenever the batch width divides
     (per-lane results stay bit-identical — lanes are independent);
     ``False`` forces the single-device runner. A write-back
-    configuration is refused: it runs through :func:`sweep_streaming`.
+    configuration is refused: its write flags go through
+    :func:`sweep_streaming`'s ``writes``.
     """
-    _refuse_write_back(cfg, "sweep")
-    t0 = time.time()
-    blocks = np.ascontiguousarray(np.asarray(blocks, np.int32))
-    if blocks.ndim != 2:
-        raise ValueError(f"blocks must be (B, T), got {blocks.shape}")
-    n_traces, n_req = blocks.shape
-    lengths = (np.full((n_traces,), n_req, np.int64) if lengths is None
-               else np.asarray(lengths, np.int64))
-    if lengths.shape != (n_traces,) or (lengths > n_req).any() \
-            or (lengths < 0).any():
-        raise ValueError("lengths must be (B,) within [0, trace axis]")
-
-    stream = sweep_streaming(cfg, blocks, lengths=lengths,
-                             lane_width=n_traces, chunk=chunk,
-                             unroll=unroll, shard=shard)
-    res = stream.result
-    return SweepResult(stats=res.stats, hit_curve=res.hit_curve,
-                       lengths=lengths, compiles=res.compiles,
-                       seconds=time.time() - t0)
-
-
-# ---------------------------------------------------------------------------
-# Corpus-scale scheduler: cost-model lane packer, bounded compile shapes
-# ---------------------------------------------------------------------------
-
-DEFAULT_MAX_SHAPES = 2      # distinct lane widths (= compiled slab shapes)
-# Per-group serial-dispatch cost in lane-equivalents. Any positive value
-# stops the pure padded-steps objective from shredding the corpus into
-# width-1 groups (grouping equal-padded traces then always wins); the
-# default is deliberately small because a chunk launch costs far less
-# than one lane of chunk compute — raise it on hardware where narrow
-# lanes underfill the vector unit (DESIGN.md §9).
-DEFAULT_PACK_OVERHEAD = 0.25
-
-
-class LaneGroup(NamedTuple):
-    indices: Tuple[int, ...]    # original trace positions in this group
-    padded_t: int               # group time axis (a chunk multiple)
-    lane_width: int             # lanes this group pads to
-    chunk: int                  # time-axis chunk of this group's slabs
-
-
-class SweepPlan(NamedTuple):
-    """Device-and-shape schedule for a heterogeneous trace corpus.
-
-    Groups are consecutive runs of the length-sorted corpus (longest
-    first), each running through a ``(chunk, width)`` slab shape drawn
-    from at most ``max_shapes`` distinct shapes — one compiled
-    executable per shape. Both axes are free per group: a short-trace
-    group may take a *narrower lane width* AND a *finer time chunk*
-    than the primary shape (the second-chunk freedom of DESIGN.md §9),
-    so chunk granularity no longer floors the padded tail on short
-    corpora. Widths are always multiples of ``n_shards`` so the lane
-    axis divides the device mesh; chunks are halvings of the base
-    chunk. ``lane_width``/``chunk`` are the widest group's shape (the
-    primary slab).
-    """
-
-    groups: Tuple[LaneGroup, ...]
-    lane_width: int             # max group width (primary compiled shape)
-    chunk: int                  # base (primary) time chunk
-    n_shards: int
-    total_requests: int         # sum of valid per-trace lengths
-    fixed_lane_steps: int       # padded_lane_steps of the fixed-shape plan
-
-    @property
-    def padded_lane_steps(self) -> int:
-        """Total (lane x request) slots the schedule executes."""
-        return sum(g.padded_t * g.lane_width for g in self.groups)
-
-    @property
-    def shape_widths(self) -> Tuple[int, ...]:
-        """Distinct lane widths across the compiled slab shapes."""
-        return tuple(sorted({g.lane_width for g in self.groups}))
-
-    @property
-    def shapes(self) -> Tuple[Tuple[int, int], ...]:
-        """Distinct compiled ``(chunk, width)`` slab shapes."""
-        return tuple(sorted({(g.chunk, g.lane_width) for g in self.groups}))
-
-    @property
-    def waste_ratio(self) -> float:
-        """Fraction of executed lane-steps that are padded-tail waste."""
-        steps = self.padded_lane_steps
-        return 1.0 - self.total_requests / steps if steps else 0.0
-
-    @property
-    def fixed_waste_ratio(self) -> float:
-        """Waste ratio of the fixed-shape reference plan (same inputs)."""
-        if not self.fixed_lane_steps:
-            return 0.0
-        return 1.0 - self.total_requests / self.fixed_lane_steps
-
-    def packer_stats(self) -> Dict[str, object]:
-        """Packer-efficiency summary recorded in BENCH json."""
-        return {
-            "n_traces": sum(len(g.indices) for g in self.groups),
-            "n_groups": len(self.groups),
-            "widths": list(self.shape_widths),
-            "shapes": [f"{c}x{w}" for c, w in self.shapes],
-            "n_shapes": len(self.shapes),
-            "chunk": self.chunk,
-            "n_shards": self.n_shards,
-            "padded_lane_steps": int(self.padded_lane_steps),
-            "ideal_lane_steps": int(self.total_requests),
-            "waste_ratio": round(self.waste_ratio, 6),
-            "fixed_padded_lane_steps": int(self.fixed_lane_steps),
-            "fixed_waste_ratio": round(self.fixed_waste_ratio, 6),
-            "reduction_vs_fixed": round(
-                1.0 - (self.padded_lane_steps / self.fixed_lane_steps
-                       if self.fixed_lane_steps else 1.0), 6),
-        }
-
-
-def _width_candidates(w_max: int, n_shards: int) -> Tuple[int, ...]:
-    """Packer width ladder: ``w_max`` and its successive halvings, each
-    rounded up to a multiple of ``n_shards`` (the §4 divisibility
-    contract applied to the lane axis), deduplicated, ascending."""
-    cands = set()
-    w = w_max
-    while w >= 1:
-        cands.add(-(-w // n_shards) * n_shards)
-        if w == 1:
-            break
-        w //= 2
-    return tuple(sorted(cands))
-
-
-# Chunk-ladder depth: the base chunk plus up to this many halvings are
-# shape candidates. Three halvings reach chunk/8 — finer granularity
-# stops mattering once the per-trace remainder is < 1/8 of a chunk,
-# while the candidate-shape count (widths x chunks) stays small enough
-# to enumerate shape subsets exhaustively.
-_CHUNK_LADDER = 3
-
-
-def _chunk_candidates(base: int) -> Tuple[int, ...]:
-    """Time-axis chunk ladder: the base chunk and its halvings
-    (``_CHUNK_LADDER`` deep, floored at 1), deduplicated, ascending."""
-    cands = set()
-    c = base
-    for _ in range(_CHUNK_LADDER + 1):
-        cands.add(max(1, c))
-        c //= 2
-    return tuple(sorted(cands))
-
-
-def _padded_len(length: int, chunk: int) -> int:
-    return -(-max(1, int(length)) // chunk) * chunk
-
-
-def _pack(lengths: Sequence[int], shapes: Sequence[Tuple[int, int]],
-          overhead: float) -> Tuple[float, Tuple[Tuple[int, int], ...]]:
-    """Optimal consecutive partition of the length-sorted corpus.
-
-    ``lengths[i]`` is trace ``i``'s raw length, sorted descending, so a
-    group covering positions ``[i, i+w)`` pads its time axis to position
-    ``i``'s length rounded up to the group's chunk. ``shapes`` are the
-    candidate ``(width, chunk)`` slab shapes. Minimizes
-
-        sum_g padded_t_g * (w_g + overhead)
-
-    — the schedule's padded lane-steps plus a per-group serial-dispatch
-    term (``overhead`` lane-equivalents) that keeps the otherwise
-    degenerate width-1 optimum from shredding the corpus into
-    per-trace groups. Returns (cost, per-group (width, chunk) in order).
-    """
-    n = len(lengths)
-    cost = [0.0] * (n + 1)
-    choice: list = [None] * n
-    for i in range(n - 1, -1, -1):
-        best, best_s = None, shapes[0]
-        for w, ck in shapes:
-            c = _padded_len(lengths[i], ck) * (w + overhead) \
-                + cost[min(n, i + w)]
-            if best is None or c < best:
-                best, best_s = c, (w, ck)
-        cost[i], choice[i] = best, best_s
-    group_shapes = []
-    i = 0
-    while i < n:
-        group_shapes.append(choice[i])
-        i += choice[i][0]
-    return cost[0], tuple(group_shapes)
-
-
-def plan_sweep(lengths, lane_width: Optional[int] = None,
-               chunk: int = DEFAULT_CHUNK,
-               n_shards: Optional[int] = None,
-               max_shapes: int = DEFAULT_MAX_SHAPES,
-               overhead_lanes: float = DEFAULT_PACK_OVERHEAD) -> SweepPlan:
-    """Pack traces into lane groups with a cost-model packer (§9).
-
-    Traces sort longest-first; groups are consecutive runs of that
-    order, so a group's time axis pads to its FIRST member's length
-    rounded up to the *group's* chunk. The packer chooses per-group
-    ``(width, chunk)`` slab shapes from the candidate ladders — widths
-    are ``lane_width`` (default ``min(n, DEFAULT_LANE_WIDTH)``) and its
-    halvings rounded up to ``n_shards`` multiples; chunks are the base
-    chunk and its halvings — to minimize total padded lane-steps plus
-    an ``overhead_lanes`` serial-dispatch term per group, subject to
-    the compile budget: at most ``max_shapes`` DISTINCT ``(chunk,
-    width)`` shapes, because every distinct slab shape is one more
-    executable. A short-trace group may therefore take a finer time
-    chunk than the primary shape (not just a narrower width), which
-    recovers the chunk-floor waste on short corpora. Plans are
-    guaranteed never worse than the fixed-shape reference (single
-    shape ``(lane_width, chunk)``) in padded lane-steps — when the
-    cost-model pick loses on pure padded waste it falls back to the
-    reference (``fixed_lane_steps`` records the reference either way).
-
-    ``n_shards=None`` reads the local device count; pass 1 to plan a
-    single-device schedule. The effective base chunk is capped at the
-    longest trace (padded up), so each group's scan reuses its shape's
-    ``(chunk, width)`` slab.
-    """
-    lengths = np.asarray(lengths, np.int64)
-    n = len(lengths)
-    if n == 0:
-        raise ValueError("plan_sweep needs at least one trace")
-    if max_shapes < 1:
-        raise ValueError("max_shapes must be >= 1")
-    if n_shards is None:
-        n_shards = max(1, jax.local_device_count())
-    w_max = min(n, DEFAULT_LANE_WIDTH) if lane_width is None \
-        else max(1, lane_width)
-    w_max = -(-w_max // n_shards) * n_shards
-    eff_chunk = max(1, min(chunk, int(lengths.max())))
-    order = np.argsort(-lengths, kind="stable")   # longest first
-    sorted_lens = [int(lengths[i]) for i in order]
-
-    def steps_of(group_shapes: Sequence[Tuple[int, int]]) -> int:
-        total, i = 0, 0
-        for w, ck in group_shapes:
-            total += _padded_len(sorted_lens[i], ck) * w
-            i += w
-        return total
-
-    # fixed-shape reference: the single-shape plan at (w_max, eff_chunk)
-    _, fixed_shapes = _pack(sorted_lens, ((w_max, eff_chunk),),
-                            overhead_lanes)
-    fixed_steps = steps_of(fixed_shapes)
-
-    # shape subsets within the compile budget, simplest-first: every
-    # single shape, then pairs, ... — ties keep the earlier (simpler)
-    # plan, so the search is deterministic. Candidate shapes are the
-    # width ladder x chunk ladder, ordered coarse-to-fine.
-    from itertools import combinations
-    cands = [(w, ck)
-             for w in reversed(_width_candidates(w_max, n_shards))
-             for ck in reversed(_chunk_candidates(eff_chunk))]
-    best_cost, best_shapes = None, fixed_shapes
-    for size in range(1, min(max_shapes, len(cands)) + 1):
-        for subset in combinations(cands, size):
-            cost, shapes = _pack(sorted_lens, subset, overhead_lanes)
-            if best_cost is None or cost < best_cost:
-                best_cost, best_shapes = cost, shapes
-
-    # never-worse guard: the packer must not trade padded waste for
-    # dispatch savings relative to the documented fixed-shape reference
-    if steps_of(best_shapes) > fixed_steps:
-        best_shapes = fixed_shapes
-
-    groups, i = [], 0
-    for w, ck in best_shapes:
-        idx = order[i: i + w]
-        groups.append(LaneGroup(tuple(int(j) for j in idx),
-                                _padded_len(sorted_lens[i], ck),
-                                int(w), int(ck)))
-        i += w
-    return SweepPlan(tuple(groups),
-                     max(g.lane_width for g in groups),
-                     eff_chunk, n_shards,
-                     int(lengths.sum()), int(fixed_steps))
-
-
-def sweep_scheduled(cfg: SimConfig,
-                    traces: Union[Mapping[str, np.ndarray],
-                                  Sequence[np.ndarray], PaddedSuite,
-                                  np.ndarray],
-                    lengths: Optional[np.ndarray] = None,
-                    lane_width: Optional[int] = None,
-                    chunk: int = DEFAULT_CHUNK, unroll: int = 1,
-                    shard: Optional[bool] = None,
-                    plan: Optional[SweepPlan] = None) -> SweepResult:
-    """Sweep an arbitrary-size trace corpus through one configuration.
-
-    Accepts a dict/sequence of unequal-length traces, a
-    :class:`PaddedSuite`, or a ``(B, T)`` block array with ``lengths``.
-    The corpus is scheduled with :func:`plan_sweep` (the cost-model lane
-    packer, §9), each group runs through :func:`sweep` — sharded over
-    local devices when possible — and per-trace results are reassembled
-    in the ORIGINAL trace order. Statistics are bit-identical to
-    sweeping (or serially simulating) each trace alone; the whole corpus
-    costs at most ``max_shapes`` compiles per config because groups draw
-    their ``(chunk, width)`` slab geometry from the packer's bounded
-    shape set. Groups holding fewer traces than their lane width are
-    padded with empty (length-0) lanes, which are bit-exact no-ops under
-    the §6 masking contract. A write-back configuration is refused.
-    """
-    _refuse_write_back(cfg, "sweep_scheduled")
-    t0 = time.time()
-    if not isinstance(traces, np.ndarray):
-        # suite-like inputs carry their own lengths; a conflicting
-        # explicit lengths argument would be silently wrong either way
-        if lengths is not None:
-            raise ValueError("pass lengths only with a (B, T) block array"
-                             " — suites already carry per-trace lengths")
-        if not isinstance(traces, PaddedSuite):
-            traces = pad_traces(traces)
-        blocks, lengths = traces.blocks, traces.lengths
-    else:
-        blocks = np.asarray(traces, np.int32)
-    if blocks.ndim != 2:
-        raise ValueError(f"traces must stack to (B, T), got {blocks.shape}")
-    n, t_max = blocks.shape
-    lengths = (np.full((n,), t_max, np.int64) if lengths is None
-               else np.asarray(lengths, np.int64))
-    if lengths.shape != (n,) or (lengths > t_max).any() \
-            or (lengths < 0).any():
-        raise ValueError("lengths must be (B,) within [0, trace axis]")
-
-    if plan is None:
-        plan = plan_sweep(lengths, lane_width, chunk,
-                          n_shards=1 if shard is False else None)
-
-    stats_out = None
-    hit = np.zeros((n, t_max), bool)
-    compiles = 0
-    for g in plan.groups:
-        gb = np.zeros((g.lane_width, g.padded_t), np.int32)
-        gl = np.zeros((g.lane_width,), np.int64)
-        for j, idx in enumerate(g.indices):
-            ln = int(lengths[idx])
-            gb[j, :ln] = blocks[idx, :ln]
-            gl[j] = ln
-        res = sweep(cfg, gb, gl, chunk=g.chunk, unroll=unroll,
-                    shard=shard)
-        compiles += res.compiles
-        leaves, treedef = jax.tree.flatten(res.stats)
-        if stats_out is None:
-            stats_out = [np.zeros((n,) + np.asarray(leaf).shape[1:],
-                                  np.asarray(leaf).dtype)
-                         for leaf in leaves]
-        for j, idx in enumerate(g.indices):
-            ln = int(lengths[idx])
-            hit[idx, :ln] = res.hit_curve[j, :ln]
-            for leaf_out, leaf in zip(stats_out, leaves):
-                leaf_out[idx] = np.asarray(leaf)[j]
-
-    return SweepResult(stats=jax.tree.unflatten(treedef, stats_out),
-                       hit_curve=hit,
-                       lengths=lengths,
-                       compiles=compiles,
-                       seconds=time.time() - t0)
-
-
-def sweep_grid(cfgs: Dict[str, SimConfig], blocks: np.ndarray,
-               lengths: Optional[np.ndarray] = None,
-               chunk: int = DEFAULT_CHUNK,
-               unroll: int = 1) -> Dict[str, SweepResult]:
-    """Sweep the trace batch through every config in the grid.
-
-    Grid entries with *equal* configs — e.g. a parameter sweep whose
-    pivot equals the baseline — share one simulation pass outright (the
-    frozen configs are hashable), on top of the per-config executable
-    cache in ``_runner``. A write-back configuration is refused.
-    """
-    for cfg in cfgs.values():
-        _refuse_write_back(cfg, "sweep_grid")
-    memo: Dict[SimConfig, SweepResult] = {}
-    out = {}
-    for name, cfg in cfgs.items():
-        if cfg not in memo:
-            memo[cfg] = sweep(cfg, blocks, lengths, chunk=chunk,
-                              unroll=unroll)
-        out[name] = memo[cfg]
-    return out
+    if cfg.write_back:
+        raise ValueError("sweep runs read-only caches; a write-back "
+                         "configuration runs through sweep_streaming")
+    return sweep_streaming(cfg, blocks, lengths=lengths,
+                           lane_width=len(blocks), chunk=chunk,
+                           unroll=unroll, shard=shard).result
 
 
 # ---------------------------------------------------------------------------
@@ -976,11 +620,10 @@ class StreamResult(NamedTuple):
     """Streaming-engine result plus schedule telemetry.
 
     ``result`` carries per-trace statistics in SUBMISSION order — the
-    same :class:`SweepResult` type the offline engines return, and per
-    trace bit-identical to them (lane assignment, slab chunking and
+    same :class:`SweepResult` type :func:`sweep` returns, and per
+    trace bit-identical to the serial ``simulate`` (lane assignment, slab chunking and
     arrival gaps are all invisible under the §6 masking contract).
-    ``lane_steps`` is the executed (lane x request) slot count — the
-    recycling analogue of ``SweepPlan.padded_lane_steps``.
+    ``lane_steps`` is the executed (lane x request) slot count.
 
     ``pipeline`` carries the producer-pipeline telemetry: stage-busy
     seconds (``produce_s`` admission, marshalling and the H2D staging
@@ -1073,10 +716,9 @@ def sweep_streaming(cfg: SimConfig,
 
     ``arrivals`` gives per-trace nondecreasing request arrival steps
     (``None`` = everything at step 0); when every trace arrives at 0 and
-    ``lane_width`` covers the batch this degrades exactly to
-    :func:`sweep` — which is, in fact, implemented on top of this
-    engine. Statistics and hit curves are bit-identical to the offline
-    engines per trace: lanes are independent and invalid slots are
+    ``lane_width`` covers the batch this is :func:`sweep`, the offline
+    special case. Statistics and hit curves are bit-identical to the
+    serial ``simulate`` per trace: lanes are independent and invalid slots are
     bit-exact no-ops (§6), and the batch-level mining barrier masks
     per-lane ``need`` (§7), so neither lane assignment, chunk phase,
     arrival gaps nor pool composition can leak between traces
@@ -1117,23 +759,8 @@ def sweep_streaming(cfg: SimConfig,
         raise ValueError(f"ring_depth must be an int >= 1, "
                          f"got {ring_depth!r}")
     ring_depth = int(ring_depth)
-    if not isinstance(traces, np.ndarray):
-        if lengths is not None:
-            raise ValueError("pass lengths only with a (B, T) block array"
-                             " — suites already carry per-trace lengths")
-        if not isinstance(traces, PaddedSuite):
-            traces = pad_traces(traces)
-        blocks, lengths = traces.blocks, traces.lengths
-    else:
-        blocks = np.asarray(traces, np.int32)
-    if blocks.ndim != 2:
-        raise ValueError(f"traces must stack to (B, T), got {blocks.shape}")
+    blocks, lengths = _suite_arrays(traces, lengths)
     n, t_max = blocks.shape
-    lengths = (np.full((n,), t_max, np.int64) if lengths is None
-               else np.asarray(lengths, np.int64))
-    if lengths.shape != (n,) or (lengths > t_max).any() \
-            or (lengths < 0).any():
-        raise ValueError("lengths must be (B,) within [0, trace axis]")
 
     avails: List[Optional[np.ndarray]] = [None] * n
     if arrivals is not None:

@@ -10,16 +10,14 @@ from .state import MithrilState, init_state
 from .mithril import (access, add_association, init, lookup, maybe_mine,
                       mine, mine_batched, record, record_event,
                       record_event_batched)
-from .mining import (associations_dense, associations_dense_batched,
-                     mine_reference_sequential, pairwise_codes,
-                     pairwise_codes_batched, select_pairs, sort_by_first_ts)
+from .mining import (associations_dense, mine_reference_sequential,
+                     pairwise_codes, select_pairs, sort_by_first_ts)
 from .hashindex import EMPTY
 
 __all__ = [
     "MithrilConfig", "MithrilState", "init_state", "init",
     "access", "add_association", "lookup", "mine", "record",
     "record_event", "record_event_batched", "maybe_mine", "mine_batched",
-    "associations_dense", "associations_dense_batched",
-    "mine_reference_sequential", "pairwise_codes", "pairwise_codes_batched",
+    "associations_dense", "mine_reference_sequential", "pairwise_codes",
     "select_pairs", "sort_by_first_ts", "EMPTY",
 ]

@@ -22,7 +22,6 @@ Association semantics (paper Fig. 2 + Alg. 1):
 
 from __future__ import annotations
 
-import functools
 from typing import List, Tuple
 
 import jax
@@ -123,42 +122,6 @@ def associations_dense(blocks: jax.Array, ts: jax.Array, cnt: jax.Array,
     blk, tss, cnts, valid = sort_by_first_ts(blocks, ts, cnt, min_support, max_support)
     code = pairwise_fn(tss, cnts, valid, delta, window)
     return _emit_pairs(blk, code, max_pairs)
-
-
-# ---------------------------------------------------------------------------
-# Batched (lanes-axis) variant for the sweep engine's mining barrier
-# ---------------------------------------------------------------------------
-
-def pairwise_codes_batched(ts: jax.Array, cnt: jax.Array, valid: jax.Array,
-                           delta: int, window: int) -> jax.Array:
-    """Batched ``pairwise_codes``: (L, N, S) x (L, N) x (L, N) -> (L, N, W).
-
-    Pure-jnp oracle for the batched Pallas kernel
-    (``kernels.mithril_mine_batched``, grid over (lane, row-block));
-    integer ops, so per-lane results are bit-identical to the serial
-    ``pairwise_codes``.
-    """
-    return jax.vmap(
-        lambda t, c, v: pairwise_codes(t, c, v, delta, window))(ts, cnt, valid)
-
-
-def associations_dense_batched(blocks: jax.Array, ts: jax.Array,
-                               cnt: jax.Array, min_support: int,
-                               max_support: int, delta: int, window: int,
-                               max_pairs: int, pairwise_fn=None):
-    """``associations_dense`` over a leading lanes axis, with ONE fused
-    pairwise pass: sort and pair emission are vmapped (cheap integer
-    ops), while ``pairwise_fn`` — the compute hot-spot — receives the
-    whole (L, N, S) stack in a single call so a batched Pallas kernel
-    can cover every lane with one launch.
-    """
-    fn = pairwise_fn or pairwise_codes_batched
-    blk, tss, cnts, valid = jax.vmap(functools.partial(
-        sort_by_first_ts, min_support=min_support,
-        max_support=max_support))(blocks, ts, cnt)
-    code = fn(tss, cnts, valid, delta, window)
-    return jax.vmap(functools.partial(_emit_pairs, max_pairs=max_pairs))(
-        blk, code)
 
 
 # ---------------------------------------------------------------------------

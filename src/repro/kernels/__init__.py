@@ -24,9 +24,6 @@ kernel (``ops`` wrapper)  on TPU                      off TPU
 (``mithril_mine.py``)     launch per flagged lane     pairwise oracle (via
                           via ``sweep.               ``mine_batched``
                           _batched_pairwise_fn``      default)
-``mithril_pairwise_       one launch over (lane,      ``core.mining``
-batched`` (``mithril_     row-block); no caller on    ``pairwise_codes_
-mine_batched.py``)        the sweep's path            batched``
 ``prefetch_lookup``       batched pFlag probe         same kernel,
 (``hash_lookup.py``)      (serving layer)             interpreted
 ``paged_decode``          flash-decode over paged     same kernel,
@@ -40,8 +37,8 @@ compare.py``.
 """
 
 from . import ops, ref
-from .ops import (mithril_pairwise, mithril_pairwise_batched,
-                  mithril_record_fused, paged_decode, prefetch_lookup)
+from .ops import (mithril_pairwise, mithril_record_fused, paged_decode,
+                  prefetch_lookup)
 
-__all__ = ["ops", "ref", "mithril_pairwise", "mithril_pairwise_batched",
-           "mithril_record_fused", "paged_decode", "prefetch_lookup"]
+__all__ = ["ops", "ref", "mithril_pairwise", "mithril_record_fused",
+           "paged_decode", "prefetch_lookup"]

@@ -33,9 +33,8 @@ def _offset_code(ts_i, cnt_i, val_i, live_i, ts_j, cnt_j, val_j,
                  delta: int):
     """Association codes for one shifted slab: (BLK, 1) int32 0/1/2.
 
-    Shared by the serial row-block kernel below and the lanes-axis
-    batched kernel (``mithril_mine_batched``) — same math, same
-    tie-breaking as ``core.mining.pairwise_codes``. Every reduction runs
+    Same math and tie-breaking as ``core.mining.pairwise_codes``.
+    Every reduction runs
     over int32 0/1 masks (no bool reductions, no bool<->int casts),
     which is what Mosaic lowers.
     """
@@ -54,14 +53,13 @@ def _offset_code(ts_i, cnt_i, val_i, live_i, ts_j, cnt_j, val_j,
 
 
 def _codes_tile(ts_ref, cnt_ref, valid_ref, r0, *, blk: int, window: int,
-                delta: int, lead: tuple = ()):
+                delta: int):
     """(BLK, window) codes of rows ``r0 .. r0+BLK`` against their
-    ``window`` successors. ``lead`` indexes the refs' leading dims (the
-    batched kernel's lane). A ``fori_loop`` over the offsets keeps one
+    ``window`` successors. A ``fori_loop`` over the offsets keeps one
     shifted slab live at a time; the tile is carried in registers and
     stored once by the caller."""
     def rows(ref, start):
-        return ref[(*lead, pl.ds(start, blk), slice(None))]
+        return ref[pl.ds(start, blk), :]
 
     ts_i, cnt_i, val_i = (rows(ts_ref, r0), rows(cnt_ref, r0),
                           rows(valid_ref, r0))      # (BLK, S), (BLK, 1) x2

@@ -18,7 +18,6 @@ from repro.core.hashindex import bucket_of
 from .backend import default_interpret
 from .hash_lookup import hash_lookup_kernel
 from .mithril_mine import pairwise_codes_kernel
-from .mithril_mine_batched import pairwise_codes_batched_kernel
 from .mithril_record import record_step_kernel
 from .paged_decode import paged_decode_kernel
 
@@ -50,31 +49,6 @@ def mithril_pairwise(ts: jax.Array, cnt: jax.Array, valid: jax.Array,
     out = pairwise_codes_kernel(ts_p, cnt_p, val_p, delta, window, blk=blk,
                                 interpret=default_interpret(interpret))
     return out[:n]
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("delta", "window", "blk", "interpret"))
-def mithril_pairwise_batched(ts: jax.Array, cnt: jax.Array, valid: jax.Array,
-                             delta: int, window: int, blk: int = 128,
-                             interpret=None) -> jax.Array:
-    """Drop-in for core.mining.pairwise_codes_batched
-    ((L,N,S),(L,N),(L,N) -> (L,N,W)): the sweep engine's batched mining
-    barrier in one kernel launch (grid over (lane, row-block)).
-
-    Same per-lane padding contract as ``mithril_pairwise``; padded rows
-    are invalid and can never match.
-    """
-    lanes, n, s = ts.shape
-    blk, _, pad_total = _mine_padding(n, window, blk)
-    big = jnp.int32(2_000_000_000)
-    ts_p = jnp.full((lanes, pad_total, s), big, jnp.int32).at[:, :n].set(ts)
-    cnt_p = jnp.zeros((lanes, pad_total, 1), jnp.int32).at[:, :n, 0].set(cnt)
-    val_p = jnp.zeros((lanes, pad_total, 1), jnp.int32).at[:, :n, 0].set(
-        valid.astype(jnp.int32))
-    out = pairwise_codes_batched_kernel(ts_p, cnt_p, val_p, delta, window,
-                                        blk=blk,
-                                        interpret=default_interpret(interpret))
-    return out[:, :n]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

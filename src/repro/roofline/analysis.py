@@ -384,14 +384,13 @@ def _record_fused_cost(g: dict):
     return float(bytes_), float(flops)
 
 
-def _mine_batched_cost(g: dict):
-    """One ``mithril_pairwise_batched`` mining barrier: the sorted
-    mining table in + candidate pairs out per lane; compute is the
-    window*S*S timestamp-closeness compare grid per row."""
-    lanes = g.get("lanes", 1)
+def _mine_cost(g: dict):
+    """One ``mithril_pairwise`` launch, which mines one lane: the sorted
+    mining table in + candidate pairs out; compute is the window*S*S
+    timestamp-closeness compare grid per row."""
     n, s, window = g["mine_rows"], g["s_sup"], g["window"]
-    bytes_ = lanes * (n * s + 2 * n + n * window) * 4 * 2
-    flops = lanes * n * window * s * 3
+    bytes_ = (n * s + 2 * n + n * window) * 4 * 2
+    flops = n * window * s * 3
     return float(bytes_), float(flops)
 
 
@@ -428,7 +427,7 @@ def _paged_decode_cost(g: dict):
 #: Names match the ``ops``/BENCH-json kernel labels.
 KERNEL_MODELS = {
     "mithril_record_fused": _record_fused_cost,
-    "mithril_mine_batched": _mine_batched_cost,
+    "mithril_mine": _mine_cost,
     "hash_lookup": _hash_lookup_cost,
     "paged_decode": _paged_decode_cost,
 }

@@ -198,7 +198,7 @@ def corpus_suite(scale: str = "quick", n_requests: int = 50_000):
     Same convention as ``synthetic.padded_suite`` — ``blocks`` is
     ``(B, max_len)`` int32 zero-padded past each trace's ``lengths[i]``
     (``synthetic.stack_padded``) — directly consumable by
-    ``cache.sweep.sweep_scheduled``.
+    ``cache.sweep.sweep_streaming``.
     """
     return stack_padded(build_corpus(corpus_specs(n_requests, scale)))
 
@@ -216,7 +216,7 @@ class RealCorpus:
     npz files also works). ``suite(scale, n_requests)`` returns the
     same ``(names, blocks, lengths)`` zero-padded batch as
     :func:`corpus_suite`, so everything downstream of the registry —
-    ``plan_sweep``, ``sweep_scheduled``, the figure engine — runs
+    ``sweep_streaming``, the figure engine — runs
     unchanged the moment a volume directory is present.
 
     Contract deltas vs the synthetic registry, both deliberate:

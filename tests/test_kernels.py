@@ -39,32 +39,6 @@ class TestMineKernel:
         assert int(jnp.sum(got)) == 0
 
 
-class TestMineBatchedKernel:
-    """Lanes-axis kernel (grid over (lane, row-block)) vs batched oracle."""
-
-    @pytest.mark.parametrize("lanes,n,s,delta,window",
-                             [(1, 64, 4, 8, 8), (3, 96, 8, 25, 16),
-                              (4, 33, 4, 5, 7)])
-    def test_matches_batched_oracle(self, rng, lanes, n, s, delta, window):
-        from repro.core.mining import pairwise_codes_batched
-        tabs = [make_table(rng, n, s) for _ in range(lanes)]
-        ts, cnt, valid = (jnp.stack([t[i] for t in tabs]) for i in range(3))
-        got = ops.mithril_pairwise_batched(ts, cnt, valid, delta, window)
-        want = pairwise_codes_batched(ts, cnt, valid, delta, window)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-
-    def test_lane_matches_serial_kernel(self, rng):
-        """Every lane of the batched kernel equals the serial kernel."""
-        tabs = [make_table(rng, 64, 8) for _ in range(3)]
-        ts, cnt, valid = (jnp.stack([t[i] for t in tabs]) for i in range(3))
-        got = ops.mithril_pairwise_batched(ts, cnt, valid, 20, 16)
-        for lane in range(3):
-            want = ops.mithril_pairwise(ts[lane], cnt[lane], valid[lane],
-                                        20, 16)
-            np.testing.assert_array_equal(np.asarray(got[lane]),
-                                          np.asarray(want))
-
-
 class TestHashLookupKernel:
     @pytest.mark.parametrize("nb,w,p,nq", [(64, 4, 2, 64), (256, 4, 3, 100),
                                            (32, 2, 2, 7)])

@@ -4,12 +4,12 @@ Pins the whole ingested-trace path end to end:
 
 * **golden end-to-end** — the checked-in fixtures
   (``tests/fixtures/msr_tiny.csv``, ``raw_tiny.raw``) ingest into a
-  corpus directory whose manifest, fingerprint and scheduled-sweep hit
+  corpus directory whose manifest, fingerprint and sweep hit
   ratios match frozen values (regenerate deliberately, never silently);
 * **round-trip differential** — the synthetic quick registry exported
   to npz volumes and re-ingested through :class:`RealCorpus` must
   reproduce the synthetic suite bit-identically: same names/lengths,
-  same packer plan, same hit curves, zero extra compiles;
+  same lane schedule, same hit curves, zero extra compiles;
 * **ingestion fuzz battery** — malformed MSR rows and raw records
   (truncated rows, non-integer fields, non-monotonic timestamps,
   zero-length ranges, negative offsets, uint64 overflow, torn trailing
@@ -31,7 +31,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.cache import SimConfig, plan_sweep, sweep_scheduled
+from repro.cache import SimConfig, sweep_streaming
 from repro.core import MithrilConfig
 from repro.traces import (INGESTED, RealCorpus, build_corpus,
                           corpus_fingerprint, corpus_specs, family_of,
@@ -98,12 +98,11 @@ class TestGoldenEndToEnd:
         names, blocks, lengths = rc.suite()
         assert names == ("msr_tiny", "raw_tiny")
         assert tuple(int(x) for x in lengths) == GOLDEN_LENGTHS
-        plan = plan_sweep(lengths)
         grid = {"lru": SimConfig(capacity=8),
                 "mithril-lru": SimConfig(capacity=8, use_mithril=True,
                                          mithril=MCFG)}
         for cname, cfg in grid.items():
-            res = sweep_scheduled(cfg, blocks, lengths, plan=plan)
+            res = sweep_streaming(cfg, blocks, lengths).result
             got = tuple(round(float(h), 6) for h in res.hit_ratios())
             assert got == GOLDEN_HR[cname], cname
         # the prefetcher's win on the looping raw volume is the whole
@@ -159,11 +158,17 @@ class TestRoundTripDifferential:
         d, traces, _ = exported
         names_s, blocks_s, lengths_s = stack_padded(traces)
         _, blocks_r, lengths_r = RealCorpus(d).suite("full")
-        plan_s, plan_r = plan_sweep(lengths_s), plan_sweep(lengths_r)
-        assert plan_s.packer_stats() == plan_r.packer_stats()
+        assert np.array_equal(lengths_s, lengths_r)
         cfg = SimConfig(capacity=64, use_mithril=True, mithril=MCFG)
-        res_s = sweep_scheduled(cfg, blocks_s, lengths_s, plan=plan_s)
-        res_r = sweep_scheduled(cfg, blocks_r, lengths_r, plan=plan_r)
+        out_s = sweep_streaming(cfg, blocks_s, lengths_s)
+        out_r = sweep_streaming(cfg, blocks_r, lengths_r)
+
+        def schedule(out):      # the stage timings are noise, not schedule
+            return {k: v for k, v in out.streaming_stats().items()
+                    if k != "pipeline"}
+
+        assert schedule(out_s) == schedule(out_r)
+        res_s, res_r = out_s.result, out_r.result
         assert np.array_equal(res_s.hit_curve, res_r.hit_curve)
         assert np.array_equal(res_s.hit_ratios(), res_r.hit_ratios())
         # same geometry + same config -> the jit cache is warm: the

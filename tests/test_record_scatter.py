@@ -458,11 +458,13 @@ def _mining_lanes(n_lanes: int, seed: int) -> list:
     return lanes
 
 
-def _assert_mine_batched_matches(lanes: list, need: np.ndarray) -> None:
+def _assert_mine_batched_matches(lanes: list, need: np.ndarray,
+                                 pairwise_fn=None) -> None:
     """Per-lane equality: mined lanes == mine(lane), others untouched."""
     cfg = _MINE_CFG
     states = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *lanes)
-    got = mine_batched(cfg, states, jnp.asarray(need))
+    got = mine_batched(cfg, states, jnp.asarray(need),
+                       pairwise_fn=pairwise_fn)
     for i, lane in enumerate(lanes):
         want = mine(cfg, lane) if need[i] else lane
         got_i = jax.tree_util.tree_map(lambda x: x[i], got)
@@ -477,15 +479,20 @@ def test_mine_batched_matches_serial_mine(seed, need_bits):
     _assert_mine_batched_matches(_mining_lanes(3, seed), need)
 
 
+@pytest.mark.parametrize("pairwise", ["oracle", "kernel"])
 @pytest.mark.parametrize("need", [(1, 1, 1, 1, 1), (0, 1, 0, 1, 0),
                                   (0, 0, 0, 0, 0)],
                          ids=["every_lane", "two_apart", "no_lane"])
-def test_mine_batched_matches_serial_mine_at_five_lanes(need):
+def test_mine_batched_matches_serial_mine_at_five_lanes(need, pairwise):
     """At 5 lanes: with every lane flagged, two flagged lanes that are
     not neighbours, or none, each flagged lane equals the serial
-    ``mine`` bit for bit and every other lane is untouched."""
-    _assert_mine_batched_matches(_mining_lanes(5, 2024),
-                                 np.array(need, bool))
+    ``mine`` bit for bit and every other lane is untouched. ``kernel``
+    runs each pass's association search through the row-block Pallas
+    kernel (interpreted here), as the sweep's barrier does on a TPU."""
+    from repro.kernels.ops import mithril_pairwise
+    _assert_mine_batched_matches(
+        _mining_lanes(5, 2024), np.array(need, bool),
+        mithril_pairwise if pairwise == "kernel" else None)
 
 
 # ---------------------------------------------------------------------------

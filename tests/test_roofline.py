@@ -19,8 +19,7 @@ from repro.roofline import (HBM_BW, PEAK_FLOPS, KERNEL_MODELS,
 GEOMS = {
     "mithril_record_fused": dict(lanes=4, n_buckets=16, ways=2, r_sup=2,
                                  mine_rows=16, s_sup=4),
-    "mithril_mine_batched": dict(lanes=2, mine_rows=256, s_sup=8,
-                                 window=32),
+    "mithril_mine": dict(mine_rows=256, s_sup=8, window=32),
     "hash_lookup": dict(queries=256, n_buckets=128, ways=4, plist=3),
     "paged_decode": dict(batch=4, heads_q=32, heads_kv=8, head_dim=128,
                          page_size=16, n_pages=8),

@@ -1,15 +1,16 @@
-"""Corpus-scale sweep scheduler: planning, bit-identity, device sharding.
+"""Corpus-scale sweeps: lane schedule invariance, input checks, sharding.
 
-The tentpole contract (ISSUE 4 / DESIGN.md §8): ``sweep_scheduled``
-buckets a heterogeneous trace corpus into fixed-geometry lane groups so
-the whole corpus runs through ONE compiled executable per config, its
-per-trace results are bit-identical to the serial ``simulate``, and
-sharding the lane axis over devices changes nothing but wall-clock —
-per-lane results stay bit-identical to the single-device path (pinned
-here on a forced 4-device CPU subprocess).
+``sweep_streaming`` runs a heterogeneous trace corpus through one pool
+of recycled lanes, so the whole corpus costs ONE compiled executable
+per config (DESIGN.md §8), its per-trace results are bit-identical to
+the serial ``simulate`` whatever the lane width and chunk, and sharding
+the lane axis over devices changes nothing but wall-clock — per-lane
+results stay bit-identical to the single-device path (pinned here on a
+forced 4-device CPU subprocess).
 """
 
 import ast
+import dataclasses
 import inspect
 import os
 import subprocess
@@ -19,8 +20,9 @@ import textwrap
 import numpy as np
 import pytest
 
-from repro.cache import SimConfig, plan_sweep, simulate, sweep_scheduled
-from repro.cache.sweep import DEFAULT_LANE_WIDTH, reset_runners
+from repro.cache import SimConfig, pad_traces, simulate, sweep, \
+    sweep_streaming
+from repro.cache.sweep import reset_runners
 from repro.core import MithrilConfig
 from repro.traces import mixed
 
@@ -35,147 +37,38 @@ CFG = SimConfig(capacity=128, use_mithril=True, use_amp=True,
 
 @pytest.fixture(scope="module")
 def corpus():
-    # heterogeneous lengths spanning several chunk multiples so the plan
-    # builds multiple groups with different padded time axes
+    # heterogeneous lengths spanning several chunk multiples so lanes
+    # drain at different slabs and recycle
     return {f"t{i:02d}": mixed(220 + 173 * i, w_seq=0.3, w_assoc=0.4,
                                w_zipf=0.3, seed=40 + i) for i in range(7)}
 
 
-class TestPlan:
-    def test_groups_cover_all_traces_once(self):
-        lengths = np.array([900, 100, 500, 700, 300])
-        plan = plan_sweep(lengths, lane_width=2, chunk=256, n_shards=1)
-        seen = [i for g in plan.groups for i in g.indices]
-        assert sorted(seen) == list(range(5))
-        # longest-first packing: the longest trace leads the first group
-        assert plan.groups[0].indices[0] == 0
-
-    def test_groups_are_consecutive_runs_of_sorted_order(self):
-        lengths = np.array([900, 100, 500, 700, 300])
-        plan = plan_sweep(lengths, lane_width=2, chunk=256, n_shards=1)
-        flat = [i for g in plan.groups for i in g.indices]
-        order = list(np.argsort(-lengths, kind="stable"))
-        assert flat == order
-
-    def test_padded_t_is_chunk_multiple_and_covers_group(self):
-        lengths = np.array([900, 100, 500, 700, 300])
-        plan = plan_sweep(lengths, lane_width=2, chunk=256, n_shards=1)
-        for g in plan.groups:
-            # each group pads to a multiple of its OWN chunk (the packer
-            # may pick a finer time chunk for short-trace groups)
-            assert g.padded_t % g.chunk == 0
-            assert 1 <= g.chunk <= plan.chunk
-            assert g.padded_t >= lengths[list(g.indices)].max()
-            assert len(g.indices) <= g.lane_width
-
-    def test_lane_width_rounds_to_shards(self):
-        plan = plan_sweep(np.array([50] * 10), lane_width=3, chunk=64,
-                          n_shards=4)
-        assert plan.lane_width == 4
-        assert plan.n_shards == 4
-        assert all(g.lane_width % 4 == 0 for g in plan.groups)
-
-    def test_chunk_capped_at_longest_trace(self):
-        plan = plan_sweep(np.array([70, 40]), chunk=4096, n_shards=1)
-        assert plan.chunk == 70
-        assert plan.groups[0].padded_t == 70
-
-    def test_defaults(self):
-        plan = plan_sweep(np.array([100] * 40), n_shards=1)
-        assert plan.lane_width <= DEFAULT_LANE_WIDTH
-        with pytest.raises(ValueError, match="at least one"):
-            plan_sweep(np.array([], np.int64))
+def _assert_same(a, b, what):
+    for field, x, y in zip(a.stats._fields, a.stats, b.stats):
+        np.testing.assert_array_equal(
+            np.asarray(x), np.asarray(y),
+            err_msg=f"stats.{field} depends on {what}")
+    np.testing.assert_array_equal(a.hit_curve, b.hit_curve,
+                                  err_msg=f"hit curve depends on {what}")
 
 
-class TestPacker:
-    """Cost-model packer invariants (ISSUE 5 / DESIGN.md §9)."""
-
-    LENGTH_SETS = [
-        np.array([1_000_000] + [1000] * 15),          # one giant outlier
-        np.array([100] * 40),                         # uniform
-        np.array([10, 20, 40, 80, 160, 320, 640, 1280, 2560, 5120]),
-        np.geomspace(50, 50_000, 33).astype(np.int64),
-        np.array([4097, 4096, 4095, 1, 1, 1, 1, 1]),  # chunk-boundary
-    ]
-
-    def test_never_worse_padded_waste_than_fixed_width(self):
-        rng = np.random.default_rng(7)
-        sets = self.LENGTH_SETS + [
-            rng.integers(1, 30_000, size=n) for n in (5, 17, 64, 135)]
-        for lengths in sets:
-            for chunk in (64, 4096):
-                plan = plan_sweep(lengths, chunk=chunk, n_shards=1)
-                assert plan.padded_lane_steps <= plan.fixed_lane_steps, \
-                    (lengths[:8], chunk)
-                assert plan.waste_ratio <= plan.fixed_waste_ratio + 1e-12
-
-    def test_compile_shape_budget_respected(self):
-        rng = np.random.default_rng(11)
-        lengths = rng.integers(1, 50_000, size=64)
-        for max_shapes in (1, 2, 3):
-            plan = plan_sweep(lengths, chunk=4096, n_shards=1,
-                              max_shapes=max_shapes)
-            # the budget counts distinct (chunk, width) slab SHAPES, of
-            # which distinct widths are a coarsening
-            assert 1 <= len(plan.shapes) <= max_shapes
-            assert len(plan.shape_widths) <= len(plan.shapes)
-        with pytest.raises(ValueError, match="max_shapes"):
-            plan_sweep(lengths, max_shapes=0)
-
-    def test_skewed_corpus_strict_reduction(self):
-        """The motivating case: one huge trace must not drag a full
-        lane group through its padded tail."""
-        plan = plan_sweep(np.array([1_000_000] + [1000] * 15),
-                          chunk=4096, n_shards=1)
-        assert plan.waste_ratio < 0.25
-        assert plan.fixed_waste_ratio > 0.9
-        red = plan.packer_stats()["reduction_vs_fixed"]
-        assert red > 0.5, red
-
-    def test_packer_stats_are_self_consistent(self):
-        lengths = np.array([9000, 12000, 20000, 300, 8000, 17000, 40])
-        plan = plan_sweep(lengths, chunk=4096, n_shards=1)
-        st = plan.packer_stats()
-        assert st["padded_lane_steps"] == sum(
-            g.padded_t * g.lane_width for g in plan.groups)
-        assert st["ideal_lane_steps"] == int(lengths.sum())
-        assert st["n_groups"] == len(plan.groups)
-        assert st["n_traces"] == len(lengths)
-        assert st["widths"] == list(plan.shape_widths)
-        assert 0.0 <= st["waste_ratio"] <= 1.0
-        # packer_stats rounds ratios to 6 decimals
-        assert abs(st["waste_ratio"]
-                   - (1 - st["ideal_lane_steps"]
-                      / st["padded_lane_steps"])) < 1e-6
-
+class TestLaneSchedule:
     def test_variable_width_plans_stay_bit_identical(self, corpus):
-        """Packing is invisible in the results: a single-shape plan and
-        the default two-shape plan produce identical stats in the
-        original trace order."""
-        from repro.cache import pad_traces
-        suite = pad_traces(corpus)
-        one = sweep_scheduled(
-            CFG, suite, chunk=256,
-            plan=plan_sweep(suite.lengths, chunk=256, n_shards=1,
-                            max_shapes=1))
-        two = sweep_scheduled(
-            CFG, suite, chunk=256,
-            plan=plan_sweep(suite.lengths, chunk=256, n_shards=1,
-                            max_shapes=2))
-        for field, x, y in zip(one.stats._fields, one.stats, two.stats):
-            np.testing.assert_array_equal(
-                np.asarray(x), np.asarray(y),
-                err_msg=f"stats.{field} depends on the packing")
-        np.testing.assert_array_equal(one.hit_curve, two.hit_curve)
+        """The schedule is invisible in the results: two lane widths
+        and two chunks, so traces drain and recycle at different slabs,
+        give identical stats in the original trace order."""
+        a = sweep_streaming(CFG, corpus, lane_width=2, chunk=256)
+        b = sweep_streaming(CFG, corpus, lane_width=5, chunk=100)
+        assert (a.n_slabs, a.chunk) != (b.n_slabs, b.chunk)
+        _assert_same(a.result, b.result, "lane width and chunk")
 
 
-class TestScheduledSweep:
+class TestCorpusSweep:
     def test_bit_identical_to_simulate_one_compile(self, corpus):
         reset_runners()
-        res = sweep_scheduled(CFG, corpus, lane_width=3, chunk=256)
-        # one (chunk, lane_width) shape serves every group: the whole
-        # corpus costs at most 2 new executables (ISSUE 4 acceptance)
-        assert 0 < res.compiles <= 2, res.compiles
+        res = sweep_streaming(CFG, corpus, lane_width=3, chunk=256).result
+        # one (chunk, lane_width) slab shape serves the whole corpus
+        assert res.compiles == 1, res.compiles
         for i, (name, trace) in enumerate(corpus.items()):
             ref = simulate(CFG, trace)
             got = res.result(i)
@@ -188,47 +81,60 @@ class TestScheduledSweep:
                 err_msg=f"hit curve diverged on {name}")
 
     def test_matches_unscheduled_sweep_any_lane_width(self, corpus):
-        """Lane grouping is invisible in the results: every lane width
-        (including short final groups padded with empty lanes) produces
-        the same stats in the same original-trace order."""
-        a = sweep_scheduled(CFG, corpus, lane_width=3, chunk=256)
-        b = sweep_scheduled(CFG, corpus, lane_width=7, chunk=256)
-        for field, x, y in zip(a.stats._fields, a.stats, b.stats):
-            np.testing.assert_array_equal(
-                np.asarray(x), np.asarray(y),
-                err_msg=f"stats.{field} depends on lane width")
-        np.testing.assert_array_equal(a.hit_curve, b.hit_curve)
+        """Recycled lanes match ``sweep``, which gives every trace its
+        own lane: at any pool width the stats come back the same, in
+        the original trace order."""
+        suite = pad_traces(corpus)
+        ref = sweep(CFG, suite.blocks, suite.lengths, chunk=256)
+        for width in (3, 7):
+            got = sweep_streaming(CFG, corpus, lane_width=width, chunk=256)
+            _assert_same(ref, got.result, f"lane width {width}")
 
     def test_accepts_padded_batch_input(self, corpus):
-        from repro.cache import pad_traces
         suite = pad_traces(corpus)
-        a = sweep_scheduled(CFG, corpus, lane_width=3, chunk=256)
-        b = sweep_scheduled(CFG, suite, lane_width=3, chunk=256)
-        np.testing.assert_array_equal(a.hit_curve, b.hit_curve)
-        np.testing.assert_array_equal(np.asarray(a.stats.hits),
-                                      np.asarray(b.stats.hits))
+        a = sweep_streaming(CFG, corpus, lane_width=3, chunk=256).result
+        for traces, lengths in ((suite, None),
+                                (suite.blocks, suite.lengths)):
+            b = sweep_streaming(CFG, traces, lengths=lengths, lane_width=3,
+                                chunk=256).result
+            np.testing.assert_array_equal(a.hit_curve, b.hit_curve)
+            np.testing.assert_array_equal(np.asarray(a.stats.hits),
+                                          np.asarray(b.stats.hits))
 
-    def test_rejects_negative_lengths(self, corpus):
-        """A negative length must raise, not silently become an
-        all-masked zero-stat lane (the surfaced-not-dropped contract)."""
+    @pytest.mark.parametrize("case", [
+        "negative_length", "length_past_axis", "lengths_shape",
+        "one_d_blocks", "lengths_beside_suite", "writes_count"])
+    def test_rejects_bad_input(self, corpus, case):
+        """A malformed sweep input raises, never runs as something else:
+        a negative length would become an all-masked zero-stat lane, a
+        length past the trace axis would read padding, and ``lengths``
+        beside a suite (which carries its own) would silently win or
+        lose. ``sweep`` hands its input to the same check."""
         blocks = np.zeros((3, 100), np.int32)
-        bad = np.array([50, -1, 100])
-        with pytest.raises(ValueError, match="lengths"):
-            sweep_scheduled(CFG, blocks, lengths=bad, chunk=64)
-        from repro.cache.sweep import sweep as sweep_fn
-        with pytest.raises(ValueError, match="lengths"):
-            sweep_fn(CFG, blocks, lengths=bad, chunk=64)
-
-    def test_rejects_conflicting_lengths(self, corpus):
-        """Suite-like inputs carry their own lengths; an explicit
-        lengths argument alongside them must raise, not silently win
-        or lose."""
-        from repro.cache import pad_traces
-        suite = pad_traces(corpus)
-        for traces in (corpus, suite):
-            with pytest.raises(ValueError, match="lengths"):
-                sweep_scheduled(CFG, traces,
-                                lengths=np.ones(len(corpus), np.int64))
+        cfg, kw = CFG, {}
+        if case == "negative_length":
+            traces, kw["lengths"], match = blocks, np.array([50, -1, 100]), \
+                "lengths"
+        elif case == "length_past_axis":
+            traces, kw["lengths"], match = blocks, np.array([50, 101, 9]), \
+                "lengths"
+        elif case == "lengths_shape":
+            traces, kw["lengths"], match = blocks, np.array([50, 100]), \
+                "lengths"
+        elif case == "one_d_blocks":
+            traces, match = blocks[0], r"\(B, T\)"
+        elif case == "lengths_beside_suite":
+            traces, kw["lengths"], match = corpus, \
+                np.ones(len(corpus), np.int64), "lengths"
+        else:
+            cfg = dataclasses.replace(CFG, write_back=True)
+            traces, kw["writes"], match = blocks, [np.ones(100, bool)], \
+                "writes"
+        with pytest.raises(ValueError, match=match):
+            sweep_streaming(cfg, traces, **kw)
+        if isinstance(traces, np.ndarray) and "writes" not in kw:
+            with pytest.raises(ValueError, match=match):
+                sweep(cfg, traces, **kw)
 
 
 def test_sharded_sweep_bit_identical_to_single_device():
@@ -239,7 +145,7 @@ def test_sharded_sweep_bit_identical_to_single_device():
     """
     script = textwrap.dedent("""
         import numpy as np
-        from repro.cache import SimConfig, sweep_scheduled
+        from repro.cache import SimConfig, sweep_streaming
         from repro.core import MithrilConfig
         from repro.traces import mixed
         import jax
@@ -251,10 +157,12 @@ def test_sharded_sweep_bit_identical_to_single_device():
                             min_support=2, max_support=4, lookahead=20,
                             rec_buckets=128, rec_ways=2, mine_rows=16,
                             pf_buckets=128, pf_ways=2))
-        single = sweep_scheduled(cfg, traces, lane_width=8, chunk=128,
+        single = sweep_streaming(cfg, traces, lane_width=4, chunk=128,
                                  shard=False)
-        sharded = sweep_scheduled(cfg, traces, lane_width=8, chunk=128,
+        sharded = sweep_streaming(cfg, traces, lane_width=4, chunk=128,
                                   shard=True)
+        assert (single.n_shards, sharded.n_shards) == (1, 4)
+        single, sharded = single.result, sharded.result
         for f, a, b in zip(single.stats._fields, single.stats,
                            sharded.stats):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b),

@@ -1,12 +1,13 @@
 """Streaming ingestion engine: ring buffer, lane recycling, arrivals.
 
-The tentpole contract (ISSUE 6 / DESIGN.md §10): arrival is the
-primitive — ``sweep_streaming`` admits traces into a recycled lane pool
-as they arrive, and the offline engines are its special case. Pinned
-here: per-trace results are bit-identical to ``sweep_scheduled`` /
-``simulate`` regardless of lane pool size, chunking, arrival gaps or
-admission order; recycling executes strictly fewer padded lane-steps
-than the offline packer on a heterogeneous corpus; the incremental
+The contract (DESIGN.md §10): arrival is the primitive —
+``sweep_streaming`` admits traces into a recycled lane pool as they
+arrive, and the offline ``sweep`` is its special case. Pinned here:
+per-trace results are bit-identical to ``sweep`` / ``simulate``
+regardless of lane pool size, chunking, arrival gaps or admission
+order; recycling executes no more lane-steps than a fixed-shape padded
+schedule, and strictly fewer on a heterogeneous corpus, because a
+drained lane idles only to the end of its slab; the incremental
 ``SimSession`` is slice-invariant; ``arrival_process`` is crc32-
 deterministic and nondecreasing.
 """
@@ -14,8 +15,8 @@ deterministic and nondecreasing.
 import numpy as np
 import pytest
 
-from repro.cache import (SimConfig, SimSession, plan_sweep, simulate,
-                         sweep_scheduled, sweep_streaming)
+from repro.cache import (SimConfig, SimSession, pad_traces, simulate, sweep,
+                         sweep_streaming)
 from repro.cache.sweep import RingBuffer
 from repro.core import MithrilConfig
 from repro.traces import arrival_process, mixed
@@ -47,9 +48,10 @@ def _assert_same_results(a, b, names):
 
 class TestStreamingBitIdentity:
     def test_replays_packed_corpus_identically(self, corpus):
-        """ISSUE 6 acceptance: streaming replay of a packed corpus gives
-        bit-identical hit ratios to ``sweep_scheduled``."""
-        offline = sweep_scheduled(CFG, corpus, lane_width=4, chunk=128)
+        """Streaming replay of a corpus through 4 recycled lanes gives
+        bit-identical hit ratios to ``sweep``, one lane per trace."""
+        suite = pad_traces(corpus)
+        offline = sweep(CFG, suite.blocks, suite.lengths, chunk=128)
         stream = sweep_streaming(CFG, corpus, lane_width=4, chunk=128)
         _assert_same_results(offline, stream.result, "offline vs stream")
         np.testing.assert_array_equal(offline.hit_ratios(),
@@ -88,32 +90,62 @@ class TestStreamingBitIdentity:
                                           np.asarray(ref.hit_curve))
 
 
+def _fixed_lane_steps(lengths, width: int, chunk: int) -> int:
+    """Lane-steps of the fixed-shape padded schedule: traces longest
+    first in groups of ``width`` lanes, each group run to its first
+    (longest) trace rounded up to a whole chunk (at least one)."""
+    ordered = sorted((int(n) for n in lengths), reverse=True)
+    return sum(-(-max(1, ordered[i]) // chunk) * chunk * width
+               for i in range(0, len(ordered), width))
+
+
+def _recycled_slabs(lengths, width: int, chunk: int) -> int:
+    """Slabs a pool of ``width`` lanes runs when each trace, in
+    submission order, takes the lane that frees first and holds it to
+    the end of the slab it drains in (an empty trace takes none)."""
+    free = [0] * width
+    for n in lengths:
+        if n:
+            lane = free.index(min(free))
+            free[lane] += -(-int(n) // chunk)
+    return max(free)
+
+
 class TestRecycling:
     def test_strictly_fewer_lane_steps_than_offline_packer(self, corpus):
-        """ISSUE 6 acceptance: on a heterogeneous-length corpus, lane
-        recycling beats the offline packer's padded lane-steps — short
-        tenants cycle through reclaimed lanes instead of the packer
-        scanning group-padded tails. Compared at the same device-mesh
-        contract (lane width 4, widths multiples of 4 — a 4-device
-        deployment, where the packer cannot shred groups below the
-        mesh width), with longest-first submission so streaming's
-        greedy admission is the packer's LPT analogue. The scheduling
-        itself is device-count independent, so this pins the 4-shard
-        plan against a single-device replay."""
+        """On a heterogeneous-length corpus, lane recycling executes
+        strictly fewer lane-steps than the fixed-shape padded schedule:
+        short tenants cycle through reclaimed lanes instead of a lane
+        group scanning its padded tail. Submitted longest first, so the
+        FIFO admission order is the schedule's group order."""
         ordered = dict(sorted(corpus.items(), key=lambda kv: -len(kv[1])))
         lengths = np.array([len(t) for t in ordered.values()])
-        plan = plan_sweep(lengths, lane_width=4, chunk=128, n_shards=4)
         stream = sweep_streaming(CFG, ordered, lane_width=4, chunk=128,
                                  shard=False)
-        assert stream.lane_steps < plan.padded_lane_steps, \
-            (stream.lane_steps, plan.padded_lane_steps)
-        # and a fortiori fewer than the fixed-shape (pre-packer) schedule
-        assert stream.lane_steps < plan.fixed_lane_steps
+        fixed = _fixed_lane_steps(lengths, 4, stream.chunk)
+        assert stream.lane_steps < fixed, (stream.lane_steps, fixed)
         st = stream.streaming_stats()
         assert st["lane_steps"] == stream.lane_steps
         assert st["ideal_lane_steps"] == int(lengths.sum())
         assert 0.0 <= st["waste_ratio"] < 1.0
-        assert st["waste_ratio"] < plan.waste_ratio
+        assert st["waste_ratio"] < 1.0 - lengths.sum() / fixed
+
+    @pytest.mark.parametrize("lengths", [
+        [300] * 6, [900] + [60] * 7, [400, 0, 250, 0, 130], [333]],
+        ids=["uniform", "skewed", "with_empty", "one_trace"])
+    def test_recycled_lanes_bound_lane_steps(self, lengths):
+        """Executed lane-steps stay at or under the fixed-shape padded
+        schedule, and the slab count is exactly that of a pool whose
+        drained lane idles only to the end of its slab before the next
+        queued trace takes it."""
+        rng = np.random.default_rng(len(lengths))
+        traces = [rng.integers(0, 200, size=n).astype(np.int32)
+                  for n in lengths]
+        stream = sweep_streaming(SimConfig(capacity=32), traces,
+                                 lane_width=4, chunk=64)
+        assert stream.lane_steps <= _fixed_lane_steps(lengths, 4, 64)
+        assert stream.n_slabs == _recycled_slabs(lengths, 4, 64)
+        assert stream.streaming_stats()["ideal_lane_steps"] == sum(lengths)
 
     def test_zero_length_tenants_drain_at_admission(self):
         traces = {"a": mixed(300, 0.3, 0.4, 0.3, seed=1),

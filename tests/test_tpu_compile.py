@@ -23,6 +23,7 @@ same tests. Nothing runs; only the compiler is exercised.
 """
 
 import dataclasses
+import functools
 import importlib
 import re
 
@@ -74,6 +75,13 @@ def _kernels(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
+def _kernels_named(text: str) -> list:
+    """The name of each Pallas kernel launch in compiled HLO text."""
+    return re.findall(r'^\s*(?:ROOT )?%(\w+)(?:\.\d+)* = .*'
+                      r'custom_call_target="tpu_custom_call"', text,
+                      flags=re.M)
+
+
 @pytest.mark.parametrize("tables", sorted(TABLES))
 def test_record_kernel_compiles(one_chip, tables):
     from repro.core import init_state
@@ -89,19 +97,31 @@ def test_record_kernel_compiles(one_chip, tables):
     assert _kernels(compiled) == 1
 
 
-@pytest.mark.parametrize("kind", ["serial", "batched"])
+@pytest.mark.parametrize("kind", ["serial", "barrier"])
 @pytest.mark.parametrize("tables", sorted(TABLES))
 def test_mining_kernel_compiles(one_chip, tables, kind):
+    """The row-block mining kernel compiles alone, and inside
+    ``mine_batched``'s loop over the flagged lanes at the table's lane
+    count (the sweep's mining barrier) as one ``mithril_mine`` launch."""
     cfg, lanes = TABLES[tables]
-    lead = () if kind == "serial" else (lanes,)
-    fn = ops.mithril_pairwise if kind == "serial" \
-        else ops.mithril_pairwise_batched
-    n, s = cfg.mine_rows, cfg.max_support
-    compiled = jax.jit(lambda ts, cnt, v: fn(
-        ts, cnt, v, cfg.lookahead, cfg.window, interpret=False)).lower(
-            _sds(one_chip, lead + (n, s)), _sds(one_chip, lead + (n,)),
-            _sds(one_chip, lead + (n,), jnp.bool_)).compile()
-    assert _kernels(compiled) == 1
+    pairwise = functools.partial(ops.mithril_pairwise, interpret=False)
+    if kind == "serial":
+        n, s = cfg.mine_rows, cfg.max_support
+        lowered = jax.jit(lambda ts, cnt, v: pairwise(
+            ts, cnt, v, cfg.lookahead, cfg.window)).lower(
+                _sds(one_chip, (n, s)), _sds(one_chip, (n,)),
+                _sds(one_chip, (n,), jnp.bool_))
+    else:
+        from repro.core import init_state, mine_batched
+        states = jax.eval_shape(
+            lambda: jax.vmap(lambda _: init_state(cfg))(jnp.arange(lanes)))
+        states = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
+                              states)
+        lowered = jax.jit(lambda st, need: mine_batched(
+            cfg, st, need, pairwise_fn=pairwise)).lower(
+                states, _sds(one_chip, (lanes,), jnp.bool_))
+    text = lowered.compile().as_text()
+    assert _kernels_named(text) == ["mithril_mine"]
 
 
 @pytest.mark.parametrize("tables", sorted(TABLES))

@@ -31,8 +31,7 @@ from bench.drivers import rw_sweep  # noqa: E402
 from bench.drivers.block_sweep import sim_config  # noqa: E402
 from repro.cache import SimSession, base, simulate  # noqa: E402
 from repro.cache.simulator import build_step  # noqa: E402
-from repro.cache.sweep import (sweep, sweep_grid, sweep_scheduled,  # noqa: E402
-                               sweep_streaming)
+from repro.cache.sweep import sweep, sweep_streaming  # noqa: E402
 from repro.traces import ingest_msr_csv  # noqa: E402
 from tiny import TINY_CONFIG  # noqa: E402
 
@@ -218,8 +217,5 @@ def test_write_flags_need_a_write_back_configuration(cfg):
     with pytest.raises(ValueError, match="write-back"):
         base.access(base.init_cache(4, ways=4), jnp.int32(1), write=True)
     assert base.access(base.init_cache(4, ways=4), jnp.int32(1))[4] is None
-    for entry in (lambda: sweep(cfg, blocks[None]),
-                  lambda: sweep_scheduled(cfg, [blocks]),
-                  lambda: sweep_grid({"wb": cfg}, blocks[None])):
-        with pytest.raises(ValueError, match="sweep_streaming"):
-            entry()
+    with pytest.raises(ValueError, match="sweep_streaming"):
+        sweep(cfg, blocks[None])
