@@ -81,7 +81,7 @@ def bench_record_fused(rows, roofs):
             t_us += (time.time() - t0) * 1e6
         ok = all(bool(jnp.array_equal(getattr(oracle, f), getattr(fused, f)))
                  for f in oracle._fields)
-        r_sup, s_sup = oracle.rec_ts.shape[-1], oracle.mine_ts.shape[-1]
+        r_sup, s_sup = oracle.rec_ts.shape[-1], oracle.mine_ts.shape[-2]
         geom = dict(lanes=lanes, n_buckets=nb, ways=w, r_sup=r_sup,
                     mine_rows=mine_rows, s_sup=s_sup)
         shape = f"l={lanes},nb={nb},w={w},nm={mine_rows}"

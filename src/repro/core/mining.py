@@ -39,13 +39,17 @@ def sort_by_first_ts(blocks: jax.Array, ts: jax.Array, cnt: jax.Array,
                      min_support: int, max_support: int):
     """Stable-sort mining rows by first timestamp; invalid rows sink to the end.
 
-    A row is valid if ``R <= cnt <= S`` (cnt > S marks a frequent block the
-    paper kicks out; cnt < R cannot normally occur but guards cleared rows).
+    ``ts`` is the mining table as the state holds it, ``(S, N)``: row
+    ``i``'s timestamps are column ``i``. The sort keys on ``ts[0, :]``
+    and gathers columns, so the table is never read timestamps-minor;
+    the sorted timestamps come back ``(S, N)`` too. A row is valid if
+    ``R <= cnt <= S`` (cnt > S marks a frequent block the paper kicks
+    out; cnt < R cannot normally occur but guards cleared rows).
     """
     valid = (cnt >= min_support) & (cnt <= max_support)
-    key = jnp.where(valid, ts[:, 0], INT32_MAX)
+    key = jnp.where(valid, ts[0, :], INT32_MAX)
     order = jnp.argsort(key, stable=True)
-    return blocks[order], ts[order], cnt[order], valid[order]
+    return blocks[order], ts[:, order], cnt[order], valid[order]
 
 
 def pairwise_codes(ts: jax.Array, cnt: jax.Array, valid: jax.Array,
@@ -116,11 +120,15 @@ def associations_dense(blocks: jax.Array, ts: jax.Array, cnt: jax.Array,
                        pairwise_fn=pairwise_codes):
     """Full vectorized mining: returns (src, dst, valid_mask, n_dropped).
 
-    ``pairwise_fn`` is swappable so the Pallas kernel can slot in for the
-    hot inner loop (``kernels.ops.mithril_pairwise``).
+    ``ts`` is the ``(S, N)`` mining table (:func:`sort_by_first_ts`).
+    ``pairwise_fn`` keeps the ``(N, S)`` contract of
+    :func:`pairwise_codes`; its input is the transpose of the sorted
+    copy, one lane's small block, so the state's table keeps its one
+    orientation. It is swappable so the Pallas kernel can slot in for
+    the hot inner loop (``kernels.ops.mithril_pairwise``).
     """
     blk, tss, cnts, valid = sort_by_first_ts(blocks, ts, cnt, min_support, max_support)
-    code = pairwise_fn(tss, cnts, valid, delta, window)
+    code = pairwise_fn(tss.T, cnts, valid, delta, window)
     return _emit_pairs(blk, code, max_pairs)
 
 
@@ -146,7 +154,9 @@ def mine_reference_sequential(blocks: np.ndarray, ts: np.ndarray, cnt: np.ndarra
                               min_support: int, max_support: int,
                               delta: int) -> List[Tuple[int, int]]:
     """Paper Algorithm 2 on a raw (unsorted) mining table. Returns directed
-    (src_block, dst_block) pairs in discovery order."""
+    (src_block, dst_block) pairs in discovery order. ``ts`` is ``(N, S)``,
+    one row per mining row as the paper draws it: the transpose of the
+    state's table."""
     valid = (cnt >= min_support) & (cnt <= max_support)
     key = np.where(valid, ts[:, 0], INT32_MAX)
     order = np.argsort(key, kind="stable")

@@ -283,7 +283,7 @@ def record_event(cfg: MithrilConfig, state: MithrilState, block: jax.Array,
     # mining-table row: migration target, the block's resident row, or a
     # no-op write of row 0's old contents
     m = jnp.where(migrate, fill, jnp.where(is_upd, old_row, 0))
-    old_mblk, old_mts, old_mcnt = (state.mine_block[m], state.mine_ts[m],
+    old_mblk, old_mts, old_mcnt = (state.mine_block[m], state.mine_ts[:, m],
                                    state.mine_cnt[m])
     can = old_mcnt < s_sup
     pos = jnp.minimum(old_mcnt, s_sup - 1)
@@ -303,7 +303,7 @@ def record_event(cfg: MithrilConfig, state: MithrilState, block: jax.Array,
         rec_row=state.rec_row.at[b, w].set(jnp.where(migrate, fill, old_row)),
         mine_block=state.mine_block.at[m].set(
             jnp.where(migrate, block, old_mblk)),
-        mine_ts=state.mine_ts.at[m].set(
+        mine_ts=state.mine_ts.at[:, m].set(
             jnp.where(migrate, mig_ts, jnp.where(is_upd, upd_ts, old_mts))),
         # exceeding S marks the block frequent (excluded from mining)
         mine_cnt=state.mine_cnt.at[m].set(

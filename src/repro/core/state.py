@@ -35,7 +35,7 @@ class MithrilState(NamedTuple):
     rec_row: jax.Array    # (NB, W)  int32 mining row when rec_loc==1
     # mining table -----------------------------------------------------------
     mine_block: jax.Array  # (Nm,)    int32
-    mine_ts: jax.Array     # (Nm, S)  int32
+    mine_ts: jax.Array     # (S, Nm)  int32 row m's timestamps in column m
     mine_cnt: jax.Array    # (Nm,)    int32 (S+1 marks "frequent", excluded)
     mine_fill: jax.Array   # ()       int32
     # prefetching table ------------------------------------------------------
@@ -63,7 +63,7 @@ def init_state(cfg: MithrilConfig) -> MithrilState:
         rec_loc=jnp.zeros((nb, w), i32),
         rec_row=jnp.zeros((nb, w), i32),
         mine_block=jnp.full((nm,), EMPTY, i32),
-        mine_ts=jnp.zeros((nm, s), i32),
+        mine_ts=jnp.zeros((s, nm), i32),
         mine_cnt=jnp.zeros((nm,), i32),
         mine_fill=jnp.zeros((), i32),
         pf_key=jnp.full((pb, pw), EMPTY, i32),

@@ -62,11 +62,12 @@ def mithril_record_fused(states, blocks: jax.Array, enabled: jax.Array,
     locate probe, the recording-table stamp and the mining-table insert
     for every lane; prefetch-table leaves and mining counters pass
     through untouched (``record_event`` never writes them). The tables
-    go to the kernel lane-dense (buckets and mining rows minor), which
-    is the layout XLA already gives these arrays on TPU, so the
-    transposes here are layout changes, not copies. The sweep engine
-    selects this on TPU via ``sweep._batched_record_fn`` and runs the
-    pure-jnp scatter form elsewhere — bit-identical either way
+    go to the kernel lane-dense (buckets and mining rows minor). The
+    mining table ``mine_ts`` is held ``(S, Nm)`` in the state, the
+    kernel's own orientation, and passes through as it is; the
+    recording tables are transposed here. The sweep engine selects
+    this on TPU via ``sweep._batched_record_fn`` and runs the pure-jnp
+    scatter form elsewhere — bit-identical either way
     (``tests/test_record_kernel.py``).
     """
     lanes, nb, _ = states.rec_key.shape
@@ -83,7 +84,7 @@ def mithril_record_fused(states, blocks: jax.Array, enabled: jax.Array,
         jnp.transpose(states.rec_ts, (0, 2, 3, 1)),
         dense(states.rec_cnt), dense(states.rec_age),
         dense(states.rec_loc), dense(states.rec_row),
-        states.mine_block[:, None], jnp.swapaxes(states.mine_ts, 1, 2),
+        states.mine_block[:, None], states.mine_ts,
         states.mine_cnt[:, None], interpret=default_interpret(interpret))
     (rec_key, rec_ts, rec_cnt, rec_age, rec_loc, rec_row,
      mine_block, mine_ts, mine_cnt, fill) = outs
@@ -92,7 +93,7 @@ def mithril_record_fused(states, blocks: jax.Array, enabled: jax.Array,
         rec_ts=jnp.transpose(rec_ts, (0, 3, 1, 2)),
         rec_cnt=dense(rec_cnt), rec_age=dense(rec_age),
         rec_loc=dense(rec_loc), rec_row=dense(rec_row),
-        mine_block=mine_block[:, 0], mine_ts=jnp.swapaxes(mine_ts, 1, 2),
+        mine_block=mine_block[:, 0], mine_ts=mine_ts,
         mine_cnt=mine_cnt[:, 0], mine_fill=fill[:, 0, 0],
         ts=states.ts + enabled)
 

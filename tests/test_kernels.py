@@ -92,9 +92,9 @@ class TestPagedDecodeKernel:
         from repro.core.mining import associations_dense
         ts, cnt, valid = make_table(rng, 64, 8)
         a = associations_dense(jnp.arange(64, dtype=jnp.int32) + 100,
-                               ts, cnt, 2, 8, 20, 16, 128)
+                               ts.T, cnt, 2, 8, 20, 16, 128)
         b_ = associations_dense(jnp.arange(64, dtype=jnp.int32) + 100,
-                                ts, cnt, 2, 8, 20, 16, 128,
+                                ts.T, cnt, 2, 8, 20, 16, 128,
                                 pairwise_fn=lambda t, c, v, d, w:
                                 ops.mithril_pairwise(t, c, v, d, w))
         for x, y in zip(a, b_):

@@ -41,7 +41,7 @@ class TestDenseVsSequential:
         blocks = np.arange(10, 10 + n, dtype=np.int32)
         want = mine_reference_sequential(blocks, ts, cnt, r_, s_max, delta)
         src, dst, valid, _ = associations_dense(
-            jnp.array(blocks), jnp.array(ts), jnp.array(cnt), r_, s_max,
+            jnp.array(blocks), jnp.array(ts.T), jnp.array(cnt), r_, s_max,
             delta, window=n - 1, max_pairs=256)
         got = [(int(a), int(b)) for a, b, v in zip(src, dst, valid) if v]
         assert got == want

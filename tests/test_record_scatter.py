@@ -53,8 +53,9 @@ def assert_trees_equal(a, b, msg=""):
 # ---------------------------------------------------------------------------
 
 def _migrate_ref(cfg, st, block, b, way, ts_row):
+    # the mining timestamps are (S, Nm): mining row ``row`` is a column
     row = st.mine_fill
-    mine_ts = st.mine_ts.at[row, : cfg.min_support].set(ts_row)
+    mine_ts = st.mine_ts.at[: cfg.min_support, row].set(ts_row)
     return st._replace(
         mine_block=st.mine_block.at[row].set(block),
         mine_ts=mine_ts,
@@ -99,8 +100,8 @@ def record_event_reference(cfg: MithrilConfig, state: MithrilState,
         mcnt = st.mine_cnt[row]
         can = mcnt < cfg.max_support
         pos = jnp.minimum(mcnt, cfg.max_support - 1)
-        mine_ts = st.mine_ts.at[row, pos].set(
-            jnp.where(can, ts, st.mine_ts[row, pos]))
+        mine_ts = st.mine_ts.at[pos, row].set(
+            jnp.where(can, ts, st.mine_ts[pos, row]))
         mine_cnt = st.mine_cnt.at[row].set(
             jnp.where(can, mcnt + 1, cfg.max_support + 1))
         return st._replace(mine_ts=mine_ts, mine_cnt=mine_cnt)
